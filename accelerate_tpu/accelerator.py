@@ -826,8 +826,8 @@ class Accelerator:
     def _apply_fp8_opt_level(self, optimizer):
         """MS-AMP ``opt_level="O2"`` analog (reference ``accelerator.py:2164``): store the
         AdamW moments as scaled-fp8. Takes effect on a ``FusedAdamW`` whose moment dtypes
-        were left unset; measured on-chip this is a ~10% end-to-end MFU win at 0.9B params
-        (the apply is bandwidth-bound — see PERF_NOTES.md round-4 window 3)."""
+        were left unset; the bandwidth-bound apply then moves 4x fewer moment bytes (its
+        end-to-end effect on the current chip tool is not measured)."""
         recipe = self.fp8_recipe
         if recipe is None or getattr(recipe, "opt_level", "O1") != "O2":
             return optimizer

@@ -75,7 +75,7 @@ __all__ = [
 MEM_BASELINE_FILE = os.path.join(REPO_ROOT, "graftmem_baseline.json")
 
 #: Per-chip HBM ceiling the budget rule gates against when no ``--budget`` is
-#: given: 16 GiB (v5e/v5p-lite class — PERF_NOTES pins the 0.9B config near it).
+#: given: 16 GiB (v5e/v5p-lite class).
 DEFAULT_CHIP_BUDGET_BYTES = 16 << 30
 
 #: Relative tolerance band on ratcheted per-label estimates: growth beyond

@@ -1,6 +1,6 @@
 """dead-knob: a config field that is only ever *defined* is worse than an error.
 
-Incident: the round-1 VERDICT's "dead/misleading plugin knobs" — a dataclass field the
+Incident: "dead/misleading plugin knobs" — a dataclass field the
 user sets and the package silently ignores. ``tests/test_no_dead_knobs.py`` guarded
 five hardcoded config classes with a regex grep; this rule is the generalization: every
 ``@dataclass`` in the linted non-test sources, checked against every attribute access

@@ -1,6 +1,6 @@
 """jit-impurity: host side effects inside jit-traced code run at TRACE time, not step time.
 
-Incident: the round-5 VERDICT's bench probe classes — a ``time.time()`` or ``print``
+Incident: the bench probe classes — a ``time.time()`` or ``print``
 inside a jitted step executes once during tracing and never again, so the "measurement"
 measures compilation, and an ``np.random`` call bakes one constant sample into the
 compiled graph. Flags impure calls and ``global`` mutation inside functions that are

@@ -3,7 +3,7 @@
 Enumerates the (train step, eval step, prefill buckets, decode, row-insert)
 programs for a model/serving config and pushes each through
 ``compile_cache.AotCache`` without executing anything, writing a warmup
-manifest beside the cache entries. A tunnel window or serving replica started
+manifest beside the cache entries. A training job or serving replica started
 afterwards deserializes executables instead of paying XLA compile
 (docs/compile_cache.md).
 """

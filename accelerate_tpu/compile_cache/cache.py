@@ -1,8 +1,7 @@
 """Persistent AOT executable cache: compile once per fleet, not once per process.
 
-Every process start re-pays every XLA compile (PERF_NOTES: two full TPU windows
-were lost to compiles of never-before-compiled programs). This module closes
-that hole at the executable level:
+Every process start re-pays every XLA compile. This module closes that hole at
+the executable level:
 
 - :class:`AotCache` — a content-addressed store of serialized compiled
   executables under ``CompileCacheConfig.cache_dir``. Keys come from
@@ -29,13 +28,10 @@ import tempfile
 import time
 from typing import Any, Callable, Optional
 
+from jax.experimental import serialize_executable as _ser
+
 from ..logging import get_logger
 from ..utils.dataclasses import CompileCacheConfig
-from ..utils.jax_compat import (
-    deserialize_executable,
-    executable_serialization_supported,
-    serialize_executable,
-)
 from .fingerprint import backend_environment, fingerprint, signature_key
 
 logger = get_logger(__name__)
@@ -62,20 +58,13 @@ class AotCache:
     """Content-addressed persistent store of serialized XLA executables.
 
     Construction is cheap and never touches disk; the directory is created on
-    the first write. A disabled config (or a jax without executable
-    serialization) makes :meth:`wrap` the identity — zero overhead, zero
-    behavior change.
+    the first write. A disabled config makes :meth:`wrap` the identity — zero
+    overhead, zero behavior change.
     """
 
     def __init__(self, config: Optional[CompileCacheConfig] = None):
         self.config = config or CompileCacheConfig()
-        self.supported = executable_serialization_supported()
-        self.enabled = bool(self.config.enabled) and self.supported
-        if self.config.enabled and not self.supported:
-            logger.warning(
-                "compile cache requested but this jax exposes no executable "
-                "serialization API; running with live compiles"
-            )
+        self.enabled = bool(self.config.enabled)
         self.cache_dir = self.config.cache_dir
         # Counters (mirrored into telemetry CompileMonitor snapshots).
         self.hits = 0
@@ -166,7 +155,7 @@ class AotCache:
                     entry = pickle.load(f)
                 if entry.get("schema") != ENTRY_SCHEMA or entry.get("key") != key:
                     raise ValueError("entry schema/key mismatch")
-                exe = deserialize_executable(
+                exe = _ser.deserialize_and_load(
                     entry["payload"], entry["in_tree"], entry["out_tree"]
                 )
                 dt = time.perf_counter() - t0
@@ -220,13 +209,13 @@ class AotCache:
         """Serialize + atomic-write one entry; storage failures only cost
         persistence, never correctness."""
         try:
-            payload, in_tree, out_tree = serialize_executable(compiled)
+            payload, in_tree, out_tree = _ser.serialize(compiled)
             # Validate before persisting: an executable that was itself LOADED from
             # jax's persistent compilation cache serializes to an incomplete payload
             # on the CPU backend (object code absent — "Symbols not found" at load).
             # Writing it would poison every later process; skipping just means this
             # program stays served by jax's own cache.
-            deserialize_executable(payload, in_tree, out_tree)
+            _ser.deserialize_and_load(payload, in_tree, out_tree)
             entry = {
                 "schema": ENTRY_SCHEMA,
                 "key": key,

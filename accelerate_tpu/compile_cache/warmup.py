@@ -5,8 +5,8 @@ geometry, serving geometry) it builds the exact programs a training run or a
 serving replica would compile lazily — train micro/apply (or fused) step, eval
 step, one prefill per shape bucket, the chunk-append program, the decode step,
 the per-slot row inserts — and pushes each through ``AotCache`` WITHOUT
-executing them (``lower().compile()`` + serialize, never dispatch). A tunnel
-window or replica that starts afterwards deserializes instead of compiling:
+executing them (``lower().compile()`` + serialize, never dispatch). A job
+or replica that starts afterwards deserializes instead of compiling:
 cold start stops scaling with program count.
 
 The resulting manifest (``<cache_dir>/warmup_manifest.json`` by default) lists
@@ -166,12 +166,11 @@ def run_warmup(
     if cache.capture is None:
         cache.capture = []  # arm program capture: the manifest stamps audit provenance
     if not cache.enabled:
-        # An unsupported jax degrades the cache to live compiles — fine for a
-        # training run, but a warmup whose whole purpose is priming the cache
-        # must fail loudly, not exit 0 with an empty manifest.
+        # A warmup whose whole purpose is priming the cache must fail loudly,
+        # not exit 0 with an empty manifest.
         raise RuntimeError(
-            "warmup cannot populate the compile cache: this jax exposes no "
-            "executable serialization API (jax.experimental.serialize_executable)"
+            "warmup cannot populate the compile cache: it is disabled "
+            "(CompileCacheConfig.enabled / ACCELERATE_COMPILE_CACHE)"
         )
     params = llama.init_params(cfg)
 

@@ -18,8 +18,7 @@ these exact kernels per ring step with correct cross-device causal masking. The 
 ``_bwd_dq`` / ``_bwd_dkv`` entry points (returning/consuming lse and delta) are the building
 blocks for the ring; ``flash_attention`` is the single-device public API.
 
-TPU-specific structure (the r2 on-chip decompose showed the first version of this kernel
-running at ~1/5 the throughput of plain XLA attention; these three choices close it):
+TPU-specific structure (the same three choices the official jax flash kernel makes):
 
 - **Lane-replicated softmax state.** The running max ``m`` and sum ``l`` live in VMEM as
   [block_q, 128] with every lane carrying the same value, so the per-step rescale math runs
@@ -35,7 +34,7 @@ running at ~1/5 the throughput of plain XLA attention; these three choices close
   ``pallas_call`` carries a ``pl.CostEstimate`` so XLA's scheduler sees the real arithmetic
   intensity. ``ACCEL_FLASH_DIMSEM=0`` disables the semantics for A/B measurement.
 
-Runs in interpreter mode on CPU (tests) and compiled on TPU. Block sizes default to 256×512
+Runs in interpreter mode on CPU (tests) and compiled on TPU. Block sizes default to 512×512
 (see ``_DEFAULT_BLOCK_Q/K``); hd should be a multiple of 128 for peak efficiency (llama3:
 hd=128). Sweep overrides: ACCEL_FLASH_BLOCK_Q / ACCEL_FLASH_BLOCK_K.
 """
@@ -44,7 +43,6 @@ from __future__ import annotations
 
 import functools
 import math
-from ..utils.jax_compat import tpu_compiler_params as _tpu_compiler_params
 import os
 from typing import Optional
 
@@ -53,22 +51,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ._common import LANES as _LANES
 from ._common import interpret_default as _interpret_default
+from ._common import lane_tile as _lane_tile
 
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
-_LANES = 128  # native VPU lane count: softmax state is replicated across lanes
 
 
 # Default tile sizes. The grid iterates sequentially on the TensorCore, so per-step fixed
 # overhead (semaphores, block DMA setup) is paid nq*nk times per (batch, head): 128x128 tiles
-# at S=2048 mean 256 steps/head of mostly overhead. 512x512 is the r2 ON-CHIP sweep best
-# (v5e, llama-0.9B b4 seq2048: blocks512 0.1937 MFU vs blocks128 0.135, blocks256x1024
-# 0.161 — PERF_NOTES.md); the working set (q/k/v 3x512KB bf16 + fp32 acc/s ~1.3MB) stays
-# well under VMEM. Baked in as the default because the round driver resets the sweep
-# output the auto-adoption would otherwise replay the tuning from.
-# Env overrides allow per-chip tuning without code changes (used by bench sweeps).
+# at S=2048 mean 256 steps/head of mostly overhead. At 512x512 the working set (q/k/v
+# 3x128KB bf16 at hd=128 + fp32 acc/s ~1.3MB) stays well under VMEM.
+# Env overrides allow per-chip tuning without code changes.
 def _env_block(name: str, default: int) -> int:
     raw = os.environ.get(name, "")
     try:
@@ -89,10 +85,10 @@ def _dim_semantics(n_parallel: int, n_arbitrary: int):
     scratch state and may be reordered/pipelined freely (PARALLEL); the trailing dims
     accumulate into VMEM scratch across iterations and must stay sequential (ARBITRARY).
     Default ON (the official jax flash kernel ships this unconditionally);
-    ACCEL_FLASH_DIMSEM=0 turns it off for A/B rows in the bench sweep."""
+    ACCEL_FLASH_DIMSEM=0 turns it off for A/B measurement."""
     if os.environ.get("ACCEL_FLASH_DIMSEM", "1") == "0":
         return None
-    return _tpu_compiler_params(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * n_parallel + ("arbitrary",) * n_arbitrary
     )
 
@@ -110,18 +106,6 @@ def _scalar(x) -> jax.Array:
 
 def _smem_scalar_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
-
-
-def _lane_tile(x, cols):
-    """Broadcast lane-replicated state [rows, _LANES] across a tile [rows, cols] —
-    full-register tile then slice, never a 1-lane relayout. Handles any cols (ceil-tile
-    + slice for non-multiples of 128, e.g. head_dim 192)."""
-    if cols == _LANES:
-        return x
-    if cols < _LANES:
-        return x[:, :cols]
-    tiled = jnp.tile(x, (1, pl.cdiv(cols, _LANES)))
-    return tiled if tiled.shape[1] == cols else tiled[:, :cols]
 
 
 def _tile_mask(*, causal, window, has_segments, kv_pad, block_q, block_k,
@@ -145,8 +129,8 @@ def _tile_mask(*, causal, window, has_segments, kv_pad, block_q, block_k,
         if window:
             mask = _and(mask, col > row - window)
     if has_segments:
-        sq = q_seg_ref[0][:, None]
-        sk = kv_seg_ref[0][None, :]
+        sq = q_seg_ref[...]    # [block_q, 1]: one id per row, along sublanes
+        sk = kv_seg_ref[...]   # [1, block_k]: one id per column, along lanes
         mask = _and(mask, jnp.logical_and(sq == sk, sk != 0))
     return mask
 
@@ -255,7 +239,10 @@ def _fwd_kernel(
 
 
 def _seg_blocks(segments, Sp, Tp):
-    """Pad + split packed segment ids into (q_seg [B,Sp], kv_seg [B,Tp]) int32 (pad = 0).
+    """Pad + split packed segment ids into (q_seg [B,Sp,1], kv_seg [B,1,Tp]) int32 (pad
+    = 0): the q side as a column and the kv side as a row, so each kernel block's last two
+    dims are (block, 1) / (1, block) — shapes Mosaic tiles — and the in-kernel
+    ``q == kv`` compare is a plain broadcast with no lane/sublane relayout.
 
     ``segments`` is either one [B,S] array (self-attention: both sides share it) or a
     ``(q_seg [B,S], kv_seg [B,T])`` pair — the ring/allgather SP case, where the kv block
@@ -268,7 +255,7 @@ def _seg_blocks(segments, Sp, Tp):
     kv_raw = jnp.asarray(kv_raw, jnp.int32)
     q_seg = jnp.pad(q_raw, ((0, 0), (0, Sp - q_raw.shape[1])))
     kv_seg = jnp.pad(kv_raw, ((0, 0), (0, Tp - kv_raw.shape[1])))
-    return q_seg, kv_seg
+    return q_seg[:, :, None], kv_seg[:, None, :]
 
 
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_offset=0,
@@ -297,8 +284,8 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_
     if has_segments:
         q_seg, kv_seg = _seg_blocks(segments, Sp, Tp)
         seg_specs = [
-            pl.BlockSpec((1, block_q), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, block_k), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((None, block_q, 1), lambda b, h, i, j: (b, i, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, h, i, j: (b, 0, j)),
         ]
         seg_args = [q_seg, kv_seg]
     # fwd cost: qk^T + pv dots (causal ≈ half the tiles), exp over the score tiles.
@@ -547,8 +534,8 @@ def _bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interpr
     if has_segments:
         q_seg, kv_seg = _seg_blocks(segments, Sp, Tp)
         seg_specs = [
-            pl.BlockSpec((1, block_q), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, block_k), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((None, block_q, 1), lambda b, h, i, j: (b, i, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, h, i, j: (b, 0, j)),
         ]
         seg_args = [q_seg, kv_seg]
     kernel = functools.partial(
@@ -611,8 +598,8 @@ def _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interp
         q_seg, kv_seg = _seg_blocks(segments, Sp, Tp)
         # Grid order here is (b, kh, j, g): kv block outer, (group rep, q block) inner.
         seg_specs = [
-            pl.BlockSpec((1, block_q), lambda b, kh, j, g: (b, g % nq)),
-            pl.BlockSpec((1, block_k), lambda b, kh, j, g: (b, j)),
+            pl.BlockSpec((None, block_q, 1), lambda b, kh, j, g: (b, g % nq, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, kh, j, g: (b, 0, j)),
         ]
         seg_args = [q_seg, kv_seg]
     kernel = functools.partial(

@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._common import interpret_default as _interpret_default
-from ..utils.jax_compat import axis_size as _axis_size, tpu_compiler_params as _tpu_compiler_params
+from ..utils.jax_compat import axis_size as _axis_size
 
 __all__ = ["fused_cross_entropy", "fused_cross_entropy_tp"]
 
@@ -213,6 +213,21 @@ def _fce(x, w, t2, vocab, softcap, block_t, block_v, interpret):
     return nll
 
 
+def _compiler_params(x, w, block_t, block_v, resident_bytes=0):
+    """Grid semantics + the scoped-VMEM limit this launch needs. The pipeline holds two
+    copies of every streamed block ([block_t, D] of x, [D, block_v] of w), the kernel
+    body a few fp32 [block_t, block_v] score temporaries, and the backward kernels a
+    resident accumulator + output block (``resident_bytes``). At d_model 4096 that is
+    past the 16 MiB default of a v5e, so say so instead of shrinking the tiles."""
+    D = x.shape[1]
+    streamed = 2 * D * (block_t * x.dtype.itemsize + block_v * w.dtype.itemsize)
+    need = streamed + resident_bytes + 6 * block_t * block_v * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
+        vmem_limit_bytes=int(need * 1.25) + (4 << 20),
+    )
+
+
 def _launch_fwd(kernel_fn, n_outputs, x, w, t2, *, vocab, softcap, block_t, block_v,
                 interpret):
     """Shared forward launch (same grid/specs/scratch for both fwd kernel variants —
@@ -232,9 +247,7 @@ def _launch_fwd(kernel_fn, n_outputs, x, w, t2, *, vocab, softcap, block_t, bloc
         out_specs=[stat_spec] * n_outputs,
         out_shape=[jax.ShapeDtypeStruct((Tp, 1), jnp.float32)] * n_outputs,
         scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)] * 3,
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
-        ),
+        compiler_params=_compiler_params(x, w, block_t, block_v),
         interpret=interpret,
     )(t2, x, w)
 
@@ -268,8 +281,9 @@ def _fce_bwd(vocab, softcap, block_t, block_v, interpret, res, g):
         out_specs=pl.BlockSpec((block_t, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, D), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
+        compiler_params=_compiler_params(
+            x, w, block_t, block_v,
+            resident_bytes=block_t * D * (4 + 2 * x.dtype.itemsize),
         ),
         interpret=interpret,
     )(t2, x, w, lse, g2)
@@ -287,8 +301,9 @@ def _fce_bwd(vocab, softcap, block_t, block_v, interpret, res, g):
         out_specs=pl.BlockSpec((D, block_v), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((D, Vp), w.dtype),
         scratch_shapes=[pltpu.VMEM((D, block_v), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
+        compiler_params=_compiler_params(
+            x, w, block_t, block_v,
+            resident_bytes=D * block_v * (4 + 2 * w.dtype.itemsize),
         ),
         interpret=interpret,
     )(t2, x, w, lse, g2)

@@ -137,7 +137,7 @@ def shard_tree(tree: Any, mesh: Mesh, specs: Any) -> Any:
 def _log_sharding_summary(params: Any, shardings: Any, mesh: Mesh) -> None:
     """Report how many bytes actually got partitioned vs silently replicated.
 
-    VERDICT r1 weak #10: ``infer_fsdp_spec`` leaves indivisible/small leaves replicated by
+    ``infer_fsdp_spec`` leaves indivisible/small leaves replicated by
     design, but silently — on a wide fsdp axis that makes "why is HBM full" undebuggable.
     """
     from ..logging import get_logger

@@ -46,13 +46,9 @@ __all__ = [
 
 def mesh_context(mesh: Mesh):
     """The ambient-mesh context letting jitted code use bare ``PartitionSpec``s in
-    sharding constraints: ``jax.set_mesh(mesh)`` where it exists, else the legacy
-    ``with mesh:`` resource-env context (jax 0.4.x), which serves the same purpose.
-    Every ``with jax.set_mesh(...)`` in this package routes through here so one jax
-    API change never strands the whole train/eval path again."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager (legacy resource env)
+    sharding constraints (``jax.set_mesh``). Every mesh context in this package
+    routes through here so a jax API move is a one-line edit."""
+    return jax.set_mesh(mesh)
 
 
 @dataclass

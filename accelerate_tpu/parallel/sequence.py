@@ -11,7 +11,7 @@ Three interchangeable strategies over the ``sp`` mesh axis, all exact:
   seq-sharded attention by default; kept as the fallback and correctness oracle.
 
 ``sequence_parallel_attention`` dispatches by mode and is shard_map-ready; wrap it with
-``make_sp_attention`` to embed into a GSPMD-jitted model (manual only over ``sp``).
+``make_sp_attention`` to embed into a GSPMD-jitted model.
 """
 
 from __future__ import annotations
@@ -214,15 +214,23 @@ def make_sp_attention(mesh, mode: str = "ring", axis_name: str = SEQUENCE_AXIS, 
                       window: int = 0, softcap: float = 0.0, sm_scale: Optional[float] = None):
     """Wrap ``sequence_parallel_attention`` for use inside a GSPMD-jitted model.
 
-    Returns ``attn(q, k, v) -> o`` over GLOBAL [B, S, H, hd] arrays: shard_map is manual only
-    over the ``sp`` axis (batch/heads stay auto-sharded by GSPMD around it).
+    Returns ``attn(q, k, v) -> o`` over GLOBAL [B, S, H, hd] arrays. The shard_map is
+    manual over EVERY mesh axis — the flash kernels inside are Mosaic custom calls, which
+    GSPMD cannot partition, so nothing may be left automatic around them: the sequence
+    rides ``sp``, the batch the batch axes, and (ring/allgather) the heads ``tp``
+    (``ops._common.attention_shard_spec``). Called from inside somebody else's manual
+    region it adds only ``sp`` to the manual set, as nesting requires.
     """
     from jax.sharding import PartitionSpec as P
 
-    spec = P(None, axis_name, None, None)
-    seg_spec = P(None, axis_name)
+    from ..ops._common import attention_shard_spec
 
     def attn(q, k, v, segment_ids=None):
+        nested = bool(mesh.manual_axes)
+        spec = P(None, axis_name, None, None) if nested else attention_shard_spec(
+            mesh, q, k, seq_axis=axis_name, heads=mode in ("ring", "allgather")
+        )
+        seg_spec = P(spec[0], axis_name)
         fn = functools.partial(
             sequence_parallel_attention, mode=mode, axis_name=axis_name, causal=causal,
             window=window, softcap=softcap, sm_scale=sm_scale,
@@ -236,7 +244,7 @@ def make_sp_attention(mesh, mode: str = "ring", axis_name: str = SEQUENCE_AXIS, 
             mesh=mesh,
             in_specs=(spec, spec, spec) + ((seg_spec,) if packed else ()),
             out_specs=spec,
-            axis_names={axis_name},
+            axis_names={axis_name} if nested else set(mesh.axis_names),
             # pallas_call out_shapes don't carry vma annotations; skip the check.
             check_vma=False,
         )

@@ -1,9 +1,9 @@
 """Step-level telemetry: trustworthy in-framework metrics (L9).
 
-This package turns the hard-won bench_rev-2 measurement lessons (PERF_NOTES.md: a
-post-compile allocator transient understated every round-1..4 scoring number ~2.4x;
-a 128 MB host fetch was once timed as device work) into a reusable pipeline instead
-of bench-script folklore:
+This package turns the hard-won bench_rev-2 measurement lessons (a post-compile
+allocator transient was once averaged into every scoring number; a 128 MB host fetch
+was once timed as device work) into a reusable pipeline instead of bench-script
+folklore:
 
 - :func:`fence` / :class:`StepTimer` — timing correct by construction (1-element
   fenced sync, monotonic clock, wall/dispatch/fence split).

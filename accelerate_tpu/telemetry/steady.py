@@ -1,9 +1,9 @@
 """Steady-state detection — the bench_rev-2 rule as a library.
 
-PERF_NOTES.md, 2026-08-01: the first 1-2 post-compile optimizer rounds pay a one-time
-allocator/settling cost (~10 s at 0.9B params near the 16 GB HBM ceiling). Every
-scoring number from rounds 1-4 averaged that transient into the step time and
-understated the framework ~2.4x. The fix ("bench_rev 2"): warm until K consecutive
+The first 1-2 post-compile optimizer rounds pay a one-time allocator/settling cost
+(seconds at 0.9B params near the 16 GB HBM ceiling). The early scoring numbers averaged
+that transient into the step time and understated the framework severalfold. The fix
+("bench_rev 2"): warm until K consecutive
 windows agree within a relative tolerance, THEN measure. Training runs for hours — a
 seconds-scale process-start transient does not belong in any rate metric.
 
@@ -60,7 +60,7 @@ class SteadyStateDetector:
         detection — later observations never relabel the past).
 
         The ``k`` agreeing windows that *triggered* steadiness count as steady, so
-        on the PERF_NOTES shape ``[10.2, 2.1, 0.47, 0.46]`` this is 2 — the 10 s and
+        on the settling shape ``[10.2, 2.1, 0.47, 0.46]`` this is 2 — the 10 s and
         2 s rounds are the transient, the two agreeing ~0.46 s rounds are not. When
         the cap fired, EVERY observed window counts as warmup (none proved steady).
         """

@@ -92,7 +92,7 @@ def run_ops_and_metrics_self_tests():
 
 
 def run_dryrun_train_2proc():
-    """Child body for the driver dryrun's 2-process section (VERDICT r3 weak #5): a real
+    """Child body for the driver dryrun's 2-process section: a real
     distributed train step on a dp×fsdp mesh spanning 2 processes × 4 devices — the
     cross-process collective transport (grad psum, global-norm clip, fsdp all-gathers)
     exercised inside the driver-scored artifact, not just the pytest tier."""

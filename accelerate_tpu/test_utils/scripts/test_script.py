@@ -25,7 +25,7 @@ def _ensure_backend():
     ``accelerate-tpu test --on-device`` sets ACCELERATE_SELF_TEST_ON_DEVICE; otherwise — bare
     runs included — the suite exercises real 8-way mesh/collective behavior on CPU. The
     device-count XLA flag takes effect at backend-client creation, so setting it here works
-    even when a sitecustomize imported jax earlier, as long as no devices were touched yet.
+    even when jax was imported earlier, as long as no devices were touched yet.
     """
     if os.environ.get("ACCELERATE_SELF_TEST_ON_DEVICE"):
         return

@@ -322,7 +322,7 @@ class TelemetryConfig(KwargsHandler):
     self-sufficient: records land in ``<jsonl_dir>/telemetry.jsonl`` even with no
     tracker configured.
 
-    ``steady_*`` parameterize the rev-2 steady-state rule (PERF_NOTES.md): warm
+    ``steady_*`` parameterize the rev-2 steady-state rule (``telemetry.steady``): warm
     until ``steady_k`` consecutive steps agree within ``steady_rtol``, cap
     ``steady_cap`` steps. ``flops_per_step``/``tokens_per_step``/``examples_per_step``
     are static per-step costs for the derived rates; tokens/examples fall back to

@@ -1,138 +1,20 @@
-"""Compatibility shims spanning the two jax lineages this repo meets in the wild.
-
-The development TPU environment runs a recent jax (``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``jax.shard_map(check_vma=...)``); CI / driver
-hosts can sit on the 0.4.x line where those spell ``with mesh:``,
-``thread_resources.env.physical_mesh`` and
-``jax.experimental.shard_map.shard_map(check_rep=...)``. Every version-sensitive
-call in the package routes through here (or through
-``parallel.mesh.mesh_context`` for the mesh context), so one jax API move never
-strands the train/eval path on half the fleet again.
-
-Each shim prefers the modern API and degrades to the 0.4.x equivalent — same
-semantics for everything this package does with them (ambient-mesh sharding
-constraints, manual collectives over a named mesh).
+"""The handful of jax names this package reaches for that have moved between
+releases, bound ONCE to the installed API (jax 0.9) so a future rename is a
+one-line edit here instead of a sweep through ``ops/``, ``parallel/`` and ``models/``.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = [
-    "axis_size",
-    "current_abstract_mesh",
-    "deserialize_executable",
-    "executable_serialization_supported",
-    "serialize_executable",
-    "shard_map",
-    "tpu_compiler_params",
-]
+__all__ = ["axis_size", "current_abstract_mesh", "shard_map"]
 
+#: The ambient mesh set by ``parallel.mesh.mesh_context`` (an EMPTY mesh — ``.empty``
+#: True, no axis names — when no context is active).
+current_abstract_mesh = jax.sharding.get_abstract_mesh
 
-def current_abstract_mesh():
-    """The ambient mesh set by ``parallel.mesh.mesh_context``:
-    ``jax.sharding.get_abstract_mesh()`` where it exists, else the legacy
-    resource-env physical mesh (an EMPTY mesh — ``.empty`` True, no axis names —
-    when no context is active, matching the modern API's contract)."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        return getter()
-    from jax._src import mesh as _mesh_lib
+#: ``shard_map(f, mesh=, in_specs=, out_specs=, check_vma=, axis_names=)``.
+shard_map = jax.shard_map
 
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
-def shard_map(
-    f, mesh=None, in_specs=None, out_specs=None, check_vma=None, axis_names=None,
-    **kwargs,
-):
-    """``jax.shard_map`` with the modern keyword surface on both lineages.
-
-    ``check_vma`` (the varying-manual-axes check) is the modern name of 0.4.x's
-    ``check_rep``; ``axis_names`` (the MANUAL axes of a partial-manual map) is the
-    complement of 0.4.x's ``auto`` set — both forwarded under whichever spelling
-    the installed jax takes.
-    """
-    modern = getattr(jax, "shard_map", None)
-    # A test harness may back-fill jax.shard_map with THIS function (marker below)
-    # — treat that as "no modern API", not as something to recurse into.
-    if modern is not None and not getattr(modern, "_accelerate_tpu_compat", False):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return modern(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (modern) — on 0.4.x, ``psum(1, axis)`` inside a manual
-    map constant-folds to the same static int at trace time."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _serialize_executable_module():
-    """The executable (de)serialization module across lineages, or None.
-
-    Both lineages currently spell it ``jax.experimental.serialize_executable``
-    (0.4.x and modern); it moved out of ``jax.interpreters`` before 0.4 and may
-    graduate again — keep every resolution path here so a rename strands only
-    this function. Returns None when no serializer exists: the AOT compile cache
-    then degrades to live compiles (``AotCache.enabled`` False) instead of
-    failing imports.
-    """
-    try:
-        from jax.experimental import serialize_executable as mod
-    except ImportError:
-        return None
-    if hasattr(mod, "serialize") and hasattr(mod, "deserialize_and_load"):
-        return mod
-    return None
-
-
-def executable_serialization_supported() -> bool:
-    """True when this jax can serialize compiled executables to bytes."""
-    return _serialize_executable_module() is not None
-
-
-def serialize_executable(compiled):
-    """``(payload_bytes, in_tree, out_tree)`` for a ``jax.stages.Compiled``.
-
-    Raises ``RuntimeError`` when the running jax has no serializer — callers that
-    want graceful degradation should gate on
-    :func:`executable_serialization_supported` first.
-    """
-    mod = _serialize_executable_module()
-    if mod is None:
-        raise RuntimeError("this jax exposes no executable serialization API")
-    return mod.serialize(compiled)
-
-
-def deserialize_executable(payload, in_tree, out_tree):
-    """Load a serialized executable back into a callable ``Compiled`` (no XLA
-    compile happens — the point of the AOT cache)."""
-    mod = _serialize_executable_module()
-    if mod is None:
-        raise RuntimeError("this jax exposes no executable serialization API")
-    return mod.deserialize_and_load(payload, in_tree, out_tree)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` under its modern name, ``TPUCompilerParams`` on
-    0.4.x — identical field set for everything this package passes
-    (``dimension_semantics``, ``vmem_limit_bytes``)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+#: Static size of a named mesh axis inside a manual region.
+axis_size = jax.lax.axis_size

@@ -43,13 +43,14 @@ def main() -> int:
     p.add_argument("--device", default="cpu", choices=["cpu", "tpu"])
     args = p.parse_args()
 
+    import jax
+
+    from accelerate_tpu.utils.environment import place_compile_cache
+
+    place_compile_cache()
     if args.device == "cpu":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        import jax
-
         jax.config.update("jax_platforms", "cpu")
-    else:
-        import jax
 
     import numpy as np
     import optax

@@ -340,7 +340,7 @@ def test_load_checkpoint_dtype_override_all_placements(tmp_path):
 
 
 def test_load_checkpoint_bounded_residency(tmp_path):
-    """VERDICT r4 weak #1 / item 2: streaming the checkpoint must hold the resident
+    """Streaming the checkpoint must hold the resident
     ("cpu"-placed, converted) portion plus O(one tensor) of scratch — never a whole-shard
     dict. 16 x 1 MiB fp32 tensors in 4 MiB shards, half placed cpu (converted to bf16,
     0.5 MiB each resident), half disk; anonymous allocation peak (tracemalloc — memmap

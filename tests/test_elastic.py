@@ -1,4 +1,4 @@
-"""Elastic supervision: dying workers get the gang restarted (VERDICT r1 next #9).
+"""Elastic supervision: dying workers get the gang restarted.
 
 Reference analog: torchrun elastic agent behavior the reference reaches through
 ``torch.distributed.run`` (``commands/launch.py:785-816``) and ``notebook_launcher``'s
@@ -160,7 +160,7 @@ def test_preemption_resume_loss_parity(tmp_path):
     mid-epoch → ElasticSupervisor restarts the gang → resume from the checkpoint
     (load_state + skip_first_batches) → final params exactly match an uninterrupted run.
 
-    This is the integration of VERDICT r1 next #9 (elastic) with L7 checkpointing —
+    This is the integration of elastic supervision with L7 checkpointing —
     the 'TPU preemptions are routine' contract from SURVEY §7."""
     import numpy as np
 

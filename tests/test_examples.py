@@ -15,7 +15,7 @@ from pathlib import Path
 import pytest
 
 # Example runs recompile XLA programs per script (~20-90 s each): slow tier, like the
-# reference's example-regression CI (VERDICT r1 weak #7). RUN_SLOW=1 enables.
+# reference's example-regression CI. RUN_SLOW=1 enables.
 from accelerate_tpu.test_utils.testing import slow_mark
 
 pytestmark = slow_mark()

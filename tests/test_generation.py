@@ -2,7 +2,7 @@
 
 Reference analog: the s/token decode path behind
 ``/root/reference/benchmarks/big_model_inference/README.md:25-37`` (transformers
-``model.generate`` over dispatched models). VERDICT round-1 #3's done-criterion: cached decode
+``model.generate`` over dispatched models). The done-criterion: cached decode
 == uncached argmax decode on the tiny config.
 """
 

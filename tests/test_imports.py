@@ -3,8 +3,9 @@
 The reference asserts ``import accelerate`` stays cheap and lazy (its CI budget test);
 here the contract is the same: importing the package must not drag in the heavy
 optional stacks (torch, transformers, orbax — all function-level imports at their use
-sites) and must stay within a wall-clock budget measured as a DELTA over interpreter
-startup (the environment's sitecustomize alone costs seconds and is not ours to spend).
+sites), must stay within a wall-clock budget measured as a DELTA over interpreter
+startup, and must not initialize a jax backend: a chip belongs to one process at a time,
+so a launcher that merely imported the package would take it from its own workers.
 """
 
 import os
@@ -32,19 +33,42 @@ def _wall(code: str) -> float:
     return time.perf_counter() - t0
 
 
-def test_import_does_not_pull_heavy_deps():
+def test_import_pulls_no_heavy_deps_and_initializes_no_backend():
     """torch / transformers / orbax / tensorboard are use-site imports, never
-    top-level: a user who only wants the facade must not pay for them."""
+    top-level: a user who only wants the facade must not pay for them. And no module
+    a parent process imports before starting workers (the kernels, the serving engine,
+    the gateway, the CLI) may touch a device at import — ``ops.quantization`` once
+    built its NF4 codebook with ``jnp.asarray`` at module level and claimed the chip."""
     r = subprocess.run(
         [sys.executable, "-c", (
             "import sys; import accelerate_tpu; "
             "leaked = [m for m in ('torch', 'transformers', 'tensorflow', 'orbax',"
             " 'tensorboard', 'wandb') if m in sys.modules]; "
-            "sys.exit(repr(leaked)) if leaked else None"
+            "import accelerate_tpu.ops, accelerate_tpu.serving, "
+            "accelerate_tpu.serving_gateway, accelerate_tpu.commands.accelerate_cli; "
+            "from jax._src import xla_bridge; "
+            "sys.exit(repr((leaked, list(xla_bridge._backends)))) "
+            "if leaked or xla_bridge._backends else None"
         )],
         capture_output=True, text=True, env=_ENV,
     )
-    assert r.returncode == 0, f"heavy modules imported at package import: {r.stderr}"
+    assert r.returncode == 0, (
+        f"(heavy modules at package import, backends initialized by imports): {r.stderr}"
+    )
+
+
+def test_tpu_backend_helper_is_the_default_backend(monkeypatch):
+    """``is_tpu_available`` is the ONE backend question (kernel dispatch, interpret
+    mode, bench refusal): exactly ``jax.default_backend() == "tpu"``."""
+    import jax
+
+    from accelerate_tpu.ops._common import interpret_default
+    from accelerate_tpu.utils.imports import is_tpu_available
+
+    for backend, on_tpu in (("tpu", True), ("cpu", False), ("gpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda backend=backend: backend)
+        assert is_tpu_available() is on_tpu
+        assert interpret_default() is (not on_tpu)
 
 
 def test_top_level_migration_surface():
@@ -97,7 +121,7 @@ def test_no_local_import_shadows_module_level():
         sorted((root / "accelerate_tpu").rglob("*.py"))
         + sorted((root / "benchmarks").rglob("*.py"))
         + sorted((root / "examples").rglob("*.py"))
-        + [root / "bench.py", root / "__graft_entry__.py"]
+        + [root / "bench.py", root / "chip_smoke.py", root / "__graft_entry__.py"]
     )
     def bound_names(node):
         for a in node.names:

@@ -1,6 +1,6 @@
 """Tripwire: every model/plugin config field must be CONSUMED somewhere in the package.
 
-Round-1 VERDICT called out accepted-but-ignored flags as worse than errors
+An early review called out accepted-but-ignored flags as worse than errors
 ("dead/misleading plugin knobs"). Originally a regex grep over five hardcoded config
 classes; now a call into graftlint's dead-knob rule (``accelerate_tpu/analysis/``),
 which covers EVERY ``@dataclass`` in the package via real AST attribute-access

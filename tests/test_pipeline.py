@@ -719,7 +719,7 @@ def _packed_batch(vocab: int, B: int, seq_len: int, seed: int) -> dict:
 @pytest.mark.parametrize("family", ["llama", "gpt"])
 @pytest.mark.parametrize("schedule,M", [("gpipe", 4), ("1f1b", 8)])
 def test_pp_packed_matches_single(family, schedule, M):
-    """Sample packing composes with pipeline parallelism (VERDICT r3 #7): segment ids /
+    """Sample packing composes with pipeline parallelism: segment ids /
     per-segment positions ride the pipeline as per-microbatch side constants (indexed by
     microbatch id, never ppermuted), restricting attention to the block-diagonal mask in
     every stage. Parity of loss AND grads vs the non-pipelined packed path, both
@@ -761,8 +761,7 @@ def test_pp_packed_matches_single(family, schedule, M):
 @pytest.mark.parametrize("schedule,M", [("gpipe", 4), ("1f1b", 8)])
 @pytest.mark.parametrize("loss_impl", ["fused", "fused_tp"])
 def test_gpt_pp_fused_loss_matches_single(schedule, M, loss_impl):
-    """gpt's pipeline carries the FULL loss_impl contract (VERDICT r3 #4 — llama got
-    the every-loss-impl-under-pp treatment first): the fused Pallas CE kernels dispatch
+    """gpt's pipeline carries the FULL loss_impl contract: the fused Pallas CE kernels dispatch
     from the gpt head on both schedules, because ln_f + head run outside the pipe on the
     full batch. fused_tp keeps the head vocab-sharded over tp (Megatron layout,
     reference megatron_lm.py:588's GPT loss)."""
@@ -1182,8 +1181,7 @@ def test_prepare_pippy_softcap_and_unknown_config():
      ("ulysses", "gpipe", 4), ("allgather", "1f1b", 4)],
 )
 def test_llama_pp_sp_attention_matches_single(mode, schedule, M):
-    """sp attention TRAINS inside the pipeline (VERDICT r3 #10 — formerly a
-    NotImplementedError): the pipeline's shard_map goes manual over sp too, activations
+    """sp attention TRAINS inside the pipeline: the pipeline's shard_map goes manual over sp too, activations
     ride sequence-sliced, and the stage body issues the ring/ulysses collectives
     directly (flat shard_map, no nesting — the nested form failed MLIR verification on
     the backward). Loss and ALL grads match the non-pipelined, non-sp run at

@@ -46,8 +46,7 @@ def test_training_decreases_loss():
 @pytest.mark.parametrize("with_mask", [False, True])
 @pytest.mark.parametrize("schedule,M", [("gpipe", 2), ("gpipe", 4), ("1f1b", 4)])
 def test_t5_pp_matches_single(with_mask, schedule, M):
-    """T5 through the pipeline (VERDICT r3 #5 — reference Megatron pipelines T5,
-    megatron_lm.py:720): encoder stages then decoder stages chained over the same pp
+    """T5 through the pipeline: encoder stages then decoder stages chained over the same pp
     axis, enc_out delivered to cross-attention as a differentiable side constant.
     Loss AND full grads (incl. the lifted rel-bias tables, whose per-stage broadcast
     grads must sum back into one table) match the non-pipelined run."""

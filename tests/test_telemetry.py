@@ -1,7 +1,7 @@
 """Telemetry subsystem tests (CPU backend): the bench_rev-2 lessons as a library.
 
 Covers the ISSUE-2 acceptance surface: SteadyStateDetector semantics on synthetic
-series including the PERF_NOTES transient shape, fenced-timer correctness (fence on a
+series including the settling-transient shape, fenced-timer correctness (fence on a
 1-element target, never the full result), compile-counter increments across an
 intentional recompile, JSONL record schema round-trip, disabled-mode zero-overhead
 (zero records AND zero extra ``block_until_ready`` calls), bench/library detector
@@ -34,14 +34,14 @@ from accelerate_tpu.utils.dataclasses import ProfileKwargs, TelemetryConfig
 
 # ------------------------------------------------------------- SteadyStateDetector
 
-#: The PERF_NOTES.md shape: ~10 s allocator-settling first round(s), then steady
+#: The settling-transient shape: ~10 s allocator-settling first round(s), then steady
 #: ~0.46 s steps. Pre-rev-2 timing averaged the 10 s into the metric (2.4x under).
-PERF_NOTES_SERIES = [10.2, 2.1, 0.47, 0.46, 0.465, 0.47]
+SETTLING_SERIES = [10.2, 2.1, 0.47, 0.46, 0.465, 0.47]
 
 
 def test_detector_perf_notes_transient_labeled_not_averaged():
     det = SteadyStateDetector(k=2, rtol=0.10, max_windows=5)
-    results = [det.observe(dt) for dt in PERF_NOTES_SERIES]
+    results = [det.observe(dt) for dt in SETTLING_SERIES]
     # Steady exactly when the first agreeing pair completes (0.47, 0.46).
     assert results == [False, False, False, True, True, True]
     assert det.steady and not det.capped
@@ -111,7 +111,7 @@ def _bench_rev2_inline_warmup(series, cap=5, rtol=0.10):
 @pytest.mark.parametrize(
     "series",
     [
-        PERF_NOTES_SERIES,
+        SETTLING_SERIES,
         [1.0, 1.0, 1.0],
         [5.0, 3.0, 2.0, 1.5, 1.45, 1.44],
         [8.0, 4.0, 2.0, 1.0, 0.5, 0.25],  # never settles: cap behavior
@@ -238,7 +238,9 @@ def test_peak_tflops_table():
     assert peak_tflops(device_kind="TPU v5 lite") == 196.6
     assert peak_tflops(device_kind="TPU v5p") == 459.0
     assert peak_tflops(device_kind="TPU v5") == 459.0  # longest-match wins over v5*
-    assert peak_tflops(device_kind="cpu") == 0.5
+    for kind in ("cpu", "unknown"):  # no row, no default: an error, never a guess
+        with pytest.raises(KeyError):
+            peak_tflops(device_kind=kind)
 
 
 # ------------------------------------------------------------ record schema / JSONL
@@ -355,12 +357,12 @@ def test_enabled_records_flow_to_jsonl_tracker(tmp_path):
     assert merged and any(k.startswith("telemetry/") for k in merged[-1])
 
 
-def test_mfu_reported_with_flop_hint(tmp_path):
+def test_flop_hint_reports_tflops_and_no_mfu_without_a_peak(tmp_path):
     cfg = TelemetryConfig(enabled=True, flops_per_step=1e6)
     acc, _, _ = _tiny_training(cfg, n_steps=3)
     rec = acc.telemetry.last_step_record
-    assert rec["mfu"] > 0
     assert rec["achieved_tflops_per_chip"] > 0
+    assert "mfu" not in rec  # the CPU has no datasheet peak to be a share of
     acc.telemetry.close()
 
 

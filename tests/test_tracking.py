@@ -83,7 +83,7 @@ def test_jsonl_media_round_trip(tmp_path):
 
 
 def test_tensorboard_media_round_trip(tmp_path):
-    """VERDICT r3 #9: an image and a table written through the TensorBoard tracker must
+    """An image and a table written through the TensorBoard tracker must
     be readable back from the offline event files (reference tracking.py:251,360)."""
     import numpy as np
     import pytest

@@ -131,6 +131,26 @@ def test_get_tpu_info_probes():
     assert "gce_accelerator" not in info or isinstance(info["gce_accelerator"], str)
 
 
+def test_place_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set → the helper touches no config (jax reads the
+    variable itself); unset → the fixed ``<checkout>/.jax_cache``, equal across calls
+    (the directory is part of the cache key: a moving path never hits)."""
+    import jax
+
+    from accelerate_tpu.utils.environment import place_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert place_compile_cache() is None and updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert place_compile_cache() == place_compile_cache() == os.path.join(repo, ".jax_cache")
+    assert [u for u in updates if u[0] == "jax_compilation_cache_dir"] == [
+        ("jax_compilation_cache_dir", os.path.join(repo, ".jax_cache"))] * 2
+    assert place_compile_cache("/elsewhere") == "/elsewhere"
+
+
 def test_parity_helper_apis(tmp_path):
     """Reference-parity helpers: find_device, merge_dicts, is_port_in_use, version probes,
     write_basic_config (reference utils/__init__ surface)."""
