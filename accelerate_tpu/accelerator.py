@@ -44,6 +44,7 @@ from .parallel.fsdp import shard_params
 from .parallel.mesh import MeshConfig, mesh_context, replicated as _mesh_replicated
 from .scheduler import AcceleratedScheduler
 from .state import AcceleratorState, GradientState
+from .telemetry.tracing import step_phase
 from .utils.constants import BATCH_AXES
 from .utils.dataclasses import (
     DataLoaderConfiguration,
@@ -171,7 +172,8 @@ class _TrainStep:
         if tel_on:
             tel._step_begin()
         try:
-            state, metrics = self._dispatch(acc, tel if tel_on else None, state, batch)
+            with step_phase("train.step", acc.step):
+                state, metrics = self._dispatch(acc, tel if tel_on else None, state, batch)
         except BaseException:
             if tel_on:
                 tel._step_abort()  # a failed step must not leak the compile label
@@ -313,11 +315,12 @@ class _FusedTrainStep:
         if tel_on:
             tel._step_begin()
         try:
-            stacked = self._stack(batches)
-            with mesh_context(acc.mesh):
-                state = acc._offload_fetch(state, opt=True)
-                state, metrics = self.fused_fn(state, stacked)
-                state = acc._offload_stash(state, opt=True)
+            with step_phase("train.step", acc.step):
+                stacked = self._stack(batches)
+                with mesh_context(acc.mesh):
+                    state = acc._offload_fetch(state, opt=True)
+                    state, metrics = self.fused_fn(state, stacked)
+                    state = acc._offload_stash(state, opt=True)
         except BaseException:
             if tel_on:
                 tel._step_abort()  # a failed step must not leak the compile label
@@ -1241,7 +1244,8 @@ class Accelerator:
             )
 
         def apply_step(state: TrainState, batch):
-            loss, aux, grads, new_fp8 = compute(state, batch)
+            with jax.named_scope("loss_and_grad"):
+                loss, aux, grads, new_fp8 = compute(state, batch)
             if state.grad_accum is not None:
                 grads = jax.tree_util.tree_map(jnp.add, state.grad_accum, grads)
             if accum_steps > 1:
@@ -1275,46 +1279,50 @@ class Accelerator:
                     if self.mesh is not None and self.mesh.size > 1:
                         fused_opt = None
             grad_scale = None
-            if max_grad_value is not None:
-                # Elementwise clamp BEFORE the norm clip (a torch user calls
-                # clip_grad_value_ then clip_grad_norm_ in that order between backward
-                # and step; the norm below is the norm of the clamped tree). Unlike the
-                # norm clip this cannot fold into the fused apply's scalar grad_scale —
-                # it materializes a clipped tree either way.
-                v = jnp.asarray(max_grad_value, jnp.float32)
-                grads = jax.tree_util.tree_map(
-                    lambda g: jnp.clip(g, -v.astype(g.dtype), v.astype(g.dtype)), grads
-                )
-            if max_grad_norm is not None:
-                gnorm = _global_norm(grads)
-                scale = jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
-                metrics["grad_norm"] = jnp.asarray(gnorm, jnp.float32)
-                if fused_opt is None:
-                    grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-                else:
-                    grad_scale = scale
+            with jax.named_scope("clip"):
+                if max_grad_value is not None:
+                    # Elementwise clamp BEFORE the norm clip (a torch user calls
+                    # clip_grad_value_ then clip_grad_norm_ in that order between
+                    # backward and step; the norm below is the norm of the clamped
+                    # tree). Unlike the norm clip this cannot fold into the fused
+                    # apply's scalar grad_scale — it materializes a clipped tree
+                    # either way.
+                    v = jnp.asarray(max_grad_value, jnp.float32)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: jnp.clip(g, -v.astype(g.dtype), v.astype(g.dtype)), grads
+                    )
+                if max_grad_norm is not None:
+                    gnorm = _global_norm(grads)
+                    scale = jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
+                    metrics["grad_norm"] = jnp.asarray(gnorm, jnp.float32)
+                    if fused_opt is None:
+                        grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+                    else:
+                        grad_scale = scale
             import optax
 
-            if fused_opt is not None:
-                new_params, new_opt_state = fused_opt(
-                    grads, state.opt_state, state.params,
-                    grad_scale=1.0 if grad_scale is None else grad_scale,
-                    specs=fused_specs,
-                    mesh=self.mesh if fused_specs is not None else None,
-                )
-                updates = None
-            else:
-                updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-            if self._zero_opt_specs is not None:
-                # ZeRO-1/2: keep optimizer state partitioned over the fsdp axis across steps
-                # (params replicated; GSPMD all-gathers the sharded updates below).
-                from .ops.collectives import maybe_shard
+            with jax.named_scope("optimizer"):
+                if fused_opt is not None:
+                    new_params, new_opt_state = fused_opt(
+                        grads, state.opt_state, state.params,
+                        grad_scale=1.0 if grad_scale is None else grad_scale,
+                        specs=fused_specs,
+                        mesh=self.mesh if fused_specs is not None else None,
+                    )
+                    updates = None
+                else:
+                    updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
+                if self._zero_opt_specs is not None:
+                    # ZeRO-1/2: keep optimizer state partitioned over the fsdp axis
+                    # across steps (params replicated; GSPMD all-gathers the sharded
+                    # updates below).
+                    from .ops.collectives import maybe_shard
 
-                new_opt_state = jax.tree_util.tree_map(
-                    lambda o, s: maybe_shard(o, s), new_opt_state, self._zero_opt_specs
-                )
-            if updates is not None:
-                new_params = optax.apply_updates(state.params, updates)
+                    new_opt_state = jax.tree_util.tree_map(
+                        lambda o, s: maybe_shard(o, s), new_opt_state, self._zero_opt_specs
+                    )
+                if updates is not None:
+                    new_params = optax.apply_updates(state.params, updates)
             if self._zero_param_specs is not None:
                 from .ops.collectives import maybe_shard
 

@@ -238,7 +238,8 @@ def multi_step_decode(forward_one: Callable, cache, tokens: jax.Array,
         cache, tok, pos, done, count = carry
         write_pos = jnp.where(done, jnp.int32(max_len), pos)
         logits, cache = forward_one(cache, tok, write_pos)
-        nxt = select_token(logits, x)
+        with jax.named_scope("sample"):
+            nxt = select_token(logits, x)
         nxt = jnp.where(done, tok, nxt)
         emit = ~done
         count = count + emit.astype(jnp.int32)

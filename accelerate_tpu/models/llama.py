@@ -530,38 +530,40 @@ def _block(x, layer, positions, mask, cfg: LlamaConfig, segment_ids=None):
     """One transformer block → (x, moe_aux_loss) (aux is 0.0 for dense MLPs)."""
     B, S, D = x.shape
     p1 = cfg.norm_plus_one
-    h = _rms_norm(x, layer["ln_attn"], cfg.norm_eps, p1)
-    q, k, v = _qkv_proj(h, layer, cfg)
-    q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    q = _rope(q, positions, cfg)
-    k = _rope(k, positions, cfg)
-    attn = _attention(q, k, v, mask, cfg, segment_ids).reshape(
-        B, S, cfg.n_heads * cfg.head_dim
-    )
-    attn_out = _proj_l(attn, layer, "wo", cfg)
-    if cfg.post_norm:  # Gemma-2: normalize the sublayer OUTPUT before the residual add
-        attn_out = _rms_norm(attn_out, layer["ln_attn_post"], cfg.norm_eps, p1)
-    x = x + attn_out
-    h = _rms_norm(x, layer["ln_mlp"], cfg.norm_eps, p1)
-    if cfg.moe_experts > 0:
-        from ..ops.moe import moe_mlp
-
-        y, aux = moe_mlp(
-            h, layer["moe"], layer["moe"]["w_router"],
-            top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-            compute_dtype=cfg.dtype,
-            # Packing: pad slots neither claim expert capacity nor bias the aux stat.
-            token_mask=None if segment_ids is None else (segment_ids != 0),
+    with jax.named_scope("attn"):
+        h = _rms_norm(x, layer["ln_attn"], cfg.norm_eps, p1)
+        q, k, v = _qkv_proj(h, layer, cfg)
+        q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        q = _rope(q, positions, cfg)
+        k = _rope(k, positions, cfg)
+        attn = _attention(q, k, v, mask, cfg, segment_ids).reshape(
+            B, S, cfg.n_heads * cfg.head_dim
         )
-        return x + y, aux
-    gate = _mlp_gate_act(_proj_l(h, layer, "w_gate", cfg), cfg)
-    up = _proj_l(h, layer, "w_up", cfg)
-    mlp_out = _proj_l(gate * up, layer, "w_down", cfg)
-    if cfg.post_norm:
-        mlp_out = _rms_norm(mlp_out, layer["ln_mlp_post"], cfg.norm_eps, p1)
-    x = x + mlp_out
+        attn_out = _proj_l(attn, layer, "wo", cfg)
+        if cfg.post_norm:  # Gemma-2: normalize the sublayer OUTPUT before the residual add
+            attn_out = _rms_norm(attn_out, layer["ln_attn_post"], cfg.norm_eps, p1)
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, layer["ln_mlp"], cfg.norm_eps, p1)
+        if cfg.moe_experts > 0:
+            from ..ops.moe import moe_mlp
+
+            y, aux = moe_mlp(
+                h, layer["moe"], layer["moe"]["w_router"],
+                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+                compute_dtype=cfg.dtype,
+                # Packing: pad slots neither claim expert capacity nor bias the aux stat.
+                token_mask=None if segment_ids is None else (segment_ids != 0),
+            )
+            return x + y, aux
+        gate = _mlp_gate_act(_proj_l(h, layer, "w_gate", cfg), cfg)
+        up = _proj_l(h, layer, "w_up", cfg)
+        mlp_out = _proj_l(gate * up, layer, "w_down", cfg)
+        if cfg.post_norm:
+            mlp_out = _rms_norm(mlp_out, layer["ln_mlp_post"], cfg.norm_eps, p1)
+        x = x + mlp_out
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -636,9 +638,10 @@ def forward_hidden(
             if segment_ids is not None
             else jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         )
-    x = params["embed"].astype(dtype)[tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dtype)[tokens]
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
     if shard_activations:
         x = _maybe_shard(x, P(BATCH_AXES, SEQUENCE_AXIS, None))
     if segment_ids is not None:
@@ -761,9 +764,10 @@ def _chunked_ce(x, head, targets, mask, chunk: int, dtype, final_softcap: float 
 
 def _ce_from_hidden(x, params, targets, mask, cfg: LlamaConfig) -> jax.Array:
     """Cross-entropy from post-ln_f hidden states (chunked when ``cfg.loss_chunk`` says so)."""
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    denom = jnp.maximum(mask.sum(), 1.0)
-    return _ce_sum_impl(x, head, targets, mask, cfg) / denom
+    with jax.named_scope("head_ce"):
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        denom = jnp.maximum(mask.sum(), 1.0)
+        return _ce_sum_impl(x, head, targets, mask, cfg) / denom
 
 
 def _ce_sum_impl(x, head, targets, mask, cfg: LlamaConfig) -> jax.Array:
@@ -1299,61 +1303,67 @@ def _block_cached(x, layer, kv, index, positions, valid, cfg: LlamaConfig,
     if moe_dense is None:
         moe_dense = T == 1
     p1 = cfg.norm_plus_one
-    h = _rms_norm(x, layer["ln_attn"], cfg.norm_eps, p1)
-    q, k, v = _qkv_proj(h, layer, cfg)
-    q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
-    k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    q = _rope(q, positions, cfg)
-    k = _rope(k, positions, cfg)
-    if paged is not None:
-        tables, pages, offs, start_pos, page_size = paged
-        new_kv = {**_write_cache_paged(kv, "k", k, pages, offs),
-                  **_write_cache_paged(kv, "v", v, pages, offs)}
-        attn = _paged_attention(
-            q, new_kv, tables, start_pos, valid, page_size=page_size,
-            sm_scale=_sm_scale(cfg), window=cfg.sliding_window,
-            softcap=cfg.attn_softcap, dtype=cfg.dtype,
-            dense_attention=lambda ck, cv: _attention_cached(
-                q, ck, cv, positions, valid, cfg
-            ),
-        )
-    else:
-        new_kv = {**_write_cache(kv, "k", k, index), **_write_cache(kv, "v", v, index)}
-        attn = _attention_cached(
-            q, _read_cache(new_kv, "k", cfg.dtype), _read_cache(new_kv, "v", cfg.dtype),
-            positions, valid, cfg,
-        )
-    attn_out = _proj_l(attn.reshape(B, T, cfg.n_heads * cfg.head_dim), layer, "wo", cfg)
-    if cfg.post_norm:
-        attn_out = _rms_norm(attn_out, layer["ln_attn_post"], cfg.norm_eps, p1)
-    x = x + attn_out
-    h = _rms_norm(x, layer["ln_mlp"], cfg.norm_eps, p1)
-    if cfg.moe_experts > 0:
-        from ..ops.moe import moe_mlp, moe_mlp_dense
-
-        if moe_dense:
-            # Decode: drop-free dense routing — capacity pooling over a single-token step
-            # would drop tokens whenever a step's rows collide on an expert (training's
-            # fixed-shape load-management artifact, wrong for inference).
-            y = moe_mlp_dense(
-                h, layer["moe"], layer["moe"]["w_router"],
-                top_k=cfg.moe_top_k, compute_dtype=cfg.dtype,
+    with jax.named_scope("attn"):
+        h = _rms_norm(x, layer["ln_attn"], cfg.norm_eps, p1)
+        q, k, v = _qkv_proj(h, layer, cfg)
+        q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
+        k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+        q = _rope(q, positions, cfg)
+        k = _rope(k, positions, cfg)
+        if paged is not None:
+            tables, pages, offs, start_pos, page_size = paged
+            with jax.named_scope("kv_write"):
+                new_kv = {**_write_cache_paged(kv, "k", k, pages, offs),
+                          **_write_cache_paged(kv, "v", v, pages, offs)}
+            attn = _paged_attention(
+                q, new_kv, tables, start_pos, valid, page_size=page_size,
+                sm_scale=_sm_scale(cfg), window=cfg.sliding_window,
+                softcap=cfg.attn_softcap, dtype=cfg.dtype,
+                dense_attention=lambda ck, cv: _attention_cached(
+                    q, ck, cv, positions, valid, cfg
+                ),
             )
         else:
-            # Prefill: identical pooled formulation (and token set) as the training forward.
-            y, _ = moe_mlp(
-                h, layer["moe"], layer["moe"]["w_router"],
-                top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
-                compute_dtype=cfg.dtype,
+            with jax.named_scope("kv_write"):
+                new_kv = {**_write_cache(kv, "k", k, index),
+                          **_write_cache(kv, "v", v, index)}
+            attn = _attention_cached(
+                q, _read_cache(new_kv, "k", cfg.dtype), _read_cache(new_kv, "v", cfg.dtype),
+                positions, valid, cfg,
             )
-        return x + y, new_kv
-    gate = _mlp_gate_act(_proj_l(h, layer, "w_gate", cfg), cfg)
-    up = _proj_l(h, layer, "w_up", cfg)
-    mlp_out = _proj_l(gate * up, layer, "w_down", cfg)
-    if cfg.post_norm:
-        mlp_out = _rms_norm(mlp_out, layer["ln_mlp_post"], cfg.norm_eps, cfg.norm_plus_one)
-    x = x + mlp_out
+        attn_out = _proj_l(attn.reshape(B, T, cfg.n_heads * cfg.head_dim), layer, "wo", cfg)
+        if cfg.post_norm:
+            attn_out = _rms_norm(attn_out, layer["ln_attn_post"], cfg.norm_eps, p1)
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        h = _rms_norm(x, layer["ln_mlp"], cfg.norm_eps, p1)
+        if cfg.moe_experts > 0:
+            from ..ops.moe import moe_mlp, moe_mlp_dense
+
+            if moe_dense:
+                # Decode: drop-free dense routing — capacity pooling over a single-token
+                # step would drop tokens whenever a step's rows collide on an expert
+                # (training's fixed-shape load-management artifact, wrong for inference).
+                y = moe_mlp_dense(
+                    h, layer["moe"], layer["moe"]["w_router"],
+                    top_k=cfg.moe_top_k, compute_dtype=cfg.dtype,
+                )
+            else:
+                # Prefill: identical pooled formulation (and token set) as the training
+                # forward.
+                y, _ = moe_mlp(
+                    h, layer["moe"], layer["moe"]["w_router"],
+                    top_k=cfg.moe_top_k, capacity_factor=cfg.moe_capacity_factor,
+                    compute_dtype=cfg.dtype,
+                )
+            return x + y, new_kv
+        gate = _mlp_gate_act(_proj_l(h, layer, "w_gate", cfg), cfg)
+        up = _proj_l(h, layer, "w_up", cfg)
+        mlp_out = _proj_l(gate * up, layer, "w_down", cfg)
+        if cfg.post_norm:
+            mlp_out = _rms_norm(mlp_out, layer["ln_mlp_post"], cfg.norm_eps, p1)
+        x = x + mlp_out
     return x, new_kv
 
 
@@ -1390,9 +1400,10 @@ def forward_cached(
     dtype = cfg.dtype
     index, positions, valid = _cache_advance(cache, tokens, token_mask)
 
-    x = params["embed"].astype(dtype)[tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dtype)[tokens]
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
     alternating = bool(cfg.sliding_window) and cfg.window_every > 1
     if cfg.scan_layers and alternating:
         # Same grouped scan as forward_hidden: layer j of each group is banded iff j == 0.
@@ -1499,9 +1510,10 @@ def forward_slots(
             tables, pos_grid, page_size, cache["valid"].shape[1], num_pages
         )
         paged = (tables, pages, offs, positions, page_size)
-    x = params["embed"][tokens].astype(cfg.dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.dtype)
     alternating = bool(cfg.sliding_window) and cfg.window_every > 1
     if cfg.scan_layers and alternating:
         # Mirror forward_cached's grouped scan: layer j of each window_every-group is

@@ -292,6 +292,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_
     dot_flops = 4 * B * H * Sp * Tp * hd * (0.5 if causal else 1.0)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(B, H, nq, nk),
         in_specs=[
             _smem_scalar_spec(),
@@ -546,6 +547,7 @@ def _bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interpr
     dot_flops = 8 * B * H * Sp * Tp * hd * (0.5 if causal else 1.0)
     dq = pl.pallas_call(
         kernel,
+        name="flash_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=[
             _smem_scalar_spec(),
@@ -611,6 +613,7 @@ def _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interp
     dot_flops = 10 * B * H * Sp * Tp * hd * (0.5 if causal else 1.0)
     dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_dkv",
         grid=(B, K, nk, reps * nq),
         in_specs=[
             _smem_scalar_spec(),

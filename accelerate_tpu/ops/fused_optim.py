@@ -174,6 +174,7 @@ def _leaf_fused(p, m, v, g, scalars, *, b1, b2, eps, wd, block_rows, interpret):
     spec = pl.BlockSpec((br, _LANES), lambda i: (i, 0))
     po, mo, vo = pl.pallas_call(
         kernel,
+        name="fused_adamw",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -238,6 +238,7 @@ def _launch_fwd(kernel_fn, n_outputs, x, w, t2, *, vocab, softcap, block_t, bloc
     stat_spec = pl.BlockSpec((block_t, 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         functools.partial(kernel_fn, block_v=block_v, vocab=vocab, softcap=softcap),
+        name="fused_xent_fwd",
         grid=(nt, nv),
         in_specs=[
             stat_spec,
@@ -270,6 +271,7 @@ def _fce_bwd(vocab, softcap, block_t, block_v, interpret, res, g):
     common = dict(block_v=block_v, vocab=vocab, softcap=softcap)
     dx = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, **common),
+        name="fused_xent_bwd_dx",
         grid=(nt, nv),
         in_specs=[
             pl.BlockSpec((block_t, 1), lambda i, j: (i, 0)),
@@ -290,6 +292,7 @@ def _fce_bwd(vocab, softcap, block_t, block_v, interpret, res, g):
 
     dw = pl.pallas_call(
         functools.partial(_bwd_dw_kernel, **common),
+        name="fused_xent_bwd_dw",
         grid=(nv, nt),
         in_specs=[
             pl.BlockSpec((block_t, 1), lambda j, i: (i, 0)),

@@ -267,6 +267,7 @@ def paged_attention(q, pool, tables, positions, valid, *, page_size, sm_scale,
     kv_itemsize = pool["k"].dtype.itemsize
     out = pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, R, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -191,6 +191,7 @@ def _quant_matmul_pallas_int8(x, qw: QuantizedWeight, block_m=128, block_k=128, 
     grid = (xp.shape[0] // bm, wp.shape[1] // bn, xp.shape[1] // bk)
     out = pl.pallas_call(
         _int8_matmul_kernel,
+        name="quant_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

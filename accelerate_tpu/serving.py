@@ -86,6 +86,7 @@ from .telemetry.schemas import (
     SERVING_THROUGHPUT_SCHEMA,
 )
 from .telemetry.slo import latency_summary
+from .telemetry.tracing import EnginePhase, phase
 from .utils.dataclasses import CompileCacheConfig
 
 __all__ = ["ContinuousBatcher", "KVBudgetError", "KVHandoff", "Request",
@@ -249,7 +250,9 @@ def _decode_step(params, cache, tokens, positions, cfg):
     when a sampled (temperature > 0) request is active."""
     logits, cache = llama.forward_slots(params, tokens[:, None], cache, positions, cfg)
     logits = logits[:, -1, :]
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return greedy, logits, cache
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
@@ -341,7 +344,9 @@ def _decode_step_paged(params, cache, tables, tokens, positions, cfg, page_size:
         params, tokens[:, None], cache, tables, positions, cfg, page_size
     )
     logits = logits[:, -1, :]
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return greedy, logits, cache
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size"), donate_argnums=(1,))
@@ -1459,63 +1464,73 @@ class ContinuousBatcher:
         request), its lane/pages are released, and the survivors' state is
         rebuilt from prompt + already-emitted tokens so the next ``step()``
         continues the workload (docs/resilience.md)."""
-        if self.role == "prefill":
-            return self._prefill_role_step()
-        finished_at_admit = self._admit()
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        self.peak_active_slots = max(self.peak_active_slots, len(active))
-        if not active:
-            if self._bisect_hold:
-                # No probe can run (every lane drained — e.g. the whole probe
-                # half was quarantined or finished): the held suspects are the
-                # only remaining work, and nothing else can exonerate them.
-                # Release them or they would be stranded forever — run()'s
-                # drain would exit (queue and lanes empty) with live requests
-                # parked in the hold, a silent loss.
-                self._release_bisect_hold()
-            if finished_at_admit:
-                self._emit_telemetry()  # admissions alone still move the counters
-            return finished_at_admit
-        # Decode-path routing: speculation wins while enabled (it already emits
-        # multiple tokens per dispatch); the multi-step super-step is BOTH the
-        # standalone fused path and what speculation degrades into when the
-        # gateway's pressure rungs flip ``spec_enabled`` off — safe mid-request,
-        # because every path consumes the same emission-indexed key schedule.
-        use_spec = self.spec_k and self.spec_enabled
-        if use_spec and self._spec_fused():
-            # Fused speculative super-step: N draft→verify→accept rounds in ONE
-            # dispatch (docs/speculative_serving.md). Flipping spec off lands on
-            # the plain decode_multi super-step below, never on N=1.
-            decode = self._spec_multi
-        elif use_spec:
-            decode = self._spec_step
-        elif self.multi_step > 1:
-            decode = self._multi_step
-        else:
-            decode = self._plain_step
-        if not self.recover:
-            finished = decode(active)
-        else:
-            active_reqs = [self.slot_req[i] for i in active]
-            try:
-                finished = decode(active)
-            except EngineCrashed:
-                # A crash is the death of the whole engine, not a step fault:
-                # no in-engine quarantine/rebuild is possible — it propagates
-                # to the replica's owner (the fleet router's failover path).
-                raise
-            except Exception as e:  # the fault boundary: quarantine + rebuild
-                finished = self._recover_step_failure(e, active_reqs)
+        lanes = self.max_slots - self.slot_req.count(None)
+        with phase("engine.step", queued=len(self.queue), lanes=lanes):
+            if self.role == "prefill":
+                return self._prefill_role_step(lanes)
+            with phase("engine.admit", lanes=lanes):
+                finished_at_admit = self._admit()
+            active = [i for i, r in enumerate(self.slot_req) if r is not None]
+            self.peak_active_slots = max(self.peak_active_slots, len(active))
+            if not active:
+                if self._bisect_hold:
+                    # No probe can run (every lane drained — e.g. the whole probe
+                    # half was quarantined or finished): the held suspects are the
+                    # only remaining work, and nothing else can exonerate them.
+                    # Release them or they would be stranded forever — run()'s
+                    # drain would exit (queue and lanes empty) with live requests
+                    # parked in the hold, a silent loss.
+                    self._release_bisect_hold()
+                if finished_at_admit:
+                    self._emit_telemetry()  # admissions alone still move the counters
+                return finished_at_admit
+            # Decode-path routing: speculation wins while enabled (it already emits
+            # multiple tokens per dispatch); the multi-step super-step is BOTH the
+            # standalone fused path and what speculation degrades into when the
+            # gateway's pressure rungs flip ``spec_enabled`` off — safe mid-request,
+            # because every path consumes the same emission-indexed key schedule.
+            use_spec = self.spec_k and self.spec_enabled
+            n_steps = 1
+            if use_spec and self._spec_fused():
+                # Fused speculative super-step: N draft→verify→accept rounds in ONE
+                # dispatch (docs/speculative_serving.md). Flipping spec off lands on
+                # the plain decode_multi super-step below, never on N=1.
+                decode, n_steps = self._spec_multi, self.multi_step
+            elif use_spec:
+                decode = self._spec_step
+            elif self.multi_step > 1:
+                decode, n_steps = self._multi_step, self.multi_step
             else:
-                self._after_clean_step(active_reqs)
-        self.evicted += len(finished)
-        self._emit_telemetry()
-        # Report in submission order (uid is the admission counter), not slot order —
-        # slot assignment is an engine detail a client should never observe.
-        return sorted(finished_at_admit + finished, key=lambda r: r.uid)
+                decode = self._plain_step
+            # The decode path chosen, all of it: one phase, and the tracer-clock t0
+            # every path's per-lane "decode" span records share.
+            ph = EnginePhase(self.tracer, "engine.decode", lanes=len(active),
+                             n_steps=n_steps)
+            if not self.recover:
+                with ph:
+                    finished = decode(active, ph)
+            else:
+                active_reqs = [self.slot_req[i] for i in active]
+                try:
+                    with ph:
+                        finished = decode(active, ph)
+                except EngineCrashed:
+                    # A crash is the death of the whole engine, not a step fault:
+                    # no in-engine quarantine/rebuild is possible — it propagates
+                    # to the replica's owner (the fleet router's failover path).
+                    raise
+                except Exception as e:  # the fault boundary: quarantine + rebuild
+                    finished = self._recover_step_failure(e, active_reqs)
+                else:
+                    self._after_clean_step(active_reqs)
+            self.evicted += len(finished)
+            self._emit_telemetry()
+            # Report in submission order (uid is the admission counter), not slot order —
+            # slot assignment is an engine detail a client should never observe.
+            return sorted(finished_at_admit + finished, key=lambda r: r.uid)
 
     # ------------------------------------------------------- disaggregated roles
-    def _prefill_role_step(self) -> list[Request]:
+    def _prefill_role_step(self, lanes: int) -> list[Request]:
         """Prefill-role ``step()``: admit queued requests (compiled prefill —
         the normal admission path, fault boundary included), then EXPORT every
         admitted lane as a :class:`KVHandoff` and free it. Lanes are transient:
@@ -1523,7 +1538,8 @@ class ContinuousBatcher:
         lanes are empty again — the replica is a prefill pump, never a decode
         host. Returns only requests that finished AT admission (EOS or a
         1-token budget — those never need a handoff)."""
-        finished = self._admit()
+        with phase("engine.admit", lanes=lanes):
+            finished = self._admit()
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         self.peak_active_slots = max(self.peak_active_slots, len(active))
         for slot in active:
@@ -1693,27 +1709,26 @@ class ContinuousBatcher:
                 on_token(int(tok))
         return req
 
-    def _plain_step(self, active: list[int]) -> list[Request]:
+    def _plain_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
         """Classic decode: ONE compiled dispatch advances every lane one token."""
-        tracer = self.tracer
-        tracing = tracer is not None and tracer.enabled  # the two-attr-read contract
-        t0 = tracer._clock() if tracing else 0.0
+        tracing = ph.tracer is not None
         traced = [self.slot_req[i] for i in active] if tracing else ()
         t_guard = self._pre_dispatch("serving.decode", active)
         if self.paged:
-            with compile_label("serving.decode_paged"):
+            with compile_label("serving.decode_paged"), phase("engine.decode.dispatch"):
                 greedy, logits, self.cache = self._decode_paged_fn(
                     self.params, self.cache, jnp.asarray(self.block_mgr.tables),
                     jnp.asarray(self.tokens), jnp.asarray(self.positions),
                     cfg=self.cfg, page_size=self.page_size,
                 )
         else:
-            with compile_label("serving.decode"):
+            with compile_label("serving.decode"), phase("engine.decode.dispatch"):
                 greedy, logits, self.cache = self._decode_fn(
                     self.params, self.cache, jnp.asarray(self.tokens),
                     jnp.asarray(self.positions), cfg=self.cfg,
                 )
-        greedy_host = np.asarray(greedy)
+        with phase("engine.decode.fetch"):
+            greedy_host = np.asarray(greedy)
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
         finished = []
         # Every lane wrote one slot (idle lanes too — static shapes); clamp so an idle
@@ -1747,15 +1762,20 @@ class ContinuousBatcher:
             # (previous dispatch's end → this one's start): the host dead time
             # trace-report's host-time column aggregates and multi-step decode
             # exists to amortize.
-            t1 = tracer._clock()
-            host_s = self._host_gap(t0, t1)
-            for req in traced:
-                tracer.span(
-                    tracer.handle_for(req.uid), "decode", t0, t1,
-                    step=self.decode_steps, occupancy=len(active), tokens=1,
-                    host_s=host_s,
-                )
+            self._decode_spans(ph, ((req, {}) for req in traced),
+                               occupancy=len(active), tokens=1)
         return finished
+
+    def _decode_spans(self, ph: EnginePhase, lanes, **shared) -> None:
+        """The Tracer's "decode" span records of one dispatch: one per traced
+        lane ``(request, its own attributes)``, all sharing ``ph``'s [t0, now],
+        this dispatch's step index and the measured inter-dispatch gap. Only
+        called while tracing."""
+        t1 = ph.now()
+        host_s = self._host_gap(ph.t0, t1)
+        for req, own in lanes:
+            ph.span(req.uid, "decode", t1, step=self.decode_steps, **shared,
+                    **own, host_s=host_s)
 
     def _host_gap(self, t0: float, t1: float) -> float:
         """Measured inter-dispatch gap for this decode dispatch's spans: previous
@@ -1767,7 +1787,7 @@ class ContinuousBatcher:
         self._last_dispatch_end = t1
         return round(max(0.0, t0 - prev), 9) if prev is not None else 0.0
 
-    def _multi_step(self, active: list[int]) -> list[Request]:
+    def _multi_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
         """Device-resident super-step: ``decode_steps=N`` decode steps in ONE
         dispatched scan (``serving.decode_multi``/``decode_multi_paged``), then
         ONE drain of the [N, B] token buffer.
@@ -1789,93 +1809,96 @@ class ContinuousBatcher:
         granularity (docs/multistep_decode.md)."""
         N = self.multi_step
         B = self.max_slots
-        tracer = self.tracer
-        tracing = tracer is not None and tracer.enabled  # the two-attr-read contract
-        t0 = tracer._clock() if tracing else 0.0
+        tracing = ph.tracer is not None
         traced = [(i, self.slot_req[i]) for i in active] if tracing else ()
-        active_mask = np.zeros((B,), bool)
-        budgets = np.ones((B,), np.int32)   # idle lanes: frozen at step 0, never read
-        eos_ids = np.full((B,), -1, np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        sampled = False
-        key_rows: list = [None] * B
-        for i in active:
-            req = self.slot_req[i]
-            active_mask[i] = True
-            budgets[i] = req.gen.max_new_tokens - len(req.tokens)
-            if req.gen.eos_token_id is not None:
-                eos_ids[i] = req.gen.eos_token_id
-            if req.gen.temperature > 0.0:
-                sampled = True
-                temps[i] = req.gen.temperature
-                top_ps[i] = req.gen.top_p
-                top_ks[i] = req.gen.top_k
-                # Scan step j consumes this lane's key for emission
-                # len(tokens)+j — the exact key Request._sample would hand
-                # _draw at that emission (window clamped at the final key,
-                # like the spec verify surplus: past-budget draws are frozen).
-                key_rows[i] = self._step_keys_window(req, len(req.tokens), N)
-        if sampled:
-            filler = jnp.zeros_like(
-                next(k for k in key_rows if k is not None)
-            )  # greedy/idle lanes: key bits are never consumed (temp 0 → argmax)
-            keys = jnp.stack([k if k is not None else filler for k in key_rows])
-        else:
-            keys = jnp.zeros((B, N, 2), jnp.uint32)
+        with phase("engine.decode.prepare"):
+            active_mask = np.zeros((B,), bool)
+            budgets = np.ones((B,), np.int32)   # idle lanes: frozen at step 0, never read
+            eos_ids = np.full((B,), -1, np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_ps = np.ones((B,), np.float32)
+            top_ks = np.zeros((B,), np.int32)
+            sampled = False
+            key_rows: list = [None] * B
+            for i in active:
+                req = self.slot_req[i]
+                active_mask[i] = True
+                budgets[i] = req.gen.max_new_tokens - len(req.tokens)
+                if req.gen.eos_token_id is not None:
+                    eos_ids[i] = req.gen.eos_token_id
+                if req.gen.temperature > 0.0:
+                    sampled = True
+                    temps[i] = req.gen.temperature
+                    top_ps[i] = req.gen.top_p
+                    top_ks[i] = req.gen.top_k
+                    # Scan step j consumes this lane's key for emission
+                    # len(tokens)+j — the exact key Request._sample would hand
+                    # _draw at that emission (window clamped at the final key,
+                    # like the spec verify surplus: past-budget draws are frozen).
+                    key_rows[i] = self._step_keys_window(req, len(req.tokens), N)
+            if sampled:
+                filler = jnp.zeros_like(
+                    next(k for k in key_rows if k is not None)
+                )  # greedy/idle lanes: key bits are never consumed (temp 0 → argmax)
+                keys = jnp.stack([k if k is not None else filler for k in key_rows])
+            else:
+                keys = jnp.zeros((B, N, 2), jnp.uint32)
+            # Host → device, in the programs' argument order after the cache
+            # (and, paged, the block tables uploaded once per super-step).
+            lane_args = tuple(jnp.asarray(a) for a in (
+                self.tokens, self.positions, active_mask, budgets, eos_ids, keys,
+                temps, top_ps, top_ks))
+            tables = jnp.asarray(self.block_mgr.tables) if self.paged else None
         t_guard = self._pre_dispatch("serving.decode", active)
         if self.paged:
-            with compile_label("serving.decode_multi_paged"):
+            with compile_label("serving.decode_multi_paged"), \
+                    phase("engine.decode.dispatch"):
                 tok_buf, counts, self.cache = self._decode_multi_paged_fn(
-                    self.params, self.cache, jnp.asarray(self.block_mgr.tables),
-                    jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                    jnp.asarray(active_mask), jnp.asarray(budgets),
-                    jnp.asarray(eos_ids), keys, jnp.asarray(temps),
-                    jnp.asarray(top_ps), jnp.asarray(top_ks),
+                    self.params, self.cache, tables, *lane_args,
                     cfg=self.cfg, n_steps=N, sample=sampled,
                     page_size=self.page_size,
                 )
         else:
-            with compile_label("serving.decode_multi"):
+            with compile_label("serving.decode_multi"), \
+                    phase("engine.decode.dispatch"):
                 tok_buf, counts, self.cache = self._decode_multi_fn(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.positions), jnp.asarray(active_mask),
-                    jnp.asarray(budgets), jnp.asarray(eos_ids), keys,
-                    jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks),
+                    self.params, self.cache, *lane_args,
                     cfg=self.cfg, n_steps=N, sample=sampled,
                 )
-        tok_host = np.asarray(tok_buf)     # [N, B]
-        counts_host = np.asarray(counts)   # [B]
+        with phase("engine.decode.fetch"):
+            tok_host = np.asarray(tok_buf)     # [N, B]
+            counts_host = np.asarray(counts)   # [B]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
         # Drain in exact generation order (step-major, lane-minor — the order N
         # sequential _plain_step calls would have appended), clamped to each
         # lane's remaining budget.
-        for j in range(N):
+        with phase("engine.decode.drain") as drain:
+            for j in range(N):
+                for i in active:
+                    req = self.slot_req[i]
+                    if j >= counts_host[i] or len(req.tokens) >= req.gen.max_new_tokens:
+                        continue
+                    tok = int(tok_host[j, i])
+                    req.tokens.append(tok)
+                    if req.on_token is not None:
+                        req.on_token(tok)
+            finished = []
+            step_tokens = 0
             for i in active:
                 req = self.slot_req[i]
-                if j >= counts_host[i] or len(req.tokens) >= req.gen.max_new_tokens:
-                    continue
-                tok = int(tok_host[j, i])
-                req.tokens.append(tok)
-                if req.on_token is not None:
-                    req.on_token(tok)
-        finished = []
-        step_tokens = 0
-        for i in active:
-            req = self.slot_req[i]
-            c = int(counts_host[i])
-            step_tokens += c
-            self.tokens[i] = int(tok_host[c - 1, i])  # the new pending token
-            self.positions[i] += c
-            eos = req.gen.eos_token_id
-            hit_eos = eos is not None and req.tokens and req.tokens[-1] == eos
-            if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
-                req.done = True
-                finished.append(req)
-                self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
-                self._release_lane(i)
-        self.positions = np.minimum(self.positions, self.max_len - 1)
+                c = int(counts_host[i])
+                step_tokens += c
+                self.tokens[i] = int(tok_host[c - 1, i])  # the new pending token
+                self.positions[i] += c
+                eos = req.gen.eos_token_id
+                hit_eos = eos is not None and req.tokens and req.tokens[-1] == eos
+                if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
+                    req.done = True
+                    finished.append(req)
+                    self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
+                    self._release_lane(i)
+            self.positions = np.minimum(self.positions, self.max_len - 1)
+            drain.set_metadata(tokens=step_tokens)
         self.decode_steps += 1
         self.decode_tokens += step_tokens
         if tracing:
@@ -1883,17 +1906,12 @@ class ContinuousBatcher:
             # that lane's real emission count, ``n_steps`` the fused depth, and
             # ``host_s`` the measured inter-dispatch gap — N tokens now share
             # ONE gap, which is the whole point.
-            t1 = tracer._clock()
-            host_s = self._host_gap(t0, t1)
-            for i, req in traced:
-                tracer.span(
-                    tracer.handle_for(req.uid), "decode", t0, t1,
-                    step=self.decode_steps, occupancy=len(active),
-                    tokens=int(counts_host[i]), n_steps=N, host_s=host_s,
-                )
+            self._decode_spans(
+                ph, ((req, {"tokens": int(counts_host[i])}) for i, req in traced),
+                occupancy=len(active), n_steps=N)
         return finished
 
-    def _spec_multi(self, active: list[int]) -> list[Request]:
+    def _spec_multi(self, active: list[int], ph: EnginePhase) -> list[Request]:
         """Fused speculative super-step: ``decode_steps=N`` draft→verify→accept
         rounds in ONE dispatched scan (``serving.spec_multi``/``spec_multi_paged``),
         then ONE drain of the [N, B, spec_k+1] token buffer — speculation with
@@ -1915,9 +1933,7 @@ class ContinuousBatcher:
         k = self.spec_k
         T = k + 1
         B = self.max_slots
-        tracer = self.tracer
-        tracing = tracer is not None and tracer.enabled  # the two-attr-read contract
-        t0 = tracer._clock() if tracing else 0.0
+        tracing = ph.tracer is not None
         traced = [(i, self.slot_req[i]) for i in active] if tracing else ()
         active_mask = np.zeros((B,), bool)
         budgets = np.ones((B,), np.int32)   # idle lanes: frozen at step 0, never read
@@ -1967,7 +1983,8 @@ class ContinuousBatcher:
         max_ngram = int(self.drafter.max_ngram)
         t_guard = self._pre_dispatch("serving.decode", active)
         if self.paged:
-            with compile_label("serving.spec_multi_paged"):
+            with compile_label("serving.spec_multi_paged"), \
+                    phase("engine.decode.dispatch"):
                 tok_buf, emits, counts, proposed, accepted, self.cache = (
                     self._spec_multi_paged_fn(
                         self.params, self.cache,
@@ -1982,7 +1999,8 @@ class ContinuousBatcher:
                     )
                 )
         else:
-            with compile_label("serving.spec_multi"):
+            with compile_label("serving.spec_multi"), \
+                    phase("engine.decode.dispatch"):
                 tok_buf, emits, counts, proposed, accepted, self.cache = (
                     self._spec_multi_fn(
                         self.params, self.cache, jnp.asarray(self.tokens),
@@ -1995,11 +2013,12 @@ class ContinuousBatcher:
                         sample=sampled,
                     )
                 )
-        ref_host = np.asarray(tok_buf)      # [N, B, k+1]
-        emits_host = np.asarray(emits)      # [N, B]
-        counts_host = np.asarray(counts)    # [B]
-        prop_host = np.asarray(proposed)    # [B]
-        acc_host = np.asarray(accepted)     # [B]
+        with phase("engine.decode.fetch"):
+            ref_host = np.asarray(tok_buf)      # [N, B, k+1]
+            emits_host = np.asarray(emits)      # [N, B]
+            counts_host = np.asarray(counts)    # [B]
+            prop_host = np.asarray(proposed)    # [B]
+            acc_host = np.asarray(accepted)     # [B]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
         # Drain in exact generation order (round-major, lane-minor — the order N
         # sequential _spec_step calls would have appended), clamped to each
@@ -2045,16 +2064,11 @@ class ContinuousBatcher:
             # ``proposed``/``accepted`` its per-lane round totals, ``n_steps``
             # the fused depth, ``host_s`` the measured inter-dispatch gap — all
             # N rounds now share ONE gap, which is the whole point.
-            t1 = tracer._clock()
-            host_s = self._host_gap(t0, t1)
-            for i, req in traced:
-                tracer.span(
-                    tracer.handle_for(req.uid), "decode", t0, t1,
-                    step=self.decode_steps, occupancy=len(active),
-                    tokens=int(counts_host[i]), n_steps=N,
-                    proposed=int(prop_host[i]), accepted=int(acc_host[i]),
-                    host_s=host_s,
-                )
+            self._decode_spans(
+                ph, ((req, {"tokens": int(counts_host[i]),
+                            "proposed": int(prop_host[i]),
+                            "accepted": int(acc_host[i])}) for i, req in traced),
+                occupancy=len(active), n_steps=N)
         tel = self.telemetry
         if tel is not None and tel.enabled:
             from .telemetry import TELEMETRY_REV
@@ -2084,7 +2098,7 @@ class ContinuousBatcher:
             })
         return finished
 
-    def _spec_step(self, active: list[int]) -> list[Request]:
+    def _spec_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
         """Speculative decode: propose → ONE fused verify → per-slot prefix acceptance.
 
         Per active slot the emitted tokens are exactly the first ``n_emit`` columns of
@@ -2097,9 +2111,7 @@ class ContinuousBatcher:
         out-of-bounds draft write."""
         k = self.spec_k
         T = k + 1
-        tracer = self.tracer
-        tracing = tracer is not None and tracer.enabled  # the two-attr-read contract
-        t0 = tracer._clock() if tracing else 0.0
+        tracing = ph.tracer is not None
         traced: list = []
         proposals = np.asarray(
             self.drafter.propose(self.slot_req, self.tokens, self.positions, k),
@@ -2110,19 +2122,22 @@ class ContinuousBatcher:
         seq[:, 1:] = proposals
         t_guard = self._pre_dispatch("serving.decode", active)
         if self.paged:
-            with compile_label("serving.spec_verify_paged"):
+            with compile_label("serving.spec_verify_paged"), \
+                    phase("engine.decode.dispatch"):
                 greedy, logits, self.cache = self._spec_verify_paged_fn(
                     self.params, self.cache, jnp.asarray(self.block_mgr.tables),
                     jnp.asarray(seq), jnp.asarray(self.positions),
                     cfg=self.cfg, page_size=self.page_size,
                 )
         else:
-            with compile_label("serving.spec_verify"):
+            with compile_label("serving.spec_verify"), \
+                    phase("engine.decode.dispatch"):
                 greedy, logits, self.cache = self._spec_verify_fn(
                     self.params, self.cache, jnp.asarray(seq),
                     jnp.asarray(self.positions), cfg=self.cfg,
                 )
-        greedy_host = np.asarray(greedy)  # [B, T]
+        with phase("engine.decode.fetch"):
+            greedy_host = np.asarray(greedy)  # [B, T]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
         finished = []
         step_tokens = step_accepted = 0
@@ -2175,15 +2190,11 @@ class ContinuousBatcher:
         self.spec_proposed += k * len(active)
         self.spec_accepted += step_accepted
         if tracing:
-            t1 = tracer._clock()
-            host_s = self._host_gap(t0, t1)
-            for req, n_emitted, n_accepted in traced:
-                tracer.span(
-                    tracer.handle_for(req.uid), "decode", t0, t1,
-                    step=self.decode_steps, occupancy=len(active),
-                    tokens=n_emitted, proposed=k, accepted=n_accepted,
-                    host_s=host_s,
-                )
+            self._decode_spans(
+                ph, ((req, {"tokens": n_emitted, "proposed": k,
+                            "accepted": n_accepted})
+                     for req, n_emitted, n_accepted in traced),
+                occupancy=len(active))
         tel = self.telemetry
         if tel is not None and tel.enabled:
             from .telemetry import TELEMETRY_REV
@@ -2586,101 +2597,92 @@ class ContinuousBatcher:
                             self._quarantine(req, f"prefill_fault:{spec.kind}")
                         )
                         continue
-                tracer = self.tracer
-                tracing = tracer is not None and tracer.enabled
-                if tracing:
-                    t_pf0 = tracer._clock()
-                    hits0 = self.prefix_hits
-                    cow0 = self.block_mgr.cow_count if self.paged else 0
-                    adopt0 = self.block_mgr.adopt_count if self.paged else 0
-                try:
-                    prefilled = self._prefill_into_slot(slot, req, plan, ctx,
-                                                        remaining)
-                except EngineCrashed:
-                    raise  # whole-engine death: the fleet router's problem
-                except Exception as e:
-                    if not self.recover:
-                        raise
-                    # Real prefill failure: quarantine the admitting request
-                    # (attribution is certain), and — since the row insert may
-                    # have consumed the donated cache — rebuild the survivors.
-                    self.queue.popleft()
-                    self.step_failures += 1
-                    kind = getattr(e, "kind", type(e).__name__)
-                    self._emit_fault(getattr(e, "site", "serving.prefill"),
-                                     kind, req.uid, reason=str(e))
-                    finished.append(
-                        self._quarantine(req, f"prefill_fault:{kind}")
-                    )
-                    if not getattr(e, "pre_dispatch", False):
-                        self._rebuild_survivors()
-                    return finished
-                if prefilled is None:
-                    # Page pool exhausted: every admission waits until lanes finish
-                    # and free pages (the defer counter moved). Nothing was consumed.
+                reserved = None
+                if self.paged:
+                    try:
+                        reserved = self._reserve_paged(plan, ctx, remaining)
+                    except Exception as e:
+                        return self._prefill_failed(req, e, finished)
+                    if reserved is None:
+                        # Page pool exhausted: every admission waits until lanes finish
+                        # and free pages (the defer counter moved). Nothing was consumed.
+                        with EnginePhase(self.tracer, "engine.defer", uid=req.uid) as ph:
+                            if ph.tracer is not None:
+                                ph.tracer.count_defer(req.uid)
+                        return finished
+                # plan is None on a prefix-cache engine (_plan_prefill is skipped):
+                # its rows are whole chunks of the prompt bucket.
+                width = plan[1] if plan is not None else (
+                    max(1, -(-len(ctx) // self.prompt_bucket)) * self.prompt_bucket)
+                with EnginePhase(self.tracer, "engine.prefill", uid=req.uid,
+                                 prompt_len=len(ctx), width=int(width)) as ph:
+                    tracing = ph.tracer is not None
                     if tracing:
-                        tracer.count_defer(req.uid)
-                    return finished
-                self.queue.popleft()
-                if req._recover_ctx is None:
-                    self.queue_waits.append(
-                        max(0.0, time.monotonic() - req.enqueued_at)
-                    )
-                greedy_dev, logits_dev, prefill_len = prefilled
-                first = (
-                    int(np.asarray(greedy_dev)[0])       # fused on-device argmax (4 bytes)
-                    if req.gen.temperature <= 0.0
-                    else req._sample(logits_dev[0])
-                )
-                if self.drafter is not None:
-                    # Same lane, same padded layout: the draft cache row must mirror
-                    # the engine row so engine positions index both.
-                    self.drafter.admit(slot, ctx, plan)
-                self.admitted += 1
-                if req._recover_ctx is not None:
-                    # Recovery re-admission succeeded: the prefill replayed
-                    # prompt + emitted tokens and `first` IS the next emission.
-                    req._recover_ctx = None
-                    req.recoveries += 1
-                    self.recovered_admissions += 1
-                    self.recovered_uids.add(req.uid)
-                    self._emit_recovery("readmit", uid=req.uid,
-                                        tokens_kept=len(req.tokens))
-                self.slot_req[slot] = req
-                self.positions[slot] = prefill_len  # next write = first decode slot
-                self.tokens[slot] = first
-                req.tokens.append(int(first))
-                if req.on_token is not None:
-                    req.on_token(int(first))
-                if tracing:
-                    # Span closes AFTER the first token is extracted and streamed:
-                    # the device sync that produces it is prefill cost the client
-                    # waits on, so queue.dur + prefill.dur reconstructs TTFT.
-                    handle = tracer.handle_for(req.uid)
-                    t_pf1 = tracer._clock()
-                    hit = self.prefix_hits > hits0
-                    # plan is None on a prefix-cache engine (_plan_prefill is
-                    # skipped): the path actually run is a prefix-snapshot
-                    # resume only when the registry hit — a cold prompt ran the
-                    # right-aligned chunked prefill.
-                    mode, width = plan if plan is not None else (
-                        "prefix" if hit else "chunk",
-                        max(1, -(-len(ctx) // self.prompt_bucket))
-                        * self.prompt_bucket,
-                    )
-                    tracer.event(
-                        handle, "admit", t=t_pf0, lane=slot,
-                        kv_defer_retries=handle.kv_defers if handle else 0,
-                    )
-                    tracer.span(
-                        handle, "prefill", t_pf0, t_pf1,
-                        mode=mode, width=int(width), prompt_len=len(ctx),
-                        prefix_hit=hit,
-                        cow=(self.block_mgr.cow_count - cow0) if self.paged else 0,
-                        adopted_pages=(
-                            (self.block_mgr.adopt_count - adopt0) if self.paged else 0
-                        ),
-                    )
+                        hits0 = self.prefix_hits
+                        cow0 = self.block_mgr.cow_count if self.paged else 0
+                        adopt0 = self.block_mgr.adopt_count if self.paged else 0
+                    try:
+                        greedy_dev, logits_dev, prefill_len = self._prefill_into_slot(
+                            slot, req, plan, ctx, remaining, reserved)
+                    except Exception as e:
+                        return self._prefill_failed(req, e, finished)
+                    self.queue.popleft()
+                    if req._recover_ctx is None:
+                        self.queue_waits.append(
+                            max(0.0, time.monotonic() - req.enqueued_at)
+                        )
+                        ph.set_metadata(queue_wait_ms=1e3 * self.queue_waits[-1])
+                    with phase("engine.prefill.fetch", uid=req.uid):
+                        first = (
+                            int(np.asarray(greedy_dev)[0])   # fused on-device argmax (4 bytes)
+                            if req.gen.temperature <= 0.0
+                            else req._sample(logits_dev[0])
+                        )
+                    if self.drafter is not None:
+                        # Same lane, same padded layout: the draft cache row must mirror
+                        # the engine row so engine positions index both.
+                        self.drafter.admit(slot, ctx, plan)
+                    self.admitted += 1
+                    if req._recover_ctx is not None:
+                        # Recovery re-admission succeeded: the prefill replayed
+                        # prompt + emitted tokens and `first` IS the next emission.
+                        req._recover_ctx = None
+                        req.recoveries += 1
+                        self.recovered_admissions += 1
+                        self.recovered_uids.add(req.uid)
+                        self._emit_recovery("readmit", uid=req.uid,
+                                            tokens_kept=len(req.tokens))
+                    self.slot_req[slot] = req
+                    self.positions[slot] = prefill_len  # next write = first decode slot
+                    self.tokens[slot] = first
+                    req.tokens.append(int(first))
+                    if req.on_token is not None:
+                        req.on_token(int(first))
+                    if tracing:
+                        # Span closes AFTER the first token is extracted and streamed:
+                        # the device sync that produces it is prefill cost the client
+                        # waits on, so queue.dur + prefill.dur reconstructs TTFT.
+                        tracer = ph.tracer
+                        handle = tracer.handle_for(req.uid)
+                        hit = self.prefix_hits > hits0
+                        # Without a plan the path actually run is a prefix-snapshot
+                        # resume only when the registry hit — a cold prompt ran the
+                        # right-aligned chunked prefill.
+                        mode = plan[0] if plan is not None else (
+                            "prefix" if hit else "chunk")
+                        tracer.event(
+                            handle, "admit", t=ph.t0, lane=slot,
+                            kv_defer_retries=handle.kv_defers if handle else 0,
+                        )
+                        ph.span(
+                            req.uid, "prefill", ph.now(),
+                            mode=mode, width=int(width), prompt_len=len(ctx),
+                            prefix_hit=hit,
+                            cow=(self.block_mgr.cow_count - cow0) if self.paged else 0,
+                            adopted_pages=(
+                                (self.block_mgr.adopt_count - adopt0) if self.paged else 0
+                            ),
+                        )
                 hit_eos = req.gen.eos_token_id is not None and int(first) == req.gen.eos_token_id
                 if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
                     req.done = True
@@ -2690,11 +2692,31 @@ class ContinuousBatcher:
                     self.evicted += 1  # finished AT admission still cycled the slot
         return finished
 
-    def _prefill_into_slot(self, slot: int, req: Request, plan, ctx=None,
-                           remaining: Optional[int] = None):
+    def _prefill_failed(self, req: Request, e: Exception,
+                        finished: list[Request]) -> list[Request]:
+        """Called while handling ``e``, raised admitting the head request
+        ``req``: without recovery (or on whole-engine death, the fleet router's
+        problem) it goes on up; else the request is quarantined — attribution
+        is certain — and, since the row insert may have consumed the donated
+        cache, the survivors are rebuilt. → ``finished``, for ``_admit`` to
+        return."""
+        if isinstance(e, EngineCrashed) or not self.recover:
+            raise
+        self.queue.popleft()
+        self.step_failures += 1
+        kind = getattr(e, "kind", type(e).__name__)
+        self._emit_fault(getattr(e, "site", "serving.prefill"),
+                         kind, req.uid, reason=str(e))
+        finished.append(self._quarantine(req, f"prefill_fault:{kind}"))
+        if not getattr(e, "pre_dispatch", False):
+            self._rebuild_survivors()
+        return finished
+
+    def _prefill_into_slot(self, slot: int, req: Request, plan, ctx, remaining: int,
+                           reserved=None):
         """Run one request's prefill and land its KV in lane ``slot`` →
-        ``(greedy_dev, logits_dev, prefill_len)``, or None when a paged admission
-        must defer on pool pressure (nothing consumed; the request stays queued).
+        ``(greedy_dev, logits_dev, prefill_len)``. ``reserved`` is what
+        :meth:`_reserve_paged` returned for this admission (paged engines).
 
         ``ctx``/``remaining`` are the admission context and generation budget —
         the request's prompt and full budget normally, prompt + emitted tokens
@@ -2705,10 +2727,6 @@ class ContinuousBatcher:
         registry hit), prefill the SAME dense row (identical compute → identical
         tokens), scatter it into the owned pages through the write-id map, then
         register this prompt's prefixes as page lists."""
-        if ctx is None:
-            ctx = req.prompt
-        if remaining is None:
-            remaining = req.gen.max_new_tokens
         if not self.paged:
             row_cache, greedy_dev, logits_dev, prefill_len = self._prefill(
                 ctx, remaining, plan
@@ -2716,11 +2734,16 @@ class ContinuousBatcher:
             # graftlint: disable=recompile-hazard(slot indexes a compile-time cache row; at most max_slots variants, admission-time only)
             self.cache = self._insert_row_fn(self.cache, row_cache, slot=slot, scan_layers=self.cfg.scan_layers)
             return greedy_dev, logits_dev, prefill_len
-        return self._prefill_into_slot_paged(slot, req, plan, ctx, remaining)
+        return self._prefill_into_slot_paged(slot, req, plan, ctx, remaining,
+                                             reserved)
 
     # ---------------------------------------------------------------- paged admission
-    def _prefill_into_slot_paged(self, slot: int, req: Request, plan, ctx,
-                                 remaining: int):
+    def _reserve_paged(self, plan, ctx, remaining: int):
+        """The pool's half of a paged admission, before any device work: the
+        prefix lookup, the pages the lane would adopt and own, and room for
+        them. → None when the admission must defer (nothing consumed; the
+        request stays queued), else ``(hit_len, entry, lookup_chunks, total,
+        adopted, cow_partial, n_tokens)`` for :meth:`_prefill_into_slot_paged`."""
         mgr = self.block_mgr
         ps = self.page_size
         max_new = remaining
@@ -2762,6 +2785,14 @@ class ContinuousBatcher:
                 continue
             mgr.defer_count += 1
             return None
+        return hit_len, entry, lookup_chunks, total, adopted, cow_partial, n_tokens
+
+    def _prefill_into_slot_paged(self, slot: int, req: Request, plan, ctx,
+                                 remaining: int, reserved):
+        mgr = self.block_mgr
+        ps = self.page_size
+        max_new = remaining
+        hit_len, entry, lookup_chunks, total, adopted, cow_partial, n_tokens = reserved
         # Count the prefix outcome only now, when this admission actually
         # proceeds: a deferred request re-runs the lookup every step() while it
         # waits, and counting there would inflate hits/misses N-fold under
@@ -2777,7 +2808,7 @@ class ContinuousBatcher:
         if self.prefix_cache_size:
             # hit_len == 0 and entry is None on a miss — the same call covers both.
             row_cache, greedy_dev, logits_dev, prefill_len = self._prefill_prefix_paged(
-                ctx, hit_len, entry, n_chunks, total
+                ctx, hit_len, entry, total // self.prompt_bucket, total
             )
         else:
             row_cache, greedy_dev, logits_dev, prefill_len = self._prefill(

@@ -65,7 +65,7 @@ from .slo import (
 )
 from .steady import SteadyStateDetector, TELEMETRY_REV
 from .timing import StepTimer, StepTiming, fence
-from .tracing import Tracer, TraceHandle
+from .tracing import EnginePhase, Tracer, TraceHandle, phase, step_phase
 
 __all__ = [
     "AlertEngine",
@@ -124,4 +124,7 @@ __all__ = [
     "fence",
     "Tracer",
     "TraceHandle",
+    "EnginePhase",
+    "phase",
+    "step_phase",
 ]

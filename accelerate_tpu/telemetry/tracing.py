@@ -57,6 +57,16 @@ verbatim through ``Telemetry.emit``, so slow-and-broken requests are always
 fully traced while the happy path pays ring entries alone — and a promoted
 trace reconstructs TTFT to the digit, because the span records ARE the ones
 full tracing would have written.
+
+**Program phases on the profiler's clock.** :func:`phase` opens a
+``jax.profiler.TraceAnnotation`` named ``atpu.<name>``: it records only while
+a ``jax.profiler`` session is live (``start_trace`` is the switch — there is
+no other) and lands in the session's trace beside the device's operations,
+nested by time on the calling thread's line (:func:`step_phase` is the same
+for a train step). :class:`EnginePhase` is the one
+helper the serving engine's boundaries go through: the phase, plus — only
+while the request-scoped :class:`Tracer` is enabled — the tracer-clock reads
+its span records are stamped with (docs/telemetry.md lists the spans).
 """
 
 from __future__ import annotations
@@ -65,10 +75,66 @@ import itertools
 import random
 from typing import Callable, Dict, Optional
 
+import jax
+
 from .clocks import resolve_clock
 from .schemas import TRACE_SPAN_SCHEMA
 
-__all__ = ["Tracer", "TraceHandle", "TRACE_SPAN_SCHEMA"]
+__all__ = ["Tracer", "TraceHandle", "TRACE_SPAN_SCHEMA", "phase", "step_phase",
+           "EnginePhase"]
+
+#: Every program span's name starts with this, so a trace reader finds them all.
+PHASE_PREFIX = "atpu."
+
+
+def phase(name: str, **attrs) -> jax.profiler.TraceAnnotation:
+    """Context manager: the span ``atpu.<name>`` on the profiler's clock, with
+    ``attrs`` as the event's stats. Outside a ``jax.profiler`` session it
+    records nothing and costs a flag test. ``set_metadata(**attrs)`` on the
+    entered object adds values known only at the span's end."""
+    return jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **attrs)
+
+
+def step_phase(name: str, step_num: int) -> jax.profiler.StepTraceAnnotation:
+    """:func:`phase` for one step of a training loop: profiler tools group the
+    device's work by the ``step_num`` of the step span that dispatched it."""
+    return jax.profiler.StepTraceAnnotation(PHASE_PREFIX + name, step_num=step_num)
+
+
+class EnginePhase:
+    """One engine boundary stamped on both clocks from one place: the
+    :func:`phase` span always, and ``t0`` on the :class:`Tracer`'s clock only
+    while that tracer is enabled. ``tracer`` is None otherwise, so a boundary
+    with tracing off reads two attributes and no clock (the overhead contract
+    above); callers emit their span records through :meth:`span`."""
+
+    __slots__ = ("tracer", "t0", "_annotation")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, **attrs):
+        self.tracer = tracer if tracer is not None and tracer.enabled else None
+        self.t0 = 0.0
+        self._annotation = phase(name, **attrs)
+
+    def __enter__(self) -> "EnginePhase":
+        self._annotation.__enter__()
+        if self.tracer is not None:
+            self.t0 = self.tracer._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+
+    def set_metadata(self, **attrs) -> None:
+        self._annotation.set_metadata(**attrs)
+
+    def now(self) -> float:
+        """The tracer's clock (only while ``tracer`` is not None)."""
+        return self.tracer._clock()
+
+    def span(self, engine_uid: int, kind: str, t1: float, **attrs) -> None:
+        """One ``[t0, t1]`` span record on the trace bound to ``engine_uid``."""
+        tracer = self.tracer
+        tracer.span(tracer.handle_for(engine_uid), kind, self.t0, t1, **attrs)
 
 #: Process-wide trace sequence: uid + submit time alone would collide when
 #: several gateways run on injectable VIRTUAL clocks against one telemetry sink

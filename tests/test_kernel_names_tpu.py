@@ -1,0 +1,121 @@
+"""Kernel names survive the TPU compiler: ``flash_attention`` forward and backward and
+``paged_attention`` compiled for a DESCRIBED v5e chip (none is attached) at the
+benchmark cells' widths, and the ``tpu_custom_call`` instructions carry the names the
+trace readers look for. These are compiles, not runs: nothing here is a measurement.
+
+The topology is described inside a module-scoped fixture and only there (never at
+import: every xdist worker imports this file, and only one process may load libtpu).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from accelerate_tpu.ops import flash_attention as flash_mod
+from accelerate_tpu.ops import paged_attention as paged_mod
+
+# Mistral-7B: 32 q heads / 8 kv heads x 128; train cell 4 x 8192, window 4096;
+# serve cell 32 lanes, pages of 16, max_len 8192 (512 table entries), 3840 pages.
+B_TRAIN, SEQ, H, K, HD, WINDOW = 4, 8192, 32, 8, 128, 4096
+LANES, PAGE, MAX_LEN, PAGES = 32, 16, 8192, 3840
+CUSTOM_CALL = re.compile(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops the description skips, not fails
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but cannot be
+    # read back without a chip (the next run would warn and compile again): keep it off.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def shape(dims, dtype, sharding):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+
+KERNEL = re.compile(r"flash_fwd|flash_bwd_dq|flash_bwd_dkv|paged_attention")
+
+
+def kernels(compiled) -> list:
+    """The kernel name each Mosaic instruction of the program carries (the instruction's
+    own name where it carries none). Directly under ``jax.grad`` the compiler wraps the
+    name in the transform's (``transpose_jvp_flash_bwd_dq__.1``); inside the train step's
+    scan and remat it stands alone (``flash_bwd_dq.11``). A trace reader searches for it."""
+    names = CUSTOM_CALL.findall(compiled.as_text())
+    return sorted(m.group(0) if (m := KERNEL.search(n)) else n for n in names)
+
+
+def flash(q, k, v):
+    return flash_mod.flash_attention(q, k, v, causal=True, window=WINDOW, interpret=False)
+
+
+def paged(q, pool_k, pool_v, tables, positions, valid):
+    return paged_mod.paged_attention(
+        q, {"k": pool_k, "v": pool_v}, tables, positions, valid, page_size=PAGE,
+        sm_scale=HD ** -0.5, window=WINDOW, interpret=False)
+
+
+def flash_args(s):
+    q = shape((B_TRAIN, SEQ, H, HD), jnp.bfloat16, s)
+    kv = shape((B_TRAIN, SEQ, K, HD), jnp.bfloat16, s)
+    return q, kv, kv
+
+
+def paged_args(s):
+    pool = shape((PAGES, PAGE, K, HD), jnp.bfloat16, s)
+    return (shape((LANES, 1, H, HD), jnp.bfloat16, s), pool, pool,
+            shape((LANES, MAX_LEN // PAGE), jnp.int32, s), shape((LANES,), jnp.int32, s),
+            shape((LANES, MAX_LEN), jnp.bool_, s))
+
+
+def test_flash_forward_is_named(one_chip):
+    compiled = jax.jit(flash).lower(*flash_args(one_chip)).compile()
+    assert kernels(compiled) == ["flash_fwd"]
+
+
+def test_flash_backward_kernels_are_named(one_chip):
+    def loss(q, k, v):
+        return flash(q, k, v).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*flash_args(one_chip)).compile()
+    assert kernels(compiled) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+def test_paged_attention_is_named(one_chip):
+    compiled = jax.jit(paged).lower(*paged_args(one_chip)).compile()
+    assert kernels(compiled) == ["paged_attention"]
+
+
+@pytest.mark.parametrize("module,fn,args", [
+    (flash_mod, flash, flash_args), (paged_mod, paged, paged_args)], ids=["flash", "paged"])
+def test_a_name_changes_nothing_but_the_name(one_chip, monkeypatch, module, fn, args):
+    """The same kernel compiled without ``name=``: same instruction count, same memory."""
+    named = jax.jit(fn).lower(*args(one_chip)).compile()
+    pallas_call = module.pl.pallas_call
+    monkeypatch.setattr(
+        module.pl, "pallas_call",
+        lambda kernel, *a, name=None, **kw: pallas_call(kernel, *a, **kw))
+    bare = jax.jit(lambda *xs: fn(*xs)).lower(*args(one_chip)).compile()
+    assert kernels(bare) != kernels(named)
+    count = lambda c: len(re.findall(r"^\s*(ROOT )?%[\w.\-]+ = ", c.as_text(), re.M))  # noqa: E731
+    assert count(bare) == count(named)
+    for field in ("argument_size_in_bytes", "output_size_in_bytes", "temp_size_in_bytes",
+                  "generated_code_size_in_bytes"):
+        assert getattr(bare.memory_analysis(), field) == getattr(named.memory_analysis(), field)
