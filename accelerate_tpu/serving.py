@@ -1787,6 +1787,24 @@ class ContinuousBatcher:
         self._last_dispatch_end = t1
         return round(max(0.0, t0 - prev), 9) if prev is not None else 0.0
 
+    def _paged_walk(self, active: list[int]) -> dict:
+        """What the paged-attention kernel walks at the first decode step of a
+        dispatch, one layer's worth summed over the active lanes: ``pages_walked``
+        table entries fetched, of which ``pages_live`` hold a key the lane's query
+        may see (``ops.paged_attention.walk_range``, the kernel's own rule). On a
+        model that alternates banded and full layers this is a banded layer's."""
+        from .ops.paged_attention import block_pages, walk_range
+
+        cfg = self.cfg
+        pool_dtype = jax.tree_util.tree_leaves(self.cache["layers"])[0].dtype
+        block = block_pages(self.page_size, cfg.n_kv_heads, cfg.head_dim,
+                            pool_dtype.itemsize, self.block_mgr.max_pages)
+        _, blocks, pages = walk_range(
+            self.positions[active],
+            np.array([self._lane_valid[i][0] for i in active], np.int32),
+            T=1, window=cfg.sliding_window, page_size=self.page_size, block=block)
+        return {"pages_live": int(pages.sum()), "pages_walked": int(blocks.sum()) * block}
+
     def _multi_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
         """Device-resident super-step: ``decode_steps=N`` decode steps in ONE
         dispatched scan (``serving.decode_multi``/``decode_multi_paged``), then
@@ -1852,7 +1870,7 @@ class ContinuousBatcher:
         t_guard = self._pre_dispatch("serving.decode", active)
         if self.paged:
             with compile_label("serving.decode_multi_paged"), \
-                    phase("engine.decode.dispatch"):
+                    phase("engine.decode.dispatch", **self._paged_walk(active)):
                 tok_buf, counts, self.cache = self._decode_multi_paged_fn(
                     self.params, self.cache, tables, *lane_args,
                     cfg=self.cfg, n_steps=N, sample=sampled,

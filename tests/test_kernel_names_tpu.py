@@ -66,9 +66,12 @@ def flash(q, k, v):
     return flash_mod.flash_attention(q, k, v, causal=True, window=WINDOW, interpret=False)
 
 
-def paged(q, pool_k, pool_v, tables, positions, valid):
+def paged(q, pool_k, pool_v, tables, positions, valid, k_scale=None, v_scale=None):
+    pool = {"k": pool_k, "v": pool_v}
+    if k_scale is not None:
+        pool.update(k_scale=k_scale, v_scale=v_scale)
     return paged_mod.paged_attention(
-        q, {"k": pool_k, "v": pool_v}, tables, positions, valid, page_size=PAGE,
+        q, pool, tables, positions, valid, page_size=PAGE,
         sm_scale=HD ** -0.5, window=WINDOW, interpret=False)
 
 
@@ -78,11 +81,14 @@ def flash_args(s):
     return q, kv, kv
 
 
-def paged_args(s):
-    pool = shape((PAGES, PAGE, K, HD), jnp.bfloat16, s)
-    return (shape((LANES, 1, H, HD), jnp.bfloat16, s), pool, pool,
+def paged_args(s, pool_dtype=jnp.bfloat16):
+    pool = shape((PAGES, PAGE, K, HD), pool_dtype, s)
+    args = (shape((LANES, 1, H, HD), jnp.bfloat16, s), pool, pool,
             shape((LANES, MAX_LEN // PAGE), jnp.int32, s), shape((LANES,), jnp.int32, s),
             shape((LANES, MAX_LEN), jnp.bool_, s))
+    if pool_dtype == jnp.int8:      # the kv_quant pool: per-slot fp32 scale pages
+        args += (shape((PAGES, PAGE, K, 1), jnp.float32, s),) * 2
+    return args
 
 
 def test_flash_forward_is_named(one_chip):
@@ -98,8 +104,9 @@ def test_flash_backward_kernels_are_named(one_chip):
     assert kernels(compiled) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
-def test_paged_attention_is_named(one_chip):
-    compiled = jax.jit(paged).lower(*paged_args(one_chip)).compile()
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_attention_is_named(one_chip, pool_dtype):
+    compiled = jax.jit(paged).lower(*paged_args(one_chip, pool_dtype)).compile()
     assert kernels(compiled) == ["paged_attention"]
 
 

@@ -210,6 +210,134 @@ def test_kernel_window_and_softcap(window, softcap):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
 
 
+# The walk over a lane's live range (ISSUE 26). Toy geometry: pages of 8 slots, 7 table
+# entries a lane (C = 56), blocks forced to 2 pages (16 slots) so a walk has several
+# iterations and 7 is not a multiple of the block. A lane is (first valid slot, length,
+# first query position); ``None`` is a freed lane as the multi-step engine leaves it —
+# stale valid row, all-sentinel table row, parked at position C — and "empty" one whose
+# valid row is empty.
+_WALK_PS, _WALK_MP, _WALK_BLOCK = 8, 7, 2
+_WALK_CASES = {
+    "freed_lane_between_live_ones": dict(lanes=[(0, 20, 19), None, (0, 11, 10), "empty"]),
+    "length_1": dict(lanes=[(0, 1, 0), (0, 30, 29)]),
+    "length_on_block_boundary_and_one_past": dict(
+        lanes=[(0, 16, 15), (0, 17, 16), (0, 32, 31), (0, 33, 32)]),
+    "left_pad_crosses_block_boundary": dict(lanes=[(19, 45, 44), (5, 23, 22)]),
+    "window_start_mid_block": dict(lanes=[(0, 50, 49), (3, 30, 29)], window=21),
+    "T3_rows_straddle_block_boundary": dict(lanes=[(0, 34, 31), (2, 18, 15)], T=3),
+    "table_not_a_multiple_of_block": dict(lanes=[(0, 56, 55), (41, 56, 55)]),
+    "int8_with_window": dict(lanes=[(0, 50, 49), (9, 27, 26)], window=13, quantized=True),
+    "sentinel_entries_above_hi": dict(lanes=[(0, 20, 19), (0, 4, 3)]),
+}
+
+
+def _walk_case(name):
+    """(pool, tables, valid, positions, live mask, kwargs) of one case above."""
+    case = _WALK_CASES[name]
+    T, quantized = case.get("T", 1), case.get("quantized", False)
+    ps, MP = _WALK_PS, _WALK_MP
+    C = ps * MP
+    rng = np.random.default_rng(sorted(_WALK_CASES).index(name))
+    lanes = case["lanes"]
+    B = len(lanes)
+    K, hd, P = 2, 16, B * MP
+    lens = np.array([l[1] if isinstance(l, tuple) else 25 for l in lanes])
+    pool, tables, valid = _build_pool(rng, B, K, hd, ps, P, MP, lens, quantized)
+    tables, valid = np.array(tables), np.array(valid)
+    positions = np.zeros((B,), np.int32)
+    live = np.zeros((B,), bool)
+    for b, lane in enumerate(lanes):
+        if isinstance(lane, tuple):
+            valid[b, :lane[0]] = False      # the K/V under the pad stays in the pool
+            positions[b], live[b] = lane[2], True
+        else:
+            tables[b], positions[b] = P, C
+            if lane == "empty":
+                valid[b] = False
+    kw = dict(page_size=ps, sm_scale=hd ** -0.5, window=case.get("window", 0))
+    return pool, tables, valid, positions, live, T, kw
+
+
+def _force_block(monkeypatch, quantized, K=2, hd=16):
+    from accelerate_tpu.ops import paged_attention as mod
+
+    page_bytes = 2 * _WALK_PS * K * hd * (1 if quantized else 4)
+    monkeypatch.setattr(mod, "_BLOCK_BYTES", _WALK_BLOCK * page_bytes)
+    assert mod.block_pages(_WALK_PS, K, hd, 1 if quantized else 4, _WALK_MP) == _WALK_BLOCK
+
+
+@pytest.mark.parametrize("name", list(_WALK_CASES))
+def test_kernel_walks_live_range(name, monkeypatch):
+    """The kernel against the reference on every live lane; zeros on a freed one."""
+    from accelerate_tpu.ops.paged_attention import (
+        paged_attention, paged_attention_reference,
+    )
+
+    pool, tables, valid, positions, live, T, kw = _walk_case(name)
+    _force_block(monkeypatch, "k_scale" in pool)
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.standard_normal((len(live), T, 4, 16)).astype(np.float32))
+    args = (q, pool, jnp.asarray(tables), jnp.asarray(positions), jnp.asarray(valid))
+    ref = np.asarray(paged_attention_reference(*args, **kw))
+    out = np.asarray(paged_attention(*args, **kw))
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-6)
+    assert np.all(out[~live] == 0.0)
+
+
+@pytest.mark.parametrize("name", list(_WALK_CASES))
+def test_walk_range_covers_exactly_the_visible_pages(name):
+    """``walk_range`` against the mask itself: the walk starts at the page of the first
+    slot any query may see, holds the page of the last, and is as short as whole blocks
+    allow. The host form (numpy) and the wrapper's (jax) agree."""
+    from accelerate_tpu.ops.paged_attention import walk_range
+
+    _, tables, valid, positions, live, T, kw = _walk_case(name)
+    ps, window, C = kw["page_size"], kw["window"], valid.shape[1]
+    slots = np.arange(C)
+    first_valid = np.where(valid.any(1), valid.argmax(1), C)
+    last_live = np.where(valid.any(1), C - 1 - valid[:, ::-1].argmax(1), -1)
+    last_live = np.minimum(last_live, (tables < len(tables) * _WALK_MP).sum(1) * ps - 1)
+    kwargs = dict(T=T, window=window, page_size=ps, block=_WALK_BLOCK)
+    first, blocks, pages = walk_range(positions, first_valid, last_live, **kwargs)
+    for b in range(len(live)):
+        seen = np.zeros((C,), bool)
+        for t in range(T):
+            q_pos = positions[b] + t
+            row = valid[b] & (slots <= q_pos) & (slots <= last_live[b])
+            if window:
+                row &= slots > q_pos - window
+            seen |= row
+        if not seen.any():
+            assert blocks[b] == 0 and pages[b] == 0, (name, b)
+            continue
+        lo, hi = slots[seen].min() // ps, slots[seen].max() // ps
+        assert (first[b], pages[b]) == (lo, hi - lo + 1), (name, b)
+        assert blocks[b] == -(-(hi - lo + 1) // _WALK_BLOCK), (name, b)
+    # an active lane has written its last query slot: the host needs no last_live
+    host = walk_range(positions[live], first_valid[live], **kwargs)
+    on_device = walk_range(jnp.asarray(positions), jnp.asarray(first_valid),
+                           jnp.asarray(last_live), **kwargs)
+    for got, dev, want in zip(host, on_device, (first, blocks, pages)):
+        assert np.array_equal(got, want[live]) and np.array_equal(np.asarray(dev), want)
+
+
+def test_kernel_compiles_once_for_every_length(monkeypatch):
+    """Positions, valid rows and tables are data: two calls with other lengths, one
+    trace and one program."""
+    from accelerate_tpu.ops.paged_attention import paged_attention
+
+    _force_block(monkeypatch, False)
+    fn = jax.jit(lambda *a: paged_attention(
+        *a, page_size=_WALK_PS, sm_scale=0.25, window=21, interpret=True))
+    rng = np.random.default_rng(3)
+    for name in ("length_1", "left_pad_crosses_block_boundary"):
+        pool, tables, valid, positions, live, T, _ = _walk_case(name)
+        q = jnp.asarray(rng.standard_normal((2, 1, 4, 16)).astype(np.float32))
+        out = fn(q, pool, jnp.asarray(tables), jnp.asarray(positions), jnp.asarray(valid))
+        assert np.isfinite(np.asarray(out)).all()
+    assert fn._cache_size() == 1
+
+
 def test_reference_matches_dense_attention_exactly():
     """The gather fallback is BITWISE the dense cached-attention math on the
     occupied slots — the foundation of the engine-level paged/dense parity."""

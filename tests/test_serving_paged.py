@@ -418,3 +418,47 @@ def test_kv_quant_paged_parity(setup):
         return [r.tokens for r in reqs]
 
     assert run(0) == run(8)
+
+
+@pytest.mark.parametrize("prefix_cache", [0, 4], ids=["left_padded", "prefix_layout"])
+def test_dispatch_span_counts_the_pages_the_kernel_walks(setup, prefix_cache, tmp_path):
+    """``pages_live`` / ``pages_walked`` on ``atpu.engine.decode.dispatch`` come from the
+    host's positions and recorded valid ranges; the kernel's wrapper derives its walk from
+    the device's valid rows and tables. Before every multi-step dispatch the two agree,
+    lane by lane, and inside a profiler session the span carries the sums."""
+    from accelerate_tpu.ops.paged_attention import block_pages, walk_range
+    from benchmarks.chipbench import program_spans
+
+    params, prompts = setup
+    cfg = dataclasses.replace(CFG, sliding_window=12)
+    eng = ContinuousBatcher(params, cfg, max_slots=3, max_len=64, prompt_bucket=16,
+                            page_size=8, decode_steps=4, prefix_cache=prefix_cache)
+    for p, n in zip(prompts, (9, 6, 12, 5, 8, 7)):
+        eng.submit(p, max_new_tokens=n)
+    block = block_pages(8, cfg.n_kv_heads, cfg.head_dim, 4, eng.block_mgr.max_pages)
+    kw = dict(T=1, window=12, page_size=8, block=block)
+    seen, inner = [], eng._paged_walk
+
+    def checked(active):
+        got = inner(active)
+        valid = np.asarray(eng.cache["valid"])[active]
+        valid[np.arange(len(active)), eng.positions[active]] = True  # the step's own write
+        C = valid.shape[1]
+        last = C - 1 - valid[:, ::-1].argmax(1)
+        allocated = (eng.block_mgr.tables[active] < eng.block_mgr.SENTINEL).sum(1) * 8 - 1
+        _, blocks, pages = walk_range(eng.positions[active], valid.argmax(1),
+                                      np.minimum(last, allocated), **kw)
+        assert got == {"pages_live": int(pages.sum()),
+                       "pages_walked": int(blocks.sum()) * block}
+        seen.append(got)
+        return got
+
+    eng._paged_walk = checked
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    assert seen and all(0 < s["pages_live"] <= s["pages_walked"] for s in seen)
+    spans = [s for s in program_spans.load(str(tmp_path)) if s.name == "engine.decode.dispatch"]
+    assert [{k: int(s.attrs[k]) for k in ("pages_live", "pages_walked")} for s in spans] == seen
