@@ -2,7 +2,8 @@
 
     python3 -m benchmarks.chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Finds the cell in ``BENCHMARK.json``, its configuration in ``configs/``, its traffic in
+Finds the cell in ``BENCHMARK.json``, its configuration in ``configs/``, the model's
+family in ``families/`` (by the configuration's ``model_type``), its traffic in
 ``traffic/`` (whose ``window`` names the module that drives it) and each per-layer
 metric's reader in ``metrics/``. Places the compile cache, builds weights on the device
 from the seed, warms the cell's shapes (set-up), measures, frees the program, runs the
@@ -97,6 +98,34 @@ def load_cell(workload: str, dry: bool, root: str = ROOT):
     return bench, cell, config, traffic.load("traffic", cell["traffic"], dry, where), where
 
 
+def load_by_path(name: str, path: str):
+    """The module in the file ``path``, under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_family(model_type: str, where: str = HERE):
+    """``families/<model_type>.py`` beside ``configs/``: everything the windows and the
+    readers need of the model (the README has the contract)."""
+    path = os.path.join(where, "families", f"{model_type}.py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"no family for model_type {model_type!r}: looked for {path}")
+    return load_by_path(f"cb_family_{model_type}", path)
+
+
+def load_window(name: str, family):
+    """The module that drives the traffic; what it calls on the family has to be there."""
+    module = importlib.import_module(f"{__package__}.{name}")
+    missing = [n for n in module.NEEDS if not hasattr(family, n)]
+    if missing:
+        raise NotImplementedError(
+            f"{family.__file__} has no {', '.join(missing)}: {name} calls them, so this "
+            f"family cannot drive a cell whose traffic names {name}")
+    return module
+
+
 def applies(metric: dict, cell: dict) -> bool:
     return "workloads" not in metric or cell["name"] in metric["workloads"]
 
@@ -114,10 +143,7 @@ def read_metric(name: str, run, where: str = HERE):
                 spec = json.load(f)
             return getattr(trace_reduce, spec["reader"])(run, **spec.get("args", {}))
         if os.path.exists(path + ".py"):
-            module_spec = importlib.util.spec_from_file_location(f"cb_metric_{stem}", path + ".py")
-            module = importlib.util.module_from_spec(module_spec)
-            module_spec.loader.exec_module(module)
-            return module.read(run)
+            return load_by_path(f"cb_metric_{stem}", path + ".py").read(run)
     raise FileNotFoundError(f"no reader for per-layer metric {name!r} under metrics/")
 
 
@@ -135,6 +161,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     t_start = T_START if argv is None else time.perf_counter()
     bench, cell, config, spec, where = load_cell(args.workload, args.cpu_dry_run, args.root)
+    family = load_family(config["model_type"], where)
+    window_module = load_window(spec["window"], family)
 
     import jax
 
@@ -161,12 +189,12 @@ def main(argv=None) -> int:
     t_ready = time.perf_counter()
     marks = {"runtime_start": t_ready - t_start}     # where the seconds before the window go
     ctx = types.SimpleNamespace(
-        config=config, traffic=spec, seed=args.seed, seconds=args.seconds, devices=devices,
-        dry=args.cpu_dry_run,
+        config=config, family=family, traffic=spec, seed=args.seed, seconds=args.seconds,
+        devices=devices, dry=args.cpu_dry_run,
         mark=lambda name: marks.__setitem__(name, time.perf_counter() - t_ready),
         span=(lambda n: jax.profiler.TraceAnnotation("cb." + n)) if tracing
         else (lambda n: contextlib.nullcontext()))
-    window = importlib.import_module(f"{__package__}.{spec['window']}").Window(ctx)
+    window = window_module.Window(ctx)
     window.warm()
     lowered = []                # programs lowered from here on: none may be (no compile)
     jax.monitoring.register_event_duration_secs_listener(
@@ -199,7 +227,8 @@ def main(argv=None) -> int:
         trace = trace_reduce.Trace(tracer.dir)
         device.update(busy_s=trace.busy_s, window_s=trace.window_s)
         run = types.SimpleNamespace(
-            obs=obs, trace=trace, config=config, memory_peak_bytes=memory_peak,
+            obs=obs, trace=trace, config=config, family=family,
+            memory_peak_bytes=memory_peak,
             slice_host=tracer.host,
             peak=None if args.cpu_dry_run else work.peaks(devices[0].device_kind))
         line["metrics"] = {}

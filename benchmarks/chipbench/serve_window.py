@@ -6,6 +6,8 @@ latencies count from the DUE time, and ``loadgen_lag`` reports the wait for the 
 ``serve_open_loop`` offers arrivals on a clock (warm-in before t = 0); ``serve_backlog``
 submits the whole list first and opens the window once the page pool first defers an
 admission. Every token's host time is taken in the engine's own ``on_token`` hook.
+Everything of the model (the program's config, the seeded weights, the reference and the
+comparison) is the family's, ``ctx.family``.
 """
 
 from __future__ import annotations
@@ -16,13 +18,15 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from . import reference, traffic
-from .train_window import program_config
+from . import traffic
+
+# what this window calls on the family (run.load_window refuses a family without them)
+NEEDS = ("program_config", "gen_params", "serve_reference", "compare_serve")
 
 
 class Window:
     def __init__(self, ctx):
-        self.ctx = ctx
+        self.ctx, self.family = ctx, ctx.family
         self.c, self.spec, self.sv = ctx.config, ctx.traffic, ctx.config["serve"]
         self.requests = traffic.serve_requests(
             self.spec, self.c["vocab_size"], ctx.seed, ctx.seconds)
@@ -30,11 +34,11 @@ class Window:
     def build(self):
         from accelerate_tpu.serving import ContinuousBatcher
 
-        sv = self.sv
-        params = reference.gen_params(self.c, self.ctx.seed, getattr(jnp, sv["dtype"]))
+        sv, family = self.sv, self.family
+        params = family.gen_params(self.c, self.ctx.seed, getattr(jnp, sv["dtype"]))
         return ContinuousBatcher(
-            params, program_config(self.c), max_slots=sv["max_slots"], max_len=sv["max_len"],
-            prompt_bucket=sv["prompt_bucket"], page_size=sv["page_size"],
+            params, family.program_config(self.c), max_slots=sv["max_slots"],
+            max_len=sv["max_len"], prompt_bucket=sv["prompt_bucket"], page_size=sv["page_size"],
             kv_pages=sv["kv_pages"], decode_steps=sv["decode_steps"])
 
     def submit(self, r: dict, now: float):
@@ -188,11 +192,11 @@ class Window:
         if not rows:
             return {"served_logit_gap": float("inf")}, {}
         width = -(-(self.spec["prompt"]["max"] + self.spec["output"]["max"]) // 512) * 512
-        args = (self.c, self.ctx.seed, rows, width, self.spec["output"]["max"])
-        logits = reference.serve_reference(*args)
+        family, args = self.family, (self.c, self.ctx.seed, rows, width, self.spec["output"]["max"])
+        logits = family.serve_reference(*args)
         readings = {"tokens_compared": sum(len(t) for _, t in rows)}
         if control:     # the tokens the reference in float8 puts first, at the same positions
-            picked = reference.serve_reference(*args, fq="fp8").argmax(-1)
-            readings["control_fp8.served_logit_gap"] = reference.compare_serve(
+            picked = family.serve_reference(*args, fq="fp8").argmax(-1)
+            readings["control_fp8.served_logit_gap"] = family.compare_serve(
                 rows, logits, picked)["served_logit_gap"]
-        return reference.compare_serve(rows, logits), readings
+        return family.compare_serve(rows, logits), readings

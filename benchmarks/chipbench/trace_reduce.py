@@ -163,8 +163,9 @@ class Trace:
 
 # ----------------------------------------------------------------------------- readers
 # reader(run, **args) → a number, or None when there is nothing to read. ``run`` has
-# ``obs`` (the window's observations), ``trace`` (a Trace or None), ``config``,
-# ``peak`` (this chip's row of peaks.json) and ``memory_peak_bytes``.
+# ``obs`` (the window's observations), ``trace`` (a Trace or None), ``config``, ``family``
+# (the configuration's family module: the model's counts), ``peak`` (this chip's row of
+# peaks.json) and ``memory_peak_bytes``.
 def percentile(run, sample: str, q: float):
     xs = run.obs["samples"].get(sample)
     return float(np.percentile(xs, q)) if xs else None
@@ -195,11 +196,12 @@ def hbm_peak_gb(run):
 
 def mfu(run, rate: str, flops: str, **args):
     """The whole step's share of the chip's peak: a rate the window counted (tokens/s
-    per chip) × the FLOPs one token needs (a function of work.py) ÷ peak."""
+    per chip) × the FLOPs one token needs (a count of the family's) ÷ peak."""
     r = run.obs["values"].get(rate)
     if r is None or run.peak is None:
         return None
-    per = getattr(work, flops)(run.config, **{k: run.obs["values"][v] for k, v in args.items()})
+    per = getattr(run.family, flops)(
+        run.config, **{k: run.obs["values"][v] for k, v in args.items()})
     return 100.0 * r * per / run.peak["bf16_flops"]
 
 
@@ -211,7 +213,7 @@ def train_kernel_roofline(run, pattern: str, work_fn: str):
     if t is None or not t.modules:
         return None
     v, rx, shares = run.obs["values"], re.compile(pattern), []
-    fl, by = getattr(work, work_fn)(run.config, v["batch"], v["seq"])
+    fl, by = getattr(run.family, work_fn)(run.config, v["batch"], v["seq"])
     for dev, ops in t.devices.items():
         inside = [m for m in t.modules.get(dev, []) if m[0] >= t.begin and m[1] <= t.end]
         if not inside:
@@ -243,7 +245,7 @@ def paged_attn_roofline(run, pattern: str):
         if run.slice_host[0] <= s["t0"] and s["t1"] <= run.slice_host[1]:
             for j in range(sv["decode_steps"]):
                 lens = [max(1, n - j) for n in s["lens"]]
-                least += work.least_seconds(*work.paged_attn_work(
+                least += work.least_seconds(*run.family.paged_attn_work(
                     run.config, lens, sv["page_size"]), run.peak)
     return 100.0 * least / secs if secs and least else None
 

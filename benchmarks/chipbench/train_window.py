@@ -2,7 +2,8 @@
 callable, driven as a JAX trainer drives it — two steps in flight, a loss fetched every
 ``log_every`` steps. Set-up builds ONE compiled step with its state, drives it from the
 seed through its first three steps (reading what ``correct`` compares on the way) and
-hands that same object to the window.
+hands that same object to the window. Everything of the model (the program's config and
+loss, the seeded weights, the reference and the comparison) is the family's, ``ctx.family``.
 """
 
 from __future__ import annotations
@@ -16,25 +17,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import reference, traffic
+from . import traffic
 
-
-def program_config(c: dict, **over):
-    """The configuration file's sizes as the program's own config object."""
-    from accelerate_tpu.models import llama
-
-    return llama.LlamaConfig(
-        vocab_size=c["vocab_size"], d_model=c["hidden_size"], n_layers=c["num_hidden_layers"],
-        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
-        head_dim_override=c["head_dim"], d_ff=c["intermediate_size"],
-        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
-        max_seq=c["max_position_embeddings"], sliding_window=c["sliding_window"],
-        tie_embeddings=c["tie_word_embeddings"], scan_layers=True, **over)
+# what this window calls on the family (run.load_window refuses a family without them)
+NEEDS = ("program_config", "loss", "gen_params", "leaf_norms", "change_norms",
+         "train_reference", "compare_train")
 
 
 class Window:
     def __init__(self, ctx):
-        self.ctx = ctx
+        self.ctx, self.family = ctx, ctx.family
         self.c, self.spec, self.hp = ctx.config, ctx.traffic, ctx.config["train"]
         self.batches = traffic.train_batches(self.spec, self.c["vocab_size"], ctx.seed)
 
@@ -43,24 +35,23 @@ class Window:
         import optax
 
         from accelerate_tpu import Accelerator
-        from accelerate_tpu.models import llama
         from accelerate_tpu.parallel import MeshConfig
         from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
         from accelerate_tpu.utils.dataclasses import FullyShardedDataParallelPlugin
 
-        hp, n = self.hp, len(self.ctx.devices)
+        hp, n, family = self.hp, len(self.ctx.devices), self.family
         for singleton in (AcceleratorState, GradientState, PartialState):
             singleton._reset_state()
         acc = Accelerator(
             mixed_precision=hp["mixed_precision"],
             mesh_config=MeshConfig(dp=1, fsdp=n, devices=self.ctx.devices),
             fsdp_plugin=FullyShardedDataParallelPlugin() if n > 1 else None)
-        cfg = program_config(self.c)
+        cfg = family.program_config(self.c)
         tx = optax.adamw(hp["lr"], b1=hp["b1"], b2=hp["b2"], eps=hp["eps"],
                          weight_decay=hp["weight_decay"])
         state = acc.create_train_state(
-            reference.gen_params(self.c, self.ctx.seed, jnp.float32), tx)
-        step = acc.build_train_step(lambda p, b: llama.loss_fn(p, b, cfg),
+            family.gen_params(self.c, self.ctx.seed, jnp.float32), tx)
+        step = acc.build_train_step(lambda p, b: family.loss(p, b, cfg),
                                     max_grad_norm=hp["max_grad_norm"])
         return step, state
 
@@ -76,17 +67,15 @@ class Window:
         self.fence = fence
         self.step, state = self.build()
         self.ctx.mark("state_built")
-        key, cfg = reference.seed_key(self.ctx.seed), reference.freeze(self.c)
-        losses = []
+        family, losses = self.family, []
         state, m = self.step(state, self.feed(0))
         losses.append(float(fence(m)["loss"]))
         self.ctx.mark("first_step")
         adam = jax.tree_util.tree_leaves(state.opt_state, is_leaf=lambda s: hasattr(s, "mu"))[0]
-        grads = {k: v / (1 - self.hp["b1"])
-                 for k, v in reference._flat(reference.leaf_norms(adam.mu)).items()}
+        grads = {k: v / (1 - self.hp["b1"]) for k, v in family.leaf_norms(adam.mu).items()}
         state, m = self.step(state, self.feed(1))
         losses.append(float(fence(m)["loss"]))
-        change = reference._flat(reference.change_norms(state.params, key, cfg))
+        change = family.change_norms(state.params, self.c, self.ctx.seed)
         state, m = self.step(state, self.feed(2))
         self.state, self.last, self.k = state, m, 3
         self.got = {"losses": losses, "grad_norms": grads, "change_norms": change}
@@ -145,12 +134,12 @@ class Window:
         over half of each batch, each put in the program's place."""
         del self.state, self.step, self.last
         gc.collect()
-        args = (self.c, self.hp, self.batches[:3], self.ctx.seed)
-        ref = reference.train_reference(*args)
+        family, args = self.family, (self.c, self.hp, self.batches[:3], self.ctx.seed)
+        ref = family.train_reference(*args)
         readings = {}
         if control:
             half = range(self.spec["batch"] // 2)
             for tag, kw in (("control_fp8", {"fq": "fp8"}), ("fault_half_batch", {"rows": half})):
-                other = reference.compare_train(reference.train_reference(*args, **kw), ref)
+                other = family.compare_train(family.train_reference(*args, **kw), ref)
                 readings.update({f"{tag}.{k}": v for k, v in other.items()})
-        return reference.compare_train(self.got, ref), readings
+        return family.compare_train(self.got, ref), readings

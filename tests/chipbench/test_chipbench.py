@@ -1,9 +1,11 @@
 """CPU tests of the benchmark's own code (benchmarks/chipbench): the traffic generator,
 the work functions, the trace reduction on a recorded TPU trace, ``run.py --cpu-dry-run``
-end to end for each traffic kind, the control and the planted faults, and the schema of
-``BENCHMARK.json``. Nothing here is a measurement; no topology or TPU call anywhere.
+end to end for each traffic kind, the control and the planted faults, the seam through which
+a second model family comes in as files, and the schema of ``BENCHMARK.json`` and of the
+configurations' files. Nothing here is a measurement; no topology or TPU call anywhere.
 """
 
+import copy
 import json
 import os
 import re
@@ -13,14 +15,15 @@ import types
 import numpy as np
 import pytest
 
-from benchmarks.chipbench import reference, run, serve_window, trace_reduce, traffic, work
-from benchmarks.chipbench import train_window
+from benchmarks.chipbench import reference, run, schema, serve_window, trace_reduce, traffic
+from benchmarks.chipbench import train_window, work
 
 ROOT = run.ROOT
+MISTRAL = run.load_family("mistral")
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in BENCH["workloads"]]
 TRAIN, CHAT, LONG = "train_mistral7b_s8k", "serve_mistral7b_chat", "serve_mistral7b_longprompt"
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+NAME = schema.NAME
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
 
@@ -147,7 +150,8 @@ def test_kernel_work_by_hand_and_share_at_the_peak_is_100():
     at_peak.devices = {"d": [(1000, 1000 + int(1e9 * least), "m/k__mosaic_")]}
     at_peak.modules = {"d": [(0, int(2e9 * least), "m")]}
     share = trace_reduce.train_kernel_roofline(types.SimpleNamespace(
-        trace=at_peak, obs={"values": {"batch": 4, "seq": 8192}}, config=c, peak=peak),
+        trace=at_peak, obs={"values": {"batch": 4, "seq": 8192}}, config=c, family=MISTRAL,
+        peak=peak),
         "__mosaic_", "flash_work")
     assert share == pytest.approx(100.0, abs=1e-4) and share <= 100.0 + 1e-4
     c = config("mistral-7b-serve-d16")
@@ -198,7 +202,7 @@ def test_recorded_trace_gap_attribution_and_roofline(recorded):
                                 "_no_span__before__end_of_slice_": pytest.approx(5e-4)}
     run_ = types.SimpleNamespace(
         trace=recorded, obs={"values": {"batch": 4, "seq": 8192}},
-        config=config("mistral-7b-train-d2"), peak=work.peaks("TPU v5 lite"))
+        config=config("mistral-7b-train-d2"), family=MISTRAL, peak=work.peaks("TPU v5 lite"))
     share = trace_reduce.train_kernel_roofline(run_, "jit_apply_step/.*__mosaic_", "flash_work")
     # four whole steps; the fifth, cut short where the trace stops, is not counted as one:
     # 4 mosaic ops x 2 calls = 0.2323 s a step against 9.90e12 FLOPs / 197e12 = 0.0502 s
@@ -236,38 +240,176 @@ def test_no_cpu_fallback_without_a_tpu(capsys):
     assert capsys.readouterr().out == ""
 
 
-def test_a_throw_away_cell_is_files_plus_entries_and_runs_on_four_devices(tmp_path, capsys):
-    """A configuration, a traffic mix, a cell and a per-layer metric, each added as a
-    file plus a BENCHMARK.json entry — no harness edit; here on 4 virtual devices."""
+# A family of another ``model_type`` whose configuration spells every size otherwise than
+# Mistral's does: its own program config, its own counts, and a reference that renames the
+# keys and follows the same equations (the program it drives is the same ``models/llama.py``).
+TOY_FAMILY = '''
+from benchmarks.chipbench import reference as ref
+
+KEYS = {"width": "hidden_size", "ff_width": "intermediate_size", "heads": "num_attention_heads",
+        "kv_heads": "num_key_value_heads", "head_width": "head_dim", "depth": "num_hidden_layers",
+        "band": "sliding_window", "rope_base": "rope_theta", "norm_eps": "rms_norm_eps"}
+
+
+def _renamed(c):
+    return {**c, **{theirs: c[ours] for ours, theirs in KEYS.items()}}
+
+
+def program_config(c, **over):
+    from accelerate_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=c["vocab_size"], d_model=c["width"], n_layers=c["depth"], n_heads=c["heads"],
+        n_kv_heads=c["kv_heads"], head_dim_override=c["head_width"], d_ff=c["ff_width"],
+        rope_theta=c["rope_base"], norm_eps=c["norm_eps"], max_seq=c["positions"],
+        sliding_window=c["band"], tie_embeddings=False, scan_layers=True, **over)
+
+
+def loss(params, batch, cfg):
+    from accelerate_tpu.models import llama
+
+    return llama.loss_fn(params, batch, cfg)
+
+
+def gen_params(c, seed, dtype):
+    return ref.gen_params(_renamed(c), seed, dtype)
+
+
+def leaf_norms(tree):
+    return ref._flat(ref.leaf_norms(tree))
+
+
+def change_norms(params, c, seed):
+    return ref._flat(ref.change_norms(params, ref.seed_key(seed), ref.freeze(_renamed(c))))
+
+
+def train_reference(c, *args, **kw):
+    return ref.train_reference(_renamed(c), *args, **kw)
+
+
+def serve_reference(c, *args, **kw):
+    return ref.serve_reference(_renamed(c), *args, **kw)
+
+
+compare_train, compare_serve = ref.compare_train, ref.compare_serve
+
+
+def serve_flops_per_token(c):
+    return 7.0 * c["width"] * c["depth"]
+'''
+TOY_SIZES = {"model_type": "toy", "width": 128, "ff_width": 256, "heads": 4, "kv_heads": 2,
+             "head_width": 32, "depth": 2, "band": 48, "vocab_size": 512, "rope_base": 10000.0,
+             "norm_eps": 1e-05, "positions": 32768}
+
+
+def toy_tree(tmp_path):
+    """A benchmark of its own under ``tmp_path``: the toy family, a training and a serving
+    configuration on it, a traffic mix and a cell for each, and four per-layer metrics — every
+    piece a file plus a BENCHMARK.json entry, no file of the harness among them."""
     bench = tmp_path / "bench"
-    for kind in ("configs", "traffic", "metrics", "dry_run"):
+    for kind in ("configs", "traffic", "metrics", "dry_run", "families"):
         (bench / kind).mkdir(parents=True)
-    shutil.copy(os.path.join(run.HERE, "dry_run", "mistral-7b-train-d2.json"),
-                bench / "dry_run" / "tmp-config.json")
-    shutil.copy(os.path.join(run.HERE, "configs", "mistral-7b-train-d2.json"),
-                bench / "configs" / "tmp-config.json")
+    (bench / "families" / "toy.py").write_text(TOY_FAMILY)
+    train_hp = config("mistral-7b-train-d2")["train"]
+    serve_sv = traffic.load("dry_run", "mistral-7b-serve-d16")["serve"]
+    for name, own in (
+            ("toy-train", {"train": train_hp,
+                           "limits": {"grad_norm_gap": 0.002, "change_norm_gap": 0.01}}),
+            ("toy-serve", {"serve": serve_sv, "limits": {"served_logit_gap": 0.06}})):
+        (bench / "configs" / f"{name}.json").write_text(json.dumps({**TOY_SIZES, **own}))
+        (bench / "dry_run" / f"{name}.json").write_text("{}")
     shutil.copy(os.path.join(run.HERE, "traffic", "train_fixed_8k.json"),
                 bench / "traffic" / "tmp_traffic.json")
+    shutil.copy(os.path.join(run.HERE, "traffic", "chat_open_loop.json"),
+                bench / "traffic" / "tmp_chat.json")
     (bench / "metrics" / "tmp_steps.json").write_text(
         json.dumps({"reader": "value", "args": {"key": "steps"}}))
     (bench / "metrics" / "tmp_own.py").write_text(
         "def read(run):\n    return float(run.obs['attempted'])\n")
-    e2e = [m for m in BENCH["end_to_end"] if "workloads" not in m or TRAIN in m["workloads"]]
-    e2e = [{**m, "workloads": ["tmp_cell"]} if "workloads" in m else m for m in e2e]
-    layer = {"unit": "count", "better": "higher", "source": "program_counter",
-             "layer": "train step", "moves": "train_tokens_per_s_per_chip"}
+    (bench / "metrics" / "tmp_rate.json").write_text(
+        json.dumps({"reader": "value", "args": {"key": "tokens_processed_per_s"}}))
+    # a dry run has no chip and so no peak: the reader states one, and ``mfu`` does the rest
+    (bench / "metrics" / "tmp_mfu.py").write_text(
+        "from benchmarks.chipbench import trace_reduce\n\n\n"
+        "def read(run):\n"
+        "    run.peak = {'bf16_flops': 1e9}\n"
+        "    return trace_reduce.mfu(run, rate='tokens_processed_per_s',\n"
+        "                            flops='serve_flops_per_token')\n")
+    cells = {"tmp_cell": "train_tokens_per_s_per_chip", "tmp_serve": "tpot_ms_p90"}
+    e2e = [{**m, "workloads": [cell]} for cell, moved in cells.items()
+           for m in BENCH["end_to_end"] if m["name"] == moved]
+    e2e.append(next(m for m in BENCH["end_to_end"] if m["name"] == "setup_s"))
+    layer = {"unit": "count", "better": "higher", "source": "program_counter", "layer": "any"}
+    per_layer = [{"name": name, **layer, "moves": cells[cell], "workloads": [cell]}
+                 for name, cell in (("tmp_steps", "tmp_cell"), ("tmp_own", "tmp_cell"),
+                                    ("tmp_rate", "tmp_serve"), ("tmp_mfu", "tmp_serve"))]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps({
-        "configs": [{"name": "tmp-config", "file": "bench/configs/tmp-config.json"}],
-        "workloads": [{"name": "tmp_cell", "config": "tmp-config", "traffic": "tmp_traffic",
-                       "chips": 4}],
-        "end_to_end": e2e,
-        "per_layer": [{"name": "tmp_steps", **layer}, {"name": "tmp_own", **layer}]}))
-    rc = run.main(["--workload", "tmp_cell", "--seed", "3", "--seconds", "2", "--trace", "1",
+        "paths": ["bench"],
+        "configs": [{"name": n, "file": f"bench/configs/{n}.json"}
+                    for n in ("toy-train", "toy-serve")],
+        "workloads": [{"name": "tmp_cell", "config": "toy-train", "traffic": "tmp_traffic",
+                       "chips": 4},
+                      {"name": "tmp_serve", "config": "toy-serve", "traffic": "tmp_chat",
+                       "chips": 1}],
+        "end_to_end": e2e, "per_layer": per_layer}))
+    return bench
+
+
+def toy_run(tmp_path, capsys, workload, seconds):
+    rc = run.main(["--workload", workload, "--seed", "3", "--seconds", seconds, "--trace", "1",
                    "--cpu-dry-run", "--root", str(tmp_path)])
-    line = last_line(capsys)
+    return rc, last_line(capsys)
+
+
+def test_a_throw_away_cell_is_files_plus_entries_and_runs_on_four_devices(tmp_path, capsys):
+    """A family, a configuration, a traffic mix, a cell and a per-layer metric, each added as
+    a file plus a BENCHMARK.json entry — no harness edit; here on 4 virtual devices."""
+    toy_tree(tmp_path)
+    rc, line = toy_run(tmp_path, capsys, "tmp_cell", "2")
     assert rc == 0 and line["device"]["count"] == 4 and line["correct"] is True
     assert line["metrics"]["tmp_steps"]["value"] == line["attempted"]
     assert line["metrics"]["tmp_own"]["value"] == line["attempted"]
+    assert set(line["compared"]) == {"grad_norm_gap", "change_norm_gap"}
+
+
+def test_a_throw_away_family_serves_and_its_own_count_is_what_mfu_reads(tmp_path, capsys):
+    toy_tree(tmp_path)
+    rc, line = toy_run(tmp_path, capsys, "tmp_serve", "4")
+    assert rc == 0 and line["correct"] is True and line["readings"]["tokens_compared"] > 10
+    rate = line["metrics"]["tmp_rate"]["value"]
+    # the toy's count, 7 × width 128 × depth 2 a token, against the stated 1e9 FLOP/s — not
+    # Mistral's 2 × matmul parameters, whose keys this configuration does not even have
+    assert line["metrics"]["tmp_mfu"]["value"] == pytest.approx(100.0 * rate * 7 * 128 * 2 / 1e9)
+    with pytest.raises(KeyError):
+        work.serve_flops_per_token(TOY_SIZES)
+
+
+def test_windows_and_readers_name_no_model():
+    """Everything of the model comes through the family resolved from ``model_type``."""
+    for module in (run, serve_window, train_window, trace_reduce):
+        with open(module.__file__) as f:
+            source = f.read()
+        for banned in (r"llama", r"import reference", r"\breference\.\w", r"getattr\(work",
+                       r"work\.(matmul|paged|flash|serve_flops|train_flops)"):
+            assert not re.search(banned, source, re.I), (module.__name__, banned)
+
+
+def test_a_model_type_without_a_family_names_the_file_it_looked_for(tmp_path):
+    with pytest.raises(FileNotFoundError, match=r"families/deepseek_v3\.py"):
+        run.load_family("deepseek_v3")
+    with pytest.raises(FileNotFoundError, match=str(tmp_path)):
+        run.load_family("mistral", str(tmp_path))
+
+
+def test_a_family_that_only_serves_refuses_a_training_cell(tmp_path):
+    (tmp_path / "families").mkdir()
+    (tmp_path / "families" / "half.py").write_text(
+        "program_config = gen_params = serve_reference = compare_serve = print\n")
+    half = run.load_family("half", str(tmp_path))
+    assert run.load_window("serve_window", half) is serve_window
+    with pytest.raises(NotImplementedError, match="half.py has no loss, leaf_norms.*train_window"):
+        run.load_window("train_window", half)
+    assert run.load_window("train_window", MISTRAL) is train_window
 
 
 # ------------------------------------------------------------- the control and the faults
@@ -388,16 +530,89 @@ def test_benchmark_json_names_units_and_arrows():
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda e: e["name"])
 def test_config_files_hold_published_widths_and_list_what_was_cut(entry):
-    published = {"hidden_size": 4096, "intermediate_size": 14336, "num_attention_heads": 32,
-                 "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 32000,
-                 "sliding_window": 4096, "rope_theta": 10000.0, "rms_norm_eps": 1e-05}
-    assert NAME.match(entry["name"]) and entry["file"].startswith(BENCH["paths"][0] + "/")
-    with open(os.path.join(ROOT, entry["file"])) as f:
-        c = json.load(f)
-    assert {k: c[k] for k in published} == published
-    assert entry["reduced"] == c["reduced"] == ["num_hidden_layers"]
-    assert c["num_hidden_layers"] < c["published"]["num_hidden_layers"] == 32
-    assert all(v is not None for v in c["limits"].values())
+    """The rules follow the configuration (schema.py); Mistral's published widths are
+    pinned there by its source, which both accepted configurations name."""
+    assert schema.entry_problems(BENCH, entry, ROOT) == []
+
+
+def accepted_tree(tmp_path):
+    """A copy of the accepted configurations, their family and BENCHMARK.json in ``tmp_path``."""
+    for sub in ("configs", "families"):
+        shutil.copytree(os.path.join(run.HERE, sub), tmp_path / BENCH["paths"][0] / sub)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(BENCH))
+    return tmp_path / BENCH["configs"][0]["file"]
+
+
+@pytest.mark.parametrize("key,value,problem", [
+    (None, None, None),
+    ("hidden_size", 2048, "published sizes changed"),
+    ("num_hidden_layers", 32, "is no cut of the published 32"),
+    ("reduced", ["num_hidden_layers", "vocab_size"], "in the entry"),
+    ("published", {"num_hidden_layers": 32, "vocab_size": 64000}, "exactly the keys of reduced"),
+    ("source", "https://example.org/config.json", "source differs"),
+    ("limits", {"grad_norm_gap": None}, "limits is empty or holds a null"),
+    ("model_type", "mixtral", "families/mixtral.py"),
+])
+def test_schema_rules_bite_on_a_copy_of_the_accepted_tree(tmp_path, key, value, problem):
+    file = accepted_tree(tmp_path)
+    if key is not None:
+        file.write_text(json.dumps({**json.loads(file.read_text()), key: value}))
+    problems = schema.config_problems(str(tmp_path))
+    if problem is None:
+        assert problems == []
+    else:
+        assert len(problems) >= 1 and any(problem in p for p in problems), problems
+        assert all(p.startswith(BENCH["configs"][0]["name"] + ": ") for p in problems)
+
+
+# The catalog's DeepSeek-V3 row (model-configs guide; source below), cut as ISSUE 27 sizes it
+# for one of 16 chips that share each layer: 16 of 256 experts, an eighth of the vocabulary,
+# one of the three leading dense layers and four MoE layers. Every width is as published.
+DEEPSEEK_V3 = {
+    "source": "https://huggingface.co/deepseek-ai/DeepSeek-V3/blob/main/config.json",
+    "attention_bias": False, "ep_size": 1, "first_k_dense_replace": 1, "hidden_act": "silu",
+    "hidden_size": 7168, "intermediate_size": 18432, "kv_lora_rank": 512,
+    "max_position_embeddings": 163840, "model_type": "deepseek_v3",
+    "moe_intermediate_size": 2048, "moe_layer_freq": 1, "n_group": 8, "n_routed_experts": 16,
+    "n_shared_experts": 1, "norm_topk_prob": True, "num_attention_heads": 128,
+    "num_experts_per_tok": 8, "num_hidden_layers": 5, "num_key_value_heads": 128,
+    "num_nextn_predict_layers": 1, "q_lora_rank": 1536, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "rms_norm_eps": 1e-06,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+                     "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+                     "type": "yarn"},
+    "rope_theta": 10000, "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+    "tie_word_embeddings": False, "topk_group": 4, "topk_method": "noaux_tc", "v_head_dim": 128,
+    "vocab_size": 16160,
+    "reduced": ["n_routed_experts", "vocab_size", "num_hidden_layers", "first_k_dense_replace"],
+    "published": {"n_routed_experts": 256, "vocab_size": 129280, "num_hidden_layers": 61,
+                  "first_k_dense_replace": 3},
+    "deployment": "one of 16 chips that share each layer by expert parallelism",
+    "serve": {"dtype": "bfloat16"}, "limits": {"served_logit_gap": 0.25},
+}
+
+
+@pytest.mark.parametrize("change,problem", [
+    ({}, None),
+    ({"n_routed_experts": 4}, "fewer than 8 routed experts held"),
+    ({"published": {"n_routed_experts": 256, "vocab_size": 129280, "num_hidden_layers": 61}},
+     "exactly the keys of reduced"),
+    ({"model_type": "deepseek_v4"}, "families/deepseek_v4.py"),
+], ids=["as_cut", "four_experts", "reduced_key_not_published", "unknown_model_type"])
+def test_a_deepseek_v3_shaped_configuration_passes_the_schema_rules(tmp_path, change, problem):
+    """The next ``model_config`` PR's configuration is files and entries: the rules read its
+    own keys (experts held, vocabulary share, layers after the leading dense one)."""
+    for sub in ("configs", "families"):
+        (tmp_path / "bench" / sub).mkdir(parents=True)
+    (tmp_path / "bench" / "families" / "deepseek_v3.py").write_text("# a stub: the file is there\n")
+    c = {**copy.deepcopy(DEEPSEEK_V3), **change}
+    (tmp_path / "bench" / "configs" / "deepseek-v3-serve.json").write_text(json.dumps(c))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({"paths": ["bench"], "configs": [{
+        "name": "deepseek-v3-serve", "source": DEEPSEEK_V3["source"],
+        "file": "bench/configs/deepseek-v3-serve.json", "reduced": DEEPSEEK_V3["reduced"],
+        "why": "MLA, sigmoid router over 256 experts of which 16 live here"}]}))
+    problems = schema.config_problems(str(tmp_path))
+    assert (problems == []) if problem is None else any(problem in p for p in problems), problems
 
 
 def test_every_per_layer_metric_has_a_reader_file():
