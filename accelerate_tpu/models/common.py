@@ -184,6 +184,59 @@ def read_kv_paged(new_kv: dict, name: str, tables: jax.Array, length: int,
     return gather_pages(new_kv, name, tables, length, dtype)
 
 
+# ------------------------------------------------------------- latent (MLA) cache planes
+def latent_width(values: int) -> int:
+    """Width of a latent cache row that holds ``values`` (c_kv | k_rope): whole 128-lane
+    tiles. The chip's memory lays a 576-wide row out as 640 anyway, and Mosaic cannot
+    cut a page out of a plane declared 576 wide; the lanes past ``values`` are never
+    written with anything but zeros and never read."""
+    return -(-values // 128) * 128
+
+
+def latent_planes(batch: int, max_len: int, values: int, dtype) -> dict:
+    """One layer's empty dense LATENT cache: ``{"latent": [B, max_len, latent_width]}``
+    — the K/V-free counterpart of :func:`kv_planes` for latent attention: one row of
+    ``values`` a token, shared by every head."""
+    return {"latent": jnp.zeros((batch, max_len, latent_width(values)), dtype)}
+
+
+def paged_latent_planes(num_pages: int, page_size: int, values: int, dtype) -> dict:
+    """One layer's empty paged LATENT pool: ``{"latent": [P, page_size,
+    latent_width]}`` — the second page layout beside :func:`paged_kv_planes`'s
+    ``{k, v} [P, page_size, K, hd]``; ``paged_kv.BlockManager`` (pages and tables only)
+    serves both."""
+    return {"latent": jnp.zeros((num_pages, page_size, latent_width(values)), dtype)}
+
+
+def write_latent_paged(kv: dict, val: jax.Array, pages: jax.Array,
+                       offs: jax.Array) -> dict:
+    """Write latent rows ``val`` [B,T,values] at physical slots ``(pages[b,t],
+    offs[b,t])`` of the pool plane, in place on a donated carry; sentinel page ids are
+    out of bounds and DROP (:func:`write_kv_paged`'s contract)."""
+    pool = kv["latent"]
+    row = jnp.pad(val.astype(pool.dtype),
+                  ((0, 0), (0, 0), (0, pool.shape[-1] - val.shape[-1])))
+    return {"latent": pool.at[pages, offs].set(row)}
+
+
+def paged_read_impl() -> str:
+    """``"kernel"`` or ``"gather"``: which paged-attention read a decoder family takes
+    — the Pallas kernel on a TPU backend (or when forced), else the gather through the
+    table. ``ACCEL_PAGED_ATTN`` ∈ {auto, kernel, gather} picks it at trace time."""
+    import os
+
+    from ..utils.imports import is_tpu_available
+
+    impl = os.environ.get("ACCEL_PAGED_ATTN", "auto")
+    if impl not in ("auto", "kernel", "gather"):
+        raise ValueError(
+            f"ACCEL_PAGED_ATTN={impl!r}: expected 'auto', 'kernel' or 'gather'"
+        )
+    if impl == "auto":
+        impl = "kernel" if is_tpu_available() else "gather"
+    return impl
+
+
 def paged_write_coords(tables: jax.Array, pos_grid: jax.Array, page_size: int,
                        max_len: int, num_pages: int):
     """Physical (page, slot) write coordinates for logical positions
@@ -372,16 +425,7 @@ def paged_attention_dispatch(q, pool, tables, positions, valid, *, page_size: in
     backend probe in :func:`attention_dispatch`); ``dense_attention(ck, cv)`` is the
     family's gather-path closure over its q/positions/valid/cfg. A kernel the compiler
     refuses is an error at the call — there is no fallback from one path to the other."""
-    import os
-
-    from ..utils.imports import is_tpu_available
-
-    impl = os.environ.get("ACCEL_PAGED_ATTN", "auto")
-    if impl not in ("auto", "kernel", "gather"):
-        raise ValueError(
-            f"ACCEL_PAGED_ATTN={impl!r}: expected 'auto', 'kernel' or 'gather'"
-        )
-    if impl == "kernel" or (impl == "auto" and is_tpu_available()):
+    if paged_read_impl() == "kernel":
         from ..ops.paged_attention import paged_attention
 
         return paged_attention(

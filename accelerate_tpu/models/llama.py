@@ -1461,6 +1461,24 @@ def forward_cached(
     return logits, new_cache
 
 
+def forward_cached_logits(params: dict, tokens: jax.Array, cache: dict, cfg: LlamaConfig,
+                          token_mask: Optional[jax.Array] = None):
+    """:func:`forward_cached` with the logits of EVERY position [B,T,V] — what the
+    serving engine's prefix-cache prefill calls (a right-aligned prompt's last real
+    token may sit before trailing pads)."""
+    return forward_cached(params, tokens, cache, cfg, token_mask=token_mask)
+
+
+def paged_walk_shape(cfg: LlamaConfig, page_size: int, itemsize: int,
+                     max_pages: int) -> tuple:
+    """(table entries the paged-attention kernel fetches an iteration, its window) for
+    the serving engine's ``pages_live`` / ``pages_walked`` counters."""
+    from ..ops.paged_attention import block_pages
+
+    return (block_pages(page_size, cfg.n_kv_heads, cfg.head_dim, itemsize, max_pages),
+            cfg.sliding_window)
+
+
 def forward_slots(
     params: dict,
     tokens: jax.Array,

@@ -55,6 +55,7 @@ the dense layout (tests/test_serving_paged.py).
 from __future__ import annotations
 
 import dataclasses
+import sys
 import time
 from collections import OrderedDict, deque
 from functools import partial
@@ -72,8 +73,6 @@ from .generation import (
     sampling_core_dyn_k,
     speculative_accept_batch,
 )
-from .models import llama
-from .models.llama import init_cache
 from .paged_kv import BlockManager, KVBudgetError, pages_for
 from .resilience.faults import EngineCrashed, StepWatchdog
 from .telemetry.compile_monitor import compile_label
@@ -100,6 +99,26 @@ __all__ = ["ContinuousBatcher", "KVBudgetError", "KVHandoff", "Request",
 #: (COW at the write boundary, the prefix-cache adoption semantics generalized
 #: across engines) and runs decode-only lanes at high occupancy.
 ENGINE_ROLES = ("mixed", "prefill", "decode")
+
+
+def _model(cfg):
+    """The model module the engine runs: the one that defines the config's class
+    (``models/llama.py`` for a ``LlamaConfig``, ``models/deepseek.py`` for a
+    ``DeepseekConfig``). The ONE seam between the engine and a decoder: the jitted
+    programs below call it at trace time (``cfg`` is static), ``__init__`` resolves it
+    once and refuses a module that lacks what the requested paths call."""
+    return sys.modules[type(cfg).__module__]
+
+
+#: What each engine path calls on the model module (``__init__`` checks them by name).
+_PATH_CALLS = {
+    "prefill": ("init_cache", "forward_cached"),
+    "prefix_cache": ("forward_cached_logits",),
+    "dense rows (page_size=0)": ("forward_slots",),
+    "paged": ("init_paged_cache", "forward_slots_paged", "paged_walk_shape"),
+    "decode_steps > 1": ("forward_slots_multi",),
+    "spec_k": ("forward_slots", "forward_slots_spec_multi"),
+}
 
 
 @partial(jax.jit, static_argnames=("top_k",))
@@ -248,7 +267,7 @@ def _decode_step(params, cache, tokens, positions, cfg):
 
     The greedy argmax stays fused on-device; the logits matrix is only fetched host-side
     when a sampled (temperature > 0) request is active."""
-    logits, cache = llama.forward_slots(params, tokens[:, None], cache, positions, cfg)
+    logits, cache = _model(cfg).forward_slots(params, tokens[:, None], cache, positions, cfg)
     logits = logits[:, -1, :]
     with jax.named_scope("sample"):
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -267,7 +286,7 @@ def _spec_verify_step(params, cache, tokens, positions, cfg):
     is what makes prefix acceptance lossless. Rejected proposals leave garbage K/V
     above the lane's rewound position; the causal mask hides it until the next step's
     writes land on those very slots."""
-    logits, cache = llama.forward_slots(params, tokens, cache, positions, cfg)
+    logits, cache = _model(cfg).forward_slots(params, tokens, cache, positions, cfg)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
 
 
@@ -340,7 +359,7 @@ def _decode_step_paged(params, cache, tables, tokens, positions, cfg, page_size:
     (Pallas kernel on TPU, gather + the same dense math on CPU — bitwise the dense
     engine there). ``tables`` [B, MP] is uploaded per step (host-side page allocation
     never rebuilds device state)."""
-    logits, cache = llama.forward_slots_paged(
+    logits, cache = _model(cfg).forward_slots_paged(
         params, tokens[:, None], cache, tables, positions, cfg, page_size
     )
     logits = logits[:, -1, :]
@@ -356,7 +375,7 @@ def _spec_verify_step_paged(params, cache, tables, tokens, positions, cfg,
     whose K/V lives in pool pages. Draft writes past a lane's allocated pages route
     through the SENTINEL table entry and drop (the paged spelling of the dense
     path's out-of-bounds-scatter contract for non-load-bearing draft tails)."""
-    logits, cache = llama.forward_slots_paged(
+    logits, cache = _model(cfg).forward_slots_paged(
         params, tokens, cache, tables, positions, cfg, page_size
     )
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
@@ -398,11 +417,11 @@ def _decode_multi_step(params, cache, tokens, positions, active, budgets, eos_id
     all happen in-scan (``llama.forward_slots_multi``); the host drains the
     token buffer once per super-step instead of once per token."""
     select_token, xs = _multi_select(sample, keys, temps, top_ps, top_ks)
-    cache, tok_buf, counts = llama.forward_slots_multi(
+    cache, tok_buf, counts, *model_counts = _model(cfg).forward_slots_multi(
         params, cache, tokens, positions, active, budgets, eos_ids,
         select_token, xs, n_steps, cfg,
     )
-    return tok_buf, counts, cache
+    return (tok_buf, counts, cache, *model_counts)
 
 
 @partial(jax.jit, static_argnames=("cfg", "n_steps", "sample", "page_size"),
@@ -416,11 +435,11 @@ def _decode_multi_step_paged(params, cache, tables, tokens, positions, active,
     ``BlockManager.admit`` — so no table entry can appear mid-scan; frozen/past-
     budget positions route to the sentinel and drop)."""
     select_token, xs = _multi_select(sample, keys, temps, top_ps, top_ks)
-    cache, tok_buf, counts = llama.forward_slots_multi(
+    cache, tok_buf, counts, *model_counts = _model(cfg).forward_slots_multi(
         params, cache, tokens, positions, active, budgets, eos_ids,
         select_token, xs, n_steps, cfg, tables=tables, page_size=page_size,
     )
-    return tok_buf, counts, cache
+    return (tok_buf, counts, cache, *model_counts)
 
 
 def _spec_multi_select(sample: bool, temps, top_ps, top_ks):
@@ -473,7 +492,7 @@ def _spec_multi_step(params, cache, tokens, positions, active, budgets, eos_ids,
         hist, lens, spec_k, max_ngram)
     select_ref = _spec_multi_select(sample, temps, top_ps, top_ks)
     cache, tok_buf, emits, counts, proposed, accepted = (
-        llama.forward_slots_spec_multi(
+        _model(cfg).forward_slots_spec_multi(
             params, cache, tokens, positions, active, budgets, eos_ids,
             propose, select_ref, key_tab, history, hist_lens, n_steps, spec_k,
             cfg,
@@ -501,7 +520,7 @@ def _spec_multi_step_paged(params, cache, tables, tokens, positions, active,
         hist, lens, spec_k, max_ngram)
     select_ref = _spec_multi_select(sample, temps, top_ps, top_ks)
     cache, tok_buf, emits, counts, proposed, accepted = (
-        llama.forward_slots_spec_multi(
+        _model(cfg).forward_slots_spec_multi(
             params, cache, tokens, positions, active, budgets, eos_ids,
             propose, select_ref, key_tab, history, hist_lens, n_steps, spec_k,
             cfg, tables=tables, page_size=page_size,
@@ -634,8 +653,8 @@ def _set_lane_valid(cache, slot, valid_row):
 
 @partial(jax.jit, static_argnames=("cfg", "max_len"))
 def _prefill_jit(params, row, mask, cfg, max_len: int):
-    cache = init_cache(cfg, 1, max_len)
-    logits, cache = llama.forward_cached(
+    cache = _model(cfg).init_cache(cfg, 1, max_len)
+    logits, cache = _model(cfg).forward_cached(
         params, row, cache, cfg, token_mask=mask, last_only=True
     )
     last = logits[:, -1, :]
@@ -646,7 +665,7 @@ def _prefill_jit(params, row, mask, cfg, max_len: int):
 def _prefill_chunk_jit(params, row, mask, cache, cfg):
     """Chunked prefill continuation: append one bucket-width chunk to an existing row
     cache. One compiled executable serves every chunk of every long prompt."""
-    logits, cache = llama.forward_cached(
+    logits, cache = _model(cfg).forward_cached(
         params, row, cache, cfg, token_mask=mask, last_only=True
     )
     last = logits[:, -1, :]
@@ -658,8 +677,8 @@ def _prefill_full_logits_jit(params, row, mask, cfg, max_len: int):
     """Right-aligned prefill (prefix-cache layout): fresh cache + one chunk, returning
     per-position logits (the caller indexes the real last token, which may sit before
     trailing pads)."""
-    cache = init_cache(cfg, 1, max_len)
-    logits, cache = llama.forward_cached(params, row, cache, cfg, token_mask=mask)
+    cache = _model(cfg).init_cache(cfg, 1, max_len)
+    logits, cache = _model(cfg).forward_cached_logits(params, row, cache, cfg, token_mask=mask)
     return logits, cache
 
 
@@ -667,7 +686,7 @@ def _prefill_full_logits_jit(params, row, mask, cfg, max_len: int):
 def _prefill_chunk_keep_jit(params, row, mask, cache, cfg):
     """Chunk append WITHOUT donating the input cache — the prefix registry keeps the
     input state alive for reuse by later prompts sharing this prefix."""
-    logits, cache = llama.forward_cached(params, row, cache, cfg, token_mask=mask)
+    logits, cache = _model(cfg).forward_cached_logits(params, row, cache, cfg, token_mask=mask)
     return logits, cache
 
 
@@ -691,6 +710,18 @@ class ContinuousBatcher:
                  decode_steps: int = 1):
         self.params = params
         self.cfg = cfg
+        #: The model module (the seam: :func:`_model`), and what this engine's paths
+        #: call on it, checked by name before any program is built.
+        self.model = _model(cfg)
+        paths = ["prefill", "paged" if page_size else "dense rows (page_size=0)"]
+        paths += ["decode_steps > 1"] * (decode_steps > 1) + ["spec_k"] * bool(spec_k)
+        paths += ["prefix_cache"] * bool(prefix_cache)
+        for path in paths:
+            for fn in _PATH_CALLS[path]:
+                if not hasattr(self.model, fn):
+                    raise NotImplementedError(
+                        f"{self.model.__name__} has no {fn}: the engine's {path} path "
+                        f"calls it, so this model cannot be served that way")
         self.max_slots = max_slots
         self.max_len = max_len
         self.prompt_bucket = prompt_bucket
@@ -886,14 +917,14 @@ class ContinuousBatcher:
             self.block_mgr = BlockManager(
                 int(kv_pages), self.page_size, max_slots, max_len
             )
-            self.cache = llama.init_paged_cache(
+            self.cache = self.model.init_paged_cache(
                 cfg, max_slots, max_len, int(kv_pages), self.page_size
             )
             self.kv_page_bytes = self.cache_bytes() // int(kv_pages)
         else:
             self.block_mgr = None
             self.kv_page_bytes = 0
-            self.cache = init_cache(cfg, max_slots, max_len)
+            self.cache = self.model.init_cache(cfg, max_slots, max_len)
         self.tokens = np.zeros((max_slots,), np.int32)  # host-side; uploaded per decode
         self.positions = np.zeros((max_slots,), np.int32)  # next write slot per lane
         self.slot_req: list[Optional[Request]] = [None] * max_slots
@@ -1369,14 +1400,14 @@ class ContinuousBatcher:
                 self._kv_pages_total, self.page_size, self.max_slots,
                 self.max_len,
             )
-            self.cache = llama.init_paged_cache(
+            self.cache = self.model.init_paged_cache(
                 self.cfg, self.max_slots, self.max_len, self._kv_pages_total,
                 self.page_size,
             )
         else:
             # Dense prefix snapshots are independent row caches (the keep-alive
             # chunk program never donates) — they survive a cache rebuild.
-            self.cache = init_cache(self.cfg, self.max_slots, self.max_len)
+            self.cache = self.model.init_cache(self.cfg, self.max_slots, self.max_len)
         self.slot_req = [None] * self.max_slots
         self.positions[:] = 0
         self.tokens[:] = 0
@@ -1791,18 +1822,18 @@ class ContinuousBatcher:
         """What the paged-attention kernel walks at the first decode step of a
         dispatch, one layer's worth summed over the active lanes: ``pages_walked``
         table entries fetched, of which ``pages_live`` hold a key the lane's query
-        may see (``ops.paged_attention.walk_range``, the kernel's own rule). On a
+        may see (``ops.paged_attention.walk_range``, the rule both paged kernels
+        share; the block and the window are the model's ``paged_walk_shape``). On a
         model that alternates banded and full layers this is a banded layer's."""
-        from .ops.paged_attention import block_pages, walk_range
+        from .ops.paged_attention import walk_range
 
-        cfg = self.cfg
         pool_dtype = jax.tree_util.tree_leaves(self.cache["layers"])[0].dtype
-        block = block_pages(self.page_size, cfg.n_kv_heads, cfg.head_dim,
-                            pool_dtype.itemsize, self.block_mgr.max_pages)
+        block, window = self.model.paged_walk_shape(
+            self.cfg, self.page_size, pool_dtype.itemsize, self.block_mgr.max_pages)
         _, blocks, pages = walk_range(
             self.positions[active],
             np.array([self._lane_valid[i][0] for i in active], np.int32),
-            T=1, window=cfg.sliding_window, page_size=self.page_size, block=block)
+            T=1, window=window, page_size=self.page_size, block=block)
         return {"pages_live": int(pages.sum()), "pages_walked": int(blocks.sum()) * block}
 
     def _multi_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
@@ -1871,7 +1902,7 @@ class ContinuousBatcher:
         if self.paged:
             with compile_label("serving.decode_multi_paged"), \
                     phase("engine.decode.dispatch", **self._paged_walk(active)):
-                tok_buf, counts, self.cache = self._decode_multi_paged_fn(
+                tok_buf, counts, self.cache, *model_counts = self._decode_multi_paged_fn(
                     self.params, self.cache, tables, *lane_args,
                     cfg=self.cfg, n_steps=N, sample=sampled,
                     page_size=self.page_size,
@@ -1879,13 +1910,15 @@ class ContinuousBatcher:
         else:
             with compile_label("serving.decode_multi"), \
                     phase("engine.decode.dispatch"):
-                tok_buf, counts, self.cache = self._decode_multi_fn(
+                tok_buf, counts, self.cache, *model_counts = self._decode_multi_fn(
                     self.params, self.cache, *lane_args,
                     cfg=self.cfg, n_steps=N, sample=sampled,
                 )
         with phase("engine.decode.fetch"):
             tok_host = np.asarray(tok_buf)     # [N, B]
             counts_host = np.asarray(counts)   # [B]
+            # what the model counted beside its tokens (its DECODE_COUNTERS), if anything
+            model_counts = [np.asarray(c) for c in model_counts]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
         # Drain in exact generation order (step-major, lane-minor — the order N
         # sequential _plain_step calls would have appended), clamped to each
@@ -1916,7 +1949,9 @@ class ContinuousBatcher:
                     self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
                     self._release_lane(i)
             self.positions = np.minimum(self.positions, self.max_len - 1)
-            drain.set_metadata(tokens=step_tokens)
+            drain.set_metadata(tokens=step_tokens, **{
+                name: int(v) for c in model_counts
+                for name, v in zip(self.model.DECODE_COUNTERS, c)})
         self.decode_steps += 1
         self.decode_tokens += step_tokens
         if tracing:
@@ -2432,7 +2467,7 @@ class ContinuousBatcher:
                     self.cache, 0, jnp.zeros((self.max_len,), bool),
                 ))
                 return entries  # no prefill surface, by construction
-            row0 = init_cache(self.cfg, 1, self.max_len)
+            row0 = self.model.init_cache(self.cfg, 1, self.max_len)
             entries.append(self._insert_paged_fn.warm(
                 self.cache, row0, write_ids, 0,
                 page_size=self.page_size, scan_layers=self.cfg.scan_layers,
@@ -2491,7 +2526,7 @@ class ContinuousBatcher:
             entries.append(self._prefill_full_logits_fn.warm(
                 self.params, row, mask, cfg=self.cfg, max_len=self.max_len
             ))
-            row_cache = init_cache(self.cfg, 1, self.max_len)
+            row_cache = self.model.init_cache(self.cfg, 1, self.max_len)
             entries.append(self._prefill_chunk_keep_fn.warm(
                 self.params, row, mask, row_cache, cfg=self.cfg
             ))
@@ -2512,13 +2547,13 @@ class ContinuousBatcher:
                 entries.append(self._prefill_fn.warm(
                     self.params, row, mask, cfg=self.cfg, max_len=self.max_len
                 ))
-                row_cache = init_cache(self.cfg, 1, self.max_len)
+                row_cache = self.model.init_cache(self.cfg, 1, self.max_len)
                 entries.append(self._prefill_chunk_fn.warm(
                     self.params, row, mask, row_cache, cfg=self.cfg
                 ))
         if not self.paged:
             if row_cache is None:
-                row_cache = init_cache(self.cfg, 1, self.max_len)
+                row_cache = self.model.init_cache(self.cfg, 1, self.max_len)
             for slot in range(self.max_slots):
                 entries.append(self._insert_row_fn.warm(
                     self.cache, row_cache, slot=slot, scan_layers=self.cfg.scan_layers

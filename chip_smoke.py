@@ -447,6 +447,49 @@ def kernel_paged(cfg, sizes: Sizes, quantized: bool) -> None:
         close(got, want, TOL_BF16, f"paged attention {tag} pages, T={T}")
 
 
+def kernel_mla() -> None:
+    """mla_paged_attention at DeepSeek-V3's published widths (128 heads over latent rows
+    of 512 + 64) on 32 lanes of 1 to 13 056 keys, vs mla_paged_attention_reference."""
+    from accelerate_tpu.models import deepseek
+    from accelerate_tpu.models.common import paged_latent_planes, write_latent_paged
+    from accelerate_tpu.ops.mla_attention import (
+        mla_paged_attention, mla_paged_attention_reference)
+
+    cfg = deepseek.DeepseekConfig()
+    H, R, r, ps = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_dim, PAGE_SIZE
+    B, C = 32, 16384
+    MP = C // ps
+    rng = np.random.default_rng(SEED + 8)
+    lens = np.concatenate([[0, 1, ps, 767, 768, 769], rng.integers(4096, 13057, B - 6)])
+    P = int(sum(-(-int(n) // ps) for n in lens)) + 1
+    tables = np.full((B, MP), P, np.int32)      # sentinel = unallocated
+    free = list(rng.permutation(P))
+    valid = np.zeros((B, C), bool)
+    for b, n in enumerate(lens):
+        for j in range(-(-int(n) // ps)):
+            tables[b, j] = free.pop()
+        valid[b, min(b, int(n)):n] = True       # lane b is left-padded by b slots
+    kl, kq, kr = jax.random.split(jax.random.PRNGKey(SEED + 8), 3)
+    pool = paged_latent_planes(P, ps, R + r, jnp.bfloat16)
+    write = jax.jit(write_latent_paged, donate_argnums=0)
+    for b in range(B):                          # a lane at a time: the rows are 19 MB each
+        pos = np.arange(C)
+        pages = np.where(valid[b], tables[b, pos // ps], P)[None]
+        rows = jax.random.normal(jax.random.fold_in(kl, b), (1, C, R + r), jnp.bfloat16)
+        pool = write(pool, rows, jnp.asarray(pages), jnp.asarray(pos % ps)[None])
+    q_lat = jax.random.normal(kq, (B, H, R), jnp.bfloat16)
+    q_rope = jax.random.normal(kr, (B, H, r), jnp.bfloat16)
+    kw = dict(page_size=ps, sm_scale=deepseek.sm_scale(cfg) / math.sqrt(R / cfg.qk_nope_dim))
+    args = (q_lat, q_rope, pool["latent"], jnp.asarray(tables),
+            jnp.asarray(np.maximum(lens - 1, 0).astype(np.int32)), jnp.asarray(valid))
+    got = twice("mla paged", jax.jit(
+        lambda *a: mla_paged_attention(*a, interpret=False, **kw)), *args)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: mla_paged_attention_reference(*a, **kw))(*args)
+    close(got, want, TOL_BF16, "mla paged attention")
+    check(not np.asarray(got[0], np.float32).any(), "mla paged attention: an empty lane emits zeros")
+
+
 def kernel_xent(cfg) -> None:
     """fused_xent fwd / dx / dw at [2048, d_model] x [d_model, vocab] vs chunked_ce."""
     from accelerate_tpu.models.common import chunked_ce
@@ -552,6 +595,7 @@ def kernels(cfg, sizes: Sizes, compiles: Compiles, dry: bool) -> None:
     kernel_flash_packed(cfg, sizes)
     kernel_paged(cfg, sizes, quantized=False)
     kernel_paged(cfg, sizes, quantized=True)
+    kernel_mla()
     kernel_xent(cfg)
     kernel_adamw(cfg)
     kernel_int8_matmul(cfg)

@@ -57,6 +57,7 @@ MODULES = [
     ("accelerate_tpu.paged_kv", "Paged KV block manager"),
     ("accelerate_tpu.ops.flash_attention", "Flash attention"),
     ("accelerate_tpu.ops.paged_attention", "Paged attention"),
+    ("accelerate_tpu.ops.mla_attention", "Latent (MLA) paged attention"),
     ("accelerate_tpu.ops.ring_attention", "Ring attention"),
     ("accelerate_tpu.ops.moe", "Mixture of experts"),
     ("accelerate_tpu.ops.fp8", "FP8"),
@@ -113,6 +114,7 @@ MODULES = [
     ("accelerate_tpu.resilience.faults", "Fault injection & recovery primitives"),
     ("accelerate_tpu.commands.chaos_train", "Elastic training chaos bench (chaos-train)"),
     ("accelerate_tpu.models.llama", "Llama family"),
+    ("accelerate_tpu.models.deepseek", "DeepSeek family (latent attention, routed experts)"),
     ("accelerate_tpu.models.lora", "LoRA fine-tuning"),
     ("accelerate_tpu.models.gpt", "GPT family"),
     ("accelerate_tpu.models.t5", "T5 family"),

@@ -1,7 +1,7 @@
-"""Kernel names survive the TPU compiler: ``flash_attention`` forward and backward and
-``paged_attention`` compiled for a DESCRIBED v5e chip (none is attached) at the
-benchmark cells' widths, and the ``tpu_custom_call`` instructions carry the names the
-trace readers look for. These are compiles, not runs: nothing here is a measurement.
+"""Kernel names survive the TPU compiler: ``flash_attention`` forward and backward,
+``paged_attention`` and ``mla_paged_attention`` compiled for a DESCRIBED v5e chip (none is
+attached) at the benchmark cells' widths, and the ``tpu_custom_call`` instructions carry
+the names the trace readers look for. These are compiles, not runs: nothing here is a measurement.
 
 The topology is described inside a module-scoped fixture and only there (never at
 import: every xdist worker imports this file, and only one process may load libtpu).
@@ -15,12 +15,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from accelerate_tpu.ops import flash_attention as flash_mod
+from accelerate_tpu.ops import mla_attention as mla_mod
 from accelerate_tpu.ops import paged_attention as paged_mod
 
 # Mistral-7B: 32 q heads / 8 kv heads x 128; train cell 4 x 8192, window 4096;
 # serve cell 32 lanes, pages of 16, max_len 8192 (512 table entries), 3840 pages.
 B_TRAIN, SEQ, H, K, HD, WINDOW = 4, 8192, 32, 8, 128, 4096
 LANES, PAGE, MAX_LEN, PAGES = 32, 16, 8192, 3840
+# DeepSeek-V3: 128 heads over latent rows of 512 + 64 (planes 640 wide); serve cell 32
+# lanes, pages of 16, max_len 16384 (1024 table entries), 26624 pages.
+MLA_H, MLA_RANK, MLA_ROPE, MLA_WIDTH, MLA_MAX_LEN, MLA_PAGES = 128, 512, 64, 640, 16384, 26624
 CUSTOM_CALL = re.compile(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"")
 
 
@@ -50,7 +54,7 @@ def shape(dims, dtype, sharding):
     return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
 
-KERNEL = re.compile(r"flash_fwd|flash_bwd_dq|flash_bwd_dkv|paged_attention")
+KERNEL = re.compile(r"flash_fwd|flash_bwd_dq|flash_bwd_dkv|mla_paged_attention|paged_attention")
 
 
 def kernels(compiled) -> list:
@@ -91,6 +95,20 @@ def paged_args(s, pool_dtype=jnp.bfloat16):
     return args
 
 
+def mla(q_lat, q_rope, pool, tables, positions, valid):
+    return mla_mod.mla_paged_attention(
+        q_lat, q_rope, pool, tables, positions, valid, page_size=PAGE, sm_scale=0.135,
+        interpret=False)
+
+
+def mla_args(s):
+    return (shape((LANES, MLA_H, MLA_RANK), jnp.bfloat16, s),
+            shape((LANES, MLA_H, MLA_ROPE), jnp.bfloat16, s),
+            shape((MLA_PAGES, PAGE, MLA_WIDTH), jnp.bfloat16, s),
+            shape((LANES, MLA_MAX_LEN // PAGE), jnp.int32, s), shape((LANES,), jnp.int32, s),
+            shape((LANES, MLA_MAX_LEN), jnp.bool_, s))
+
+
 def test_flash_forward_is_named(one_chip):
     compiled = jax.jit(flash).lower(*flash_args(one_chip)).compile()
     assert kernels(compiled) == ["flash_fwd"]
@@ -110,8 +128,16 @@ def test_paged_attention_is_named(one_chip, pool_dtype):
     assert kernels(compiled) == ["paged_attention"]
 
 
+def test_mla_paged_attention_is_named(one_chip):
+    """The latent kernel at the DeepSeek-V3 cell's shapes: Mosaic accepts its blocks and
+    its VMEM (interpret mode proves neither), and the instruction carries its name."""
+    compiled = jax.jit(mla).lower(*mla_args(one_chip)).compile()
+    assert kernels(compiled) == ["mla_paged_attention"]
+
+
 @pytest.mark.parametrize("module,fn,args", [
-    (flash_mod, flash, flash_args), (paged_mod, paged, paged_args)], ids=["flash", "paged"])
+    (flash_mod, flash, flash_args), (paged_mod, paged, paged_args), (mla_mod, mla, mla_args)],
+    ids=["flash", "paged", "mla"])
 def test_a_name_changes_nothing_but_the_name(one_chip, monkeypatch, module, fn, args):
     """The same kernel compiled without ``name=``: same instruction count, same memory."""
     named = jax.jit(fn).lower(*args(one_chip)).compile()
