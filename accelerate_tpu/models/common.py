@@ -94,19 +94,25 @@ def quant_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-def write_kv(kv: dict, name: str, val: jax.Array, index) -> dict:
+def write_kv(kv: dict, name: str, val: jax.Array, index, layer=None) -> dict:
     """Write ``val`` [B,T,...] into cache plane ``name`` at ``index`` (scalar slot for all
     rows, or per-row vector: row b's tokens land at slots ``index[b] .. index[b]+T-1`` —
     the continuous-batching decode (T == 1) and the batched speculative verify (T == k)
     share this path), quantizing when the cache is int8. Per-row writes past the cache
     end are dropped (jax scatter OOB semantics); the serving engine's budget capping
-    guarantees no emitted token ever depends on a dropped slot."""
+    guarantees no emitted token ever depends on a dropped slot.
+
+    ``layer`` (per-row ``index`` only): the planes are STACKED ``[L, B, max_len, ...]``
+    and the write lands in plane ``layer`` of the stack — the form a layer scan that
+    carries the whole cache writes through (:func:`write_kv_paged`)."""
     out = {}
     if f"{name}_scale" in kv:
         q, scale = quant_kv(val)
         planes = ((name, q), (f"{name}_scale", scale))
     else:
         planes = ((name, val.astype(kv[name].dtype)),)
+    if layer is not None and jnp.ndim(index) == 0:
+        raise ValueError("write_kv: a layer of stacked planes is written at per-row slots")
     for key, plane in planes:
         if jnp.ndim(index) == 0:
             out[key] = jax.lax.dynamic_update_slice(
@@ -116,12 +122,13 @@ def write_kv(kv: dict, name: str, val: jax.Array, index) -> dict:
             rows = jnp.arange(plane.shape[0])
             T = plane.shape[1]
             if T == 1:
-                out[key] = kv[key].at[rows, index].set(plane[:, 0].astype(kv[key].dtype))
+                at, new = (rows, index), plane[:, 0]
             else:
                 slots = index[:, None] + jnp.arange(T, dtype=index.dtype)[None, :]
-                out[key] = kv[key].at[rows[:, None], slots].set(
-                    plane.astype(kv[key].dtype)
-                )
+                at, new = (rows[:, None], slots), plane
+            if layer is not None:
+                at = (layer, *at)
+            out[key] = kv[key].at[at].set(new.astype(kv[key].dtype))
     return out
 
 
@@ -152,36 +159,44 @@ def paged_kv_planes(num_pages: int, page_size: int, heads: int, head_dim: int, d
 
 
 def write_kv_paged(kv: dict, name: str, val: jax.Array, pages: jax.Array,
-                   offs: jax.Array) -> dict:
+                   offs: jax.Array, layer=None) -> dict:
     """Write ``val`` [B,T,K,hd] into pool plane ``name`` at physical slots
     ``(pages[b,t], offs[b,t])``, quantizing when the pool is int8 (same per-slot
     quantization as the dense :func:`write_kv`, so paged and dense caches hold
     bit-identical values). Sentinel page ids (== num_pages) are out of bounds and
     the scatter DROPS them — stale/unallocated block-table entries and past-budget
-    draft writes vanish instead of corrupting another lane's pages."""
+    draft writes vanish instead of corrupting another lane's pages.
+
+    ``layer``: the planes are the STACKED pool ``[L, P, page_size, K, hd]`` and the
+    write lands at ``[layer, pages, offs]`` — a scatter into the stack, so a layer scan
+    that CARRIES the stack updates it in place (a scan that takes the stack as ``xs``
+    and returns the written layers as ``ys`` slices, restacks and copies the whole
+    pool every step). A sentinel page id drops the update whatever the layer."""
     out = {}
     if f"{name}_scale" in kv:
         q, scale = quant_kv(val)
         planes = ((name, q), (f"{name}_scale", scale))
     else:
         planes = ((name, val.astype(kv[name].dtype)),)
+    at = (pages, offs) if layer is None else (layer, pages, offs)
     for key, plane in planes:
-        out[key] = kv[key].at[pages, offs].set(plane.astype(kv[key].dtype))
+        out[key] = kv[key].at[at].set(plane.astype(kv[key].dtype))
     return out
 
 
 def read_kv_paged(new_kv: dict, name: str, tables: jax.Array, length: int,
-                  dtype) -> jax.Array:
+                  dtype, layer=None) -> jax.Array:
     """Dense ``[B, length, K, hd]`` compute-dtype view of pool plane ``name``
     gathered through block tables [B, MP] — the jnp gather read the CPU tier-1
     suite exercises (sentinel entries clamp to a real page; the caller's
     valid/causal mask hides those slots). int8 pools dequantize like
-    :func:`read_kv`. ONE implementation shared with the kernel's test oracle
+    :func:`read_kv`. ``layer`` gathers ``pool[layer, ids]`` from the STACKED pool.
+    ONE implementation shared with the kernel's test oracle
     (``ops.paged_attention.gather_pages``) — the CPU gather path and the reference
     the kernel is pinned against can never diverge."""
     from ..ops.paged_attention import gather_pages
 
-    return gather_pages(new_kv, name, tables, length, dtype)
+    return gather_pages(new_kv, name, tables, length, dtype, layer=layer)
 
 
 # ------------------------------------------------------------- latent (MLA) cache planes
@@ -415,7 +430,7 @@ def spec_multi_step_decode(forward_verify: Callable, propose: Callable,
 
 def paged_attention_dispatch(q, pool, tables, positions, valid, *, page_size: int,
                              sm_scale: float, window: int = 0, softcap: float = 0.0,
-                             dtype, dense_attention):
+                             dtype, dense_attention, layer=None):
     """Family-shared paged-attention read: the Pallas kernel on a TPU backend (or when
     forced), else gather-through-the-table into the family's own dense cached-attention
     math — which makes CPU paged decode BITWISE the dense engine (the tier-1 parity
@@ -424,16 +439,18 @@ def paged_attention_dispatch(q, pool, tables, positions, valid, *, page_size: in
     ``ACCEL_PAGED_ATTN`` ∈ {auto, kernel, gather} picks the path (trace-time, like the
     backend probe in :func:`attention_dispatch`); ``dense_attention(ck, cv)`` is the
     family's gather-path closure over its q/positions/valid/cfg. A kernel the compiler
-    refuses is an error at the call — there is no fallback from one path to the other."""
+    refuses is an error at the call — there is no fallback from one path to the other.
+    ``layer``: ``pool`` is the STACKED pool ``[L, P, ...]`` and the read is of plane
+    ``layer`` — neither path slices that plane out of the stack."""
     if paged_read_impl() == "kernel":
         from ..ops.paged_attention import paged_attention
 
         return paged_attention(
             q, pool, tables, positions, valid, page_size=page_size,
-            sm_scale=sm_scale, window=window, softcap=softcap,
+            sm_scale=sm_scale, window=window, softcap=softcap, layer=layer,
         )
-    ck = read_kv_paged(pool, "k", tables, valid.shape[1], dtype)
-    cv = read_kv_paged(pool, "v", tables, valid.shape[1], dtype)
+    ck = read_kv_paged(pool, "k", tables, valid.shape[1], dtype, layer=layer)
+    cv = read_kv_paged(pool, "v", tables, valid.shape[1], dtype, layer=layer)
     return dense_attention(ck, cv)
 
 

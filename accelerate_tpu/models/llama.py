@@ -1279,7 +1279,7 @@ def _attention_cached(q, ck, cv, q_positions, valid, cfg: LlamaConfig):
 
 
 def _block_cached(x, layer, kv, index, positions, valid, cfg: LlamaConfig,
-                  moe_dense: Optional[bool] = None, paged=None):
+                  moe_dense: Optional[bool] = None, paged=None, at_layer=None):
     """One block with KV-cache read/write → (x, new_kv).
 
     ``index`` is the write slot: a SCALAR advances every row together (generate's
@@ -1298,6 +1298,12 @@ def _block_cached(x, layer, kv, index, positions, valid, cfg: LlamaConfig,
     grid, reads go through ``common.paged_attention_dispatch`` (Pallas kernel on TPU,
     gather into THIS function's own ``_attention_cached`` on CPU — bitwise the dense
     path there).
+
+    ``at_layer`` — ``kv`` holds the STACKED planes of every layer (``[L, ...]``, the
+    ``scan_layers`` cache as ``init_cache`` / ``init_paged_cache`` build it) and this
+    block writes and reads plane ``at_layer`` of them, returning the whole stack:
+    :func:`forward_slots`'s layer scan carries the cache and no layer is ever sliced
+    out of it or stacked back (docs/paged_kv.md, "The cache rides the carry").
     """
     B, T, D = x.shape
     if moe_dense is None:
@@ -1314,8 +1320,8 @@ def _block_cached(x, layer, kv, index, positions, valid, cfg: LlamaConfig,
         if paged is not None:
             tables, pages, offs, start_pos, page_size = paged
             with jax.named_scope("kv_write"):
-                new_kv = {**_write_cache_paged(kv, "k", k, pages, offs),
-                          **_write_cache_paged(kv, "v", v, pages, offs)}
+                new_kv = {**_write_cache_paged(kv, "k", k, pages, offs, at_layer),
+                          **_write_cache_paged(kv, "v", v, pages, offs, at_layer)}
             attn = _paged_attention(
                 q, new_kv, tables, start_pos, valid, page_size=page_size,
                 sm_scale=_sm_scale(cfg), window=cfg.sliding_window,
@@ -1323,13 +1329,17 @@ def _block_cached(x, layer, kv, index, positions, valid, cfg: LlamaConfig,
                 dense_attention=lambda ck, cv: _attention_cached(
                     q, ck, cv, positions, valid, cfg
                 ),
+                layer=at_layer,
             )
         else:
             with jax.named_scope("kv_write"):
-                new_kv = {**_write_cache(kv, "k", k, index),
-                          **_write_cache(kv, "v", v, index)}
+                new_kv = {**_write_cache(kv, "k", k, index, at_layer),
+                          **_write_cache(kv, "v", v, index, at_layer)}
+            # The dense read takes its layer's planes out of a carried stack: one layer's
+            # bytes, what the attention reads anyway.
+            own = new_kv if at_layer is None else {n: p[at_layer] for n, p in new_kv.items()}
             attn = _attention_cached(
-                q, _read_cache(new_kv, "k", cfg.dtype), _read_cache(new_kv, "v", cfg.dtype),
+                q, _read_cache(own, "k", cfg.dtype), _read_cache(own, "v", cfg.dtype),
                 positions, valid, cfg,
             )
         attn_out = _proj_l(attn.reshape(B, T, cfg.n_heads * cfg.head_dim), layer, "wo", cfg)
@@ -1509,6 +1519,11 @@ def forward_slots(
     alternating-sliding-window grouping, per-layer banding and MoE routing literally
     cannot drift between them (the dense/paged token-parity contract,
     tests/test_serving_paged.py).
+
+    Under ``cfg.scan_layers`` the stacked cache of all layers is the layer scan's CARRY,
+    written in place a layer at a time (``_block_cached(..., at_layer=l)``), never its
+    ``xs``/``ys`` — so the engine's decode programs, which carry the cache through
+    their own scan over steps and donate it at the jit boundary, move no pool plane.
     """
     from .common import paged_write_coords
 
@@ -1532,45 +1547,34 @@ def forward_slots(
         x = params["embed"][tokens].astype(cfg.dtype)
         if cfg.embed_scale:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.dtype)
-    alternating = bool(cfg.sliding_window) and cfg.window_every > 1
-    if cfg.scan_layers and alternating:
-        # Mirror forward_cached's grouped scan: layer j of each window_every-group is
-        # banded iff j == 0 (without this, decode would band-limit the full-attention
-        # layers and diverge from generate()).
-        per = cfg.window_every
+    if cfg.scan_layers:
+        # ONE scan for the plain and the alternating-window stack: it walks groups of
+        # `per` layers (per == 1 when every layer has the same band), layer j of a group
+        # banded iff j == 0 as in forward_cached (without this, decode would band-limit
+        # the full-attention layers and diverge from generate()). The cache of ALL layers
+        # is the scan's CARRY and each block writes its plane of it in place; as the
+        # scan's xs/ys it would be sliced, restacked and copied whole every step.
+        per = cfg.window_every if cfg.sliding_window else 1
         full_cfg = dataclasses.replace(cfg, sliding_window=0)
-        regroup = lambda a: a.reshape(cfg.n_layers // per, per, *a.shape[1:])  # noqa: E731
-        grouped = jax.tree_util.tree_map(regroup, (params["layers"], cache["layers"]))
+        grouped = jax.tree_util.tree_map(
+            lambda a: a.reshape(cfg.n_layers // per, per, *a.shape[1:]), params["layers"]
+        )
 
         def body(carry, group):
-            layers_g, kv_g = group
-            out = carry
-            new_kvs = []
+            out, kv = carry
+            layers_g, first = group
             for j in range(per):
                 layer_j = jax.tree_util.tree_map(lambda a, j=j: a[j], layers_g)
-                kv_j = jax.tree_util.tree_map(lambda a, j=j: a[j], kv_g)
-                out, new_kv = _block_cached(
-                    out, layer_j, kv_j, positions, pos_grid, valid,
+                # vector index → per-row write slots (_block_cached handles both)
+                out, kv = _block_cached(
+                    out, layer_j, kv, positions, pos_grid, valid,
                     cfg if j == 0 else full_cfg, moe_dense=True, paged=paged,
+                    at_layer=first + j,
                 )
-                new_kvs.append(new_kv)
-            return out, jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *new_kvs)
+            return (out, kv), None
 
-        x, new_grouped = jax.lax.scan(body, x, grouped)
-        new_layers = jax.tree_util.tree_map(
-            lambda a: a.reshape(cfg.n_layers, *a.shape[2:]), new_grouped
-        )
-    elif cfg.scan_layers:
-        def body(carry, layer_and_kv):
-            layer, kv = layer_and_kv
-            # vector index → per-row write slots (_block_cached handles both)
-            out, new_kv = _block_cached(
-                carry, layer, kv, positions, pos_grid, valid, cfg, moe_dense=True,
-                paged=paged,
-            )
-            return out, new_kv
-
-        x, new_layers = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+        firsts = jnp.arange(0, cfg.n_layers, per, dtype=jnp.int32)
+        (x, new_layers), _ = jax.lax.scan(body, (x, cache["layers"]), (grouped, firsts))
     else:
         # Mirror forward_cached's per-layer banded/full alternation (cfg.window_every).
         full_cfg = dataclasses.replace(cfg, sliding_window=0)

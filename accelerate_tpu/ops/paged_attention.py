@@ -48,6 +48,11 @@ the MXU operand; their per-slot scales arrive as lane-dense ``[1, page_size·K]`
 and multiply the score columns (K) and the probability columns (V) — the fp32 cache
 never exists in HBM *or* VMEM.
 
+A decode program that scans its layers carries the pools of ALL layers stacked
+(``[L, num_pages, ...]``) and must not slice one out (that is a copy of the pool a
+layer): ``paged_attention(..., layer=l)`` views the stack as ``L·num_pages`` pages and
+offsets the clamped table by ``l·num_pages`` — same kernel, same walk.
+
 ``paged_attention_reference`` is the same contract in pure jnp (gather through the table,
 mask, softmax) — the kernel's test oracle. The serving engine's CPU path instead gathers
 into the family's ``_attention_cached`` (``models.common.paged_attention_dispatch``) so
@@ -85,20 +90,25 @@ _M_INIT = -1e29
 _BLOCK_BYTES = 1 << 20
 
 
-def gather_pages(pool: dict, name: str, tables: jax.Array, length: int, dtype):
+def gather_pages(pool: dict, name: str, tables: jax.Array, length: int, dtype,
+                 layer=None):
     """Dense ``[B, length, K, hd]`` view of pool plane ``name`` through block tables
     ``[B, MP]`` — sentinel entries clamp to a real page (callers mask those slots).
     int8 planes dequantize against their scale pages (the convert+scale fuses into the
-    consuming einsum, so the fp32 copy never lands in HBM)."""
-    P, ps = pool[name].shape[0], pool[name].shape[1]
+    consuming einsum, so the fp32 copy never lands in HBM). ``layer``: the planes are
+    stacked ``[L, P, ...]`` and the pages come from plane ``layer`` (one gather at
+    ``[layer, ids]``; the plane is never sliced out of the stack)."""
+    P, ps = pool[name].shape[-4:-2]
     ids = jnp.minimum(tables, P - 1)
-    pages = jnp.take(pool[name], ids, axis=0)                  # [B, MP, ps, K, hd]
     B, MP = ids.shape
-    x = pages.reshape(B, MP * ps, *pages.shape[3:])[:, :length]
+
+    def rows(plane):                                           # [B, MP, ps, K, *] pages
+        pages = jnp.take(plane, ids, axis=0) if layer is None else plane[layer, ids]
+        return pages.reshape(B, MP * ps, *pages.shape[3:])[:, :length]
+
+    x = rows(pool[name])
     if f"{name}_scale" in pool:
-        scales = jnp.take(pool[f"{name}_scale"], ids, axis=0)
-        scales = scales.reshape(B, MP * ps, *scales.shape[3:])[:, :length]
-        return x.astype(dtype) * scales.astype(dtype)
+        return x.astype(dtype) * rows(pool[f"{name}_scale"]).astype(dtype)
     return x.astype(dtype)
 
 
@@ -304,11 +314,16 @@ def _kernel(first_ref, count_ref, tab_ref, pos_ref, *refs, page_size, block, tab
 
 
 def paged_attention(q, pool, tables, positions, valid, *, page_size, sm_scale,
-                    window: int = 0, softcap: float = 0.0, interpret=None):
+                    window: int = 0, softcap: float = 0.0, layer=None, interpret=None):
     """Paged-attention decode: q [B,T,H,hd] against pool pages through block tables.
 
     - ``pool``: ``{"k","v": [P, page_size, K, hd]}`` (+ ``k_scale``/``v_scale``
-      [P, page_size, K, 1] fp32 when int8-quantized).
+      [P, page_size, K, 1] fp32 when int8-quantized). With ``layer`` (an int32 scalar,
+      traced or not) the planes are the STACKED pools of all layers ``[L, P, ...]`` and
+      the read is of plane ``layer``: the stack is viewed as ``L·P`` pages and every
+      table entry offset by ``layer·P``, so no plane is sliced out of the stack (a
+      decode program carries the stack through its layer scan and must not copy it)
+      and the kernel itself is the same.
     - ``tables`` [B, MP] int32 physical page per logical page (sentinel == P for
       unallocated entries: the walk stops at a lane's last allocated entry; one inside
       the walked range is clamped for the fetch and masked from the softmax).
@@ -322,7 +337,7 @@ def paged_attention(q, pool, tables, positions, valid, *, page_size, sm_scale,
     scaling the score instead of each cached element). A lane with an empty range — no
     valid slot, no allocated page, or every valid slot behind the window — emits zeros."""
     B, T, H, hd = q.shape
-    P, ps, K = pool["k"].shape[0], pool["k"].shape[1], pool["k"].shape[2]
+    P, ps, K = pool["k"].shape[-4:-1]
     if ps != page_size:
         raise ValueError(f"pool page_size {ps} != page_size argument {page_size}")
     if H % K:
@@ -354,6 +369,8 @@ def paged_attention(q, pool, tables, positions, valid, *, page_size, sm_scale,
     # slot). The mask has one entry per score column: column slot*K + kh of page i carries
     # valid[b, i*ps + slot].
     tables = jnp.pad(jnp.minimum(tables.astype(jnp.int32), P - 1), ((0, 0), (0, n)))
+    if layer is not None:
+        tables = tables + jnp.asarray(layer, jnp.int32) * P
     valid_cols = jnp.repeat(
         jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, (MP + n) * ps - C))), K, axis=-1,
     ).reshape(B, MP + n, W)
@@ -363,17 +380,17 @@ def paged_attention(q, pool, tables, positions, valid, *, page_size, sm_scale,
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((None, R, hd), _lane), hbm]
-    args = [q.reshape(B, R, hd), pool["k"].reshape(P, W, hd)]
+    args = [q.reshape(B, R, hd), pool["k"].reshape(-1, W, hd)]
     scratch = [pltpu.VMEM((2, n, W, hd), pool["k"].dtype),
                pltpu.VMEM((2, n, W, hd), pool["v"].dtype)]
     if quantized:
         in_specs.append(hbm)
-        args.append(pool["k_scale"].reshape(P, 1, W))
+        args.append(pool["k_scale"].reshape(-1, 1, W))
     in_specs.append(hbm)
-    args.append(pool["v"].reshape(P, W, hd))
+    args.append(pool["v"].reshape(-1, W, hd))
     if quantized:
         in_specs.append(hbm)
-        args.append(pool["v_scale"].reshape(P, 1, W))
+        args.append(pool["v_scale"].reshape(-1, 1, W))
         scratch += [pltpu.VMEM((2, n, W), jnp.float32)] * 2
     in_specs.append(pl.BlockSpec((None, MP + n, W), _lane))
     args.append(valid_cols)

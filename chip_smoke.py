@@ -404,6 +404,20 @@ def kernel_flash_packed(cfg, sizes: Sizes) -> None:
         close(g, w, TOL_BF16, f"flash packed rows {name}")
 
 
+def lane_tables(rng, lens, left_pad, P: int, MP: int, ps: int):
+    """Block tables [B, MP] over a shuffled pool of P pages (entry P = unallocated) and
+    the valid mask [B, MP * ps] of lanes that hold ``lens[b]`` slots, the first
+    ``left_pad[b]`` of them invalid pads."""
+    tables = np.full((len(lens), MP), P, np.int32)
+    free = list(rng.permutation(P))
+    valid = np.zeros((len(lens), MP * ps), bool)
+    for b, n in enumerate(lens):
+        for j in range(-(-int(n) // ps)):
+            tables[b, j] = free.pop()
+        valid[b, left_pad[b]:n] = True
+    return tables, valid
+
+
 def kernel_paged(cfg, sizes: Sizes, quantized: bool) -> None:
     """paged attention (T=1 decode and T=3 verify) vs paged_attention_reference."""
     from accelerate_tpu.models.common import paged_kv_planes, write_kv_paged
@@ -415,13 +429,7 @@ def kernel_paged(cfg, sizes: Sizes, quantized: bool) -> None:
     P = B * MP
     rng = np.random.default_rng(SEED + 3)
     lens = np.array([max(8, C // 200), C // 2 + C // 16, C - 1, C // 11])[:B]
-    tables = np.full((B, MP), P, np.int32)      # sentinel = unallocated
-    free = list(rng.permutation(P))
-    valid = np.zeros((B, C), bool)
-    for b, n in enumerate(lens):
-        for j in range(-(-int(n) // ps)):
-            tables[b, j] = free.pop()
-        valid[b, 2:n] = True                    # two left-pad slots stay invalid
+    tables, valid = lane_tables(rng, lens, [2] * B, P, MP, ps)   # two left-pad slots a lane
     pos = np.arange(C)
     pages = np.where(valid, tables[np.arange(B)[:, None], pos // ps], P)
     offs = np.broadcast_to(pos % ps, (B, C))
@@ -447,6 +455,39 @@ def kernel_paged(cfg, sizes: Sizes, quantized: bool) -> None:
         close(got, want, TOL_BF16, f"paged attention {tag} pages, T={T}")
 
 
+def kernel_paged_stacked() -> None:
+    """The layer-indexed read of the decode program, at the serve cell's shapes: the
+    stacked pools of 16 layers (3840 pages of 16, 8 kv heads x 128, bf16: 2 GB a plane)
+    under 32 lanes; ``paged_attention(..., layer=l)`` vs the reference on ``pool[l]``."""
+    from accelerate_tpu.ops.paged_attention import paged_attention, paged_attention_reference
+
+    L, P, ps, K, H, hd, B, C, window = 16, 3840, PAGE_SIZE, 8, 32, 128, 32, 8192, 4096
+    MP = C // ps
+    rng = np.random.default_rng(SEED + 9)
+    lens = np.concatenate([[1, ps, ps + 1, C - 1, 4097], rng.integers(64, 3000, B - 5)])
+    check(sum(-(-int(n) // ps) for n in lens) <= P, "stacked pool: the lanes fit the pool")
+    # lane b is left-padded by b slots
+    tables, valid = lane_tables(rng, lens, [min(b, int(n) - 1) for b, n in enumerate(lens)],
+                                P, MP, ps)
+    plane = jax.jit(lambda key: jnp.stack([
+        jax.random.normal(jax.random.fold_in(key, l), (P, ps, K, hd), jnp.bfloat16)
+        for l in range(L)]))
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(SEED + 10), 3)
+    pool = {"k": plane(kk), "v": plane(kv)}
+    q = jax.random.normal(kq, (B, 1, H, hd), jnp.bfloat16)
+    kw = dict(page_size=ps, sm_scale=1.0 / math.sqrt(hd), window=window)
+    args = (jnp.asarray(tables), jnp.asarray((lens - 1).astype(np.int32)), jnp.asarray(valid))
+    kernel = jax.jit(lambda pool, l: paged_attention(
+        q, pool, *args, layer=l, interpret=False, **kw))
+    reference = jax.jit(lambda pool, l: paged_attention_reference(
+        q, {"k": pool["k"][l], "v": pool["v"][l]}, *args, **kw))
+    for l in (0, 7, L - 1):
+        got = twice(f"paged stacked layer {l}", kernel, pool, jnp.int32(l))
+        with jax.default_matmul_precision("highest"):
+            want = reference(pool, jnp.int32(l))
+        close(got, want, TOL_BF16, f"paged attention on the stacked pool, layer {l}")
+
+
 def kernel_mla() -> None:
     """mla_paged_attention at DeepSeek-V3's published widths (128 heads over latent rows
     of 512 + 64) on 32 lanes of 1 to 13 056 keys, vs mla_paged_attention_reference."""
@@ -462,13 +503,9 @@ def kernel_mla() -> None:
     rng = np.random.default_rng(SEED + 8)
     lens = np.concatenate([[0, 1, ps, 767, 768, 769], rng.integers(4096, 13057, B - 6)])
     P = int(sum(-(-int(n) // ps) for n in lens)) + 1
-    tables = np.full((B, MP), P, np.int32)      # sentinel = unallocated
-    free = list(rng.permutation(P))
-    valid = np.zeros((B, C), bool)
-    for b, n in enumerate(lens):
-        for j in range(-(-int(n) // ps)):
-            tables[b, j] = free.pop()
-        valid[b, min(b, int(n)):n] = True       # lane b is left-padded by b slots
+    # lane b is left-padded by b slots
+    tables, valid = lane_tables(rng, lens, [min(b, int(n)) for b, n in enumerate(lens)],
+                                P, MP, ps)
     kl, kq, kr = jax.random.split(jax.random.PRNGKey(SEED + 8), 3)
     pool = paged_latent_planes(P, ps, R + r, jnp.bfloat16)
     write = jax.jit(write_latent_paged, donate_argnums=0)
@@ -595,6 +632,7 @@ def kernels(cfg, sizes: Sizes, compiles: Compiles, dry: bool) -> None:
     kernel_flash_packed(cfg, sizes)
     kernel_paged(cfg, sizes, quantized=False)
     kernel_paged(cfg, sizes, quantized=True)
+    kernel_paged_stacked()
     kernel_mla()
     kernel_xent(cfg)
     kernel_adamw(cfg)

@@ -1,7 +1,9 @@
 """Kernel names survive the TPU compiler: ``flash_attention`` forward and backward,
 ``paged_attention`` and ``mla_paged_attention`` compiled for a DESCRIBED v5e chip (none is
 attached) at the benchmark cells' widths, and the ``tpu_custom_call`` instructions carry
-the names the trace readers look for. These are compiles, not runs: nothing here is a measurement.
+the names the trace readers look for. So does the serving engine's whole decode program,
+whose compiled form must write the KV pool in place (ISSUE 29). These are compiles, not
+runs: nothing here is a measurement.
 
 The topology is described inside a module-scoped fixture and only there (never at
 import: every xdist worker imports this file, and only one process may load libtpu).
@@ -21,7 +23,7 @@ from accelerate_tpu.ops import paged_attention as paged_mod
 # Mistral-7B: 32 q heads / 8 kv heads x 128; train cell 4 x 8192, window 4096;
 # serve cell 32 lanes, pages of 16, max_len 8192 (512 table entries), 3840 pages.
 B_TRAIN, SEQ, H, K, HD, WINDOW = 4, 8192, 32, 8, 128, 4096
-LANES, PAGE, MAX_LEN, PAGES = 32, 16, 8192, 3840
+LANES, PAGE, MAX_LEN, PAGES, LAYERS = 32, 16, 8192, 3840, 16
 # DeepSeek-V3: 128 heads over latent rows of 512 + 64 (planes 640 wide); serve cell 32
 # lanes, pages of 16, max_len 16384 (1024 table entries), 26624 pages.
 MLA_H, MLA_RANK, MLA_ROPE, MLA_WIDTH, MLA_MAX_LEN, MLA_PAGES = 128, 512, 64, 640, 16384, 26624
@@ -70,13 +72,14 @@ def flash(q, k, v):
     return flash_mod.flash_attention(q, k, v, causal=True, window=WINDOW, interpret=False)
 
 
-def paged(q, pool_k, pool_v, tables, positions, valid, k_scale=None, v_scale=None):
+def paged(q, pool_k, pool_v, tables, positions, valid, k_scale=None, v_scale=None,
+          layer=None):
     pool = {"k": pool_k, "v": pool_v}
     if k_scale is not None:
         pool.update(k_scale=k_scale, v_scale=v_scale)
     return paged_mod.paged_attention(
         q, pool, tables, positions, valid, page_size=PAGE,
-        sm_scale=HD ** -0.5, window=WINDOW, interpret=False)
+        sm_scale=HD ** -0.5, window=WINDOW, layer=layer, interpret=False)
 
 
 def flash_args(s):
@@ -85,14 +88,18 @@ def flash_args(s):
     return q, kv, kv
 
 
-def paged_args(s, pool_dtype=jnp.bfloat16):
-    pool = shape((PAGES, PAGE, K, HD), pool_dtype, s)
+def paged_args(s, pool_dtype=jnp.bfloat16, stack=()):
+    """``stack=(LAYERS,)``: the pools of all layers stacked, as the decode program carries
+    them; the layer to read then rides as the last argument."""
+    pool = shape((*stack, PAGES, PAGE, K, HD), pool_dtype, s)
     args = (shape((LANES, 1, H, HD), jnp.bfloat16, s), pool, pool,
             shape((LANES, MAX_LEN // PAGE), jnp.int32, s), shape((LANES,), jnp.int32, s),
             shape((LANES, MAX_LEN), jnp.bool_, s))
     if pool_dtype == jnp.int8:      # the kv_quant pool: per-slot fp32 scale pages
-        args += (shape((PAGES, PAGE, K, 1), jnp.float32, s),) * 2
-    return args
+        args += (shape((*stack, PAGES, PAGE, K, 1), jnp.float32, s),) * 2
+    else:
+        args += (None, None)
+    return args + ((shape((), jnp.int32, s),) if stack else ())
 
 
 def mla(q_lat, q_rope, pool, tables, positions, valid):
@@ -126,6 +133,61 @@ def test_flash_backward_kernels_are_named(one_chip):
 def test_paged_attention_is_named(one_chip, pool_dtype):
     compiled = jax.jit(paged).lower(*paged_args(one_chip, pool_dtype)).compile()
     assert kernels(compiled) == ["paged_attention"]
+
+
+STACKED = re.compile(rf"= (?:bf16|s8)\[{LAYERS},{PAGES},{PAGE},{K},{HD}\]\S* ([\w\-]+)\(")
+
+
+def pool_shaped(compiled) -> list:
+    """The opcode of every instruction whose result has the shape of the stacked K or V
+    pool (2 GB in bf16), less those that move no byte. (An int8 pool's fp32 scale planes,
+    ``[..., K, 1]``, are 31 MB a stack and ARE laid out anew for the kernel's lane-dense
+    ``[1, page_size * K]`` rows, as a layer's were before: no cell runs them.)"""
+    free = {"parameter", "get-tuple-element", "bitcast"}
+    return sorted(op for op in STACKED.findall(compiled.as_text()) if op not in free)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_attention_reads_its_layer_of_the_stacked_pool(one_chip, pool_dtype):
+    """The layer-indexed read at the serve cell's shapes (16 layers x 3840 pages, 32
+    lanes): Mosaic accepts it, it is still ONE kernel named ``paged_attention``, and no
+    instruction around it has the stack's shape: the layer is never sliced out."""
+    compiled = jax.jit(paged).lower(*paged_args(one_chip, pool_dtype, (LAYERS,))).compile()
+    assert kernels(compiled) == ["paged_attention"]
+    assert pool_shaped(compiled) == []
+
+
+def test_decode_program_writes_the_pool_in_place(one_chip, monkeypatch):
+    """``serving._decode_multi_step_paged`` at the Mistral serve cell's shapes (depth 16,
+    4 steps a dispatch): in the compiled program the only instructions of the pool's
+    shape are the two in-place scatters of a layer's new K and V (the scatter and the
+    fusion that holds it) — no ``copy``, ``dynamic-slice`` or ``dynamic-update-slice``
+    of a 2 GB plane, which the layer scan's xs/ys form cost six times a decode step —
+    and its temporaries are a fraction of one plane (5.34 GB before, 0.81 GB now)."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.models import llama
+
+    monkeypatch.setenv("ACCEL_PAGED_ATTN", "kernel")       # no chip here to choose it
+    monkeypatch.setattr(paged_mod, "_interpret_default", lambda: False)
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, d_model=4096, n_layers=LAYERS, n_heads=H, n_kv_heads=K,
+        head_dim_override=HD, d_ff=14336, max_seq=32768, sliding_window=WINDOW,
+        tie_embeddings=False, scan_layers=True)
+    on_chip = lambda tree, dtype=None: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: shape(x.shape, dtype or x.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(lambda: llama.init_params(cfg)), jnp.bfloat16)
+    cache = on_chip(jax.eval_shape(
+        lambda: llama.init_paged_cache(cfg, LANES, MAX_LEN, PAGES, PAGE)))
+    lanes = lambda dtype, *more: shape((LANES, *more), dtype, one_chip)  # noqa: E731
+    compiled = serving._decode_multi_step_paged.lower(
+        params, cache, lanes(jnp.int32, MAX_LEN // PAGE), lanes(jnp.int32),
+        lanes(jnp.int32), lanes(jnp.bool_), lanes(jnp.int32), lanes(jnp.int32),
+        lanes(jnp.uint32, 4, 2), lanes(jnp.float32), lanes(jnp.float32), lanes(jnp.int32),
+        cfg=cfg, n_steps=4, sample=False, page_size=PAGE).compile()
+    assert kernels(compiled) == ["paged_attention"]
+    assert pool_shaped(compiled) == ["fusion", "fusion", "scatter", "scatter"]
+    plane = LAYERS * PAGES * PAGE * K * HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < plane // 2
 
 
 def test_mla_paged_attention_is_named(one_chip):

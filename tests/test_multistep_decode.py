@@ -356,3 +356,29 @@ def test_sampling_core_dyn_k_matches_static():
                 logits, key, jnp.float32(0.8), jnp.float32(0.9),
                 jnp.int32(k))
             assert np.array_equal(np.asarray(want), np.asarray(got)), k
+
+
+@pytest.mark.parametrize("variant", ["plain", "window_every2", "kv_quant"])
+def test_scan_layers_paged_multistep_parity(variant):
+    """``decode_steps=4`` = ``decode_steps=1`` token for token under ``scan_layers`` on
+    the paged layout, where the stacked pool is the carry of the layer scan INSIDE the
+    carry of the scan over steps — plain, with grouped (alternately banded) layers, and
+    with int8 planes. More requests than lanes, budgets that are no multiple of 4."""
+    over = {"plain": {}, "window_every2": dict(sliding_window=6, window_every=2),
+            "kv_quant": dict(kv_quant=True)}[variant]
+    cfg = dataclasses.replace(CFG, n_layers=4, scan_layers=True, **over)
+    params = llama.init_params(cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, CFG.vocab_size, int(n)).astype(np.int32)
+               for n in (5, 9, 3, 12, 6)]
+    budgets = [6, 11, 8, 3, 5]
+
+    def run(decode_steps):
+        eng = ContinuousBatcher(params, cfg, max_slots=3, max_len=64, prompt_bucket=16,
+                                page_size=8, decode_steps=decode_steps)
+        reqs = [eng.submit(p, max_new_tokens=b) for p, b in zip(prompts, budgets)]
+        eng.run()
+        assert eng.block_mgr.stats()["pages_in_use"] == 0
+        return [r.tokens for r in reqs]
+
+    assert run(4) == run(1)

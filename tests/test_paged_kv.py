@@ -145,12 +145,59 @@ def test_paged_write_sentinel_drops():
     assert float(jnp.abs(out["k"]).sum()) == 0.0
 
 
+def _stacked_planes(rng, one: dict, n_layers: int) -> dict:
+    """``n_layers`` planes shaped like ``one``'s, stacked, each with bytes of its own."""
+    def fill(x):
+        vals = rng.integers(-100, 100, (n_layers, *x.shape))
+        return jnp.asarray(vals.astype(np.float32)).astype(x.dtype)
+
+    return {k: fill(v) for k, v in one.items()}
+
+
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_stacked_write_lands_in_its_layer_and_a_dropped_one_nowhere(layout, quantized, layer):
+    """A layer scan that carries the cache writes plane ``layer`` of the STACKED planes
+    (``write_kv_paged`` / ``write_kv`` with ``layer``): the write changes that plane as
+    the per-layer write would and no byte of another; through a sentinel page id (dense:
+    a slot past the row's end) it changes no byte of ANY layer."""
+    from accelerate_tpu.models.common import (
+        kv_planes, paged_kv_planes, write_kv, write_kv_paged,
+    )
+
+    rng = np.random.default_rng(layer)
+    L, B, K, hd, ps, P = 3, 2, 2, 8, 4, 6
+    if layout == "paged":
+        stack = _stacked_planes(rng, paged_kv_planes(P, ps, K, hd, jnp.bfloat16, quantized), L)
+        write = lambda kv, where, at: write_kv_paged(  # noqa: E731
+            kv, "k", val, jnp.asarray(where, jnp.int32)[:, None],
+            jnp.asarray([1, 3], jnp.int32)[:, None], at)
+        live, dropped = [4, 2], [P, P]
+    else:
+        stack = _stacked_planes(rng, kv_planes(B, P * ps, K, hd, jnp.bfloat16, quantized), L)
+        write = lambda kv, where, at: write_kv(  # noqa: E731
+            kv, "k", val, jnp.asarray(where, jnp.int32), at)
+        live, dropped = [5, 17], [P * ps, P * ps]
+    val = jnp.asarray(rng.standard_normal((B, 1, K, hd)), jnp.bfloat16)
+    one = {k: v[layer] for k, v in stack.items()}
+    want = jax.jit(lambda kv: write(kv, live, None))(one)
+    got = jax.jit(lambda kv, at: write(kv, live, at))(stack, jnp.int32(layer))
+    none = jax.jit(lambda kv, at: write(kv, dropped, at))(stack, jnp.int32(layer))
+    for key in want:
+        assert not np.array_equal(np.asarray(want[key]), np.asarray(one[key])), key
+        for l in range(L):
+            np.testing.assert_array_equal(
+                np.asarray(got[key][l]), np.asarray(want[key] if l == layer else stack[key][l]))
+            np.testing.assert_array_equal(np.asarray(none[key][l]), np.asarray(stack[key][l]))
+
+
 # ------------------------------------------------------------------ Pallas kernel
-def _build_pool(rng, B, K, hd, ps, P, MP, lens, quantized):
+def _build_pool(rng, B, K, hd, ps, P, MP, lens, quantized, dtype=jnp.float32):
     from accelerate_tpu.models.common import paged_kv_planes, write_kv_paged
 
     C = MP * ps
-    pool = paged_kv_planes(P, ps, K, hd, jnp.float32, quantized)
+    pool = paged_kv_planes(P, ps, K, hd, dtype, quantized)
     tables = np.full((B, MP), P, np.int32)
     free = list(range(P))
     valid = np.zeros((B, C), bool)
@@ -190,6 +237,55 @@ def test_kernel_matches_reference(T, quantized):
     ref = paged_attention_reference(q, pool, tables, positions, valid, **kw)
     out = paged_attention(q, pool, tables, positions, valid, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
+
+
+@pytest.mark.parametrize("T", [1, 3])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_kernel_reads_its_layer_of_a_stacked_pool(T, quantized):
+    """``paged_attention(..., layer=l)`` on the stacked pools of all layers is the
+    kernel on ``pool[l]`` bit for bit, and the reference on ``pool[l]``, for every l —
+    with a sentinel entry inside a lane's walked range, which is clamped into layer l's
+    OWN pages (against P, not L·P). Every other layer is poisoned with NaN (an int8
+    pool: NaN scales), so one page fetched from a wrong layer shows in the output."""
+    from accelerate_tpu.ops.paged_attention import (
+        paged_attention, paged_attention_reference,
+    )
+
+    rng = np.random.default_rng(11)
+    L, B, H, K, hd, ps, P, MP = 3, 3, 4, 2, 16, 8, 10, 3
+    lens = np.array([5, 20, 11])
+    pools = [_build_pool(rng, B, K, hd, ps, P, MP, lens, quantized, jnp.bfloat16)
+             for _ in range(L)]
+    tables, valid = np.array(pools[0][1]), np.array(pools[0][2])
+    # Lane 1's middle page was never allocated (a prefix-layout hole): its entry is the
+    # sentinel, its slots are not valid, and the walk from page 0 to page 2 crosses it.
+    tables[1, 1] = P
+    valid[1, ps:2 * ps] = False
+    q = jnp.asarray(rng.standard_normal((B, T, H, hd)), jnp.bfloat16)
+    positions = jnp.asarray((lens - T).astype(np.int32))
+    args = (jnp.asarray(tables), positions, jnp.asarray(valid))
+    kw = dict(page_size=ps, sm_scale=hd ** -0.5)
+    stacked_kernel = jax.jit(lambda pool, l: paged_attention(q, pool, *args, layer=l, **kw))
+    poisoned = "k_scale" if quantized else "k", "v_scale" if quantized else "v"
+    for l in range(L):
+        own = pools[l][0]
+        stack = {
+            key: jnp.stack([
+                pools[m][0][key] if m == l or key not in poisoned
+                else jnp.full_like(pools[m][0][key], jnp.nan) for m in range(L)])
+            for key in own
+        }
+        out = np.asarray(stacked_kernel(stack, jnp.int32(l)).astype(jnp.float32))
+        alone = np.asarray(paged_attention(q, own, *args, **kw).astype(jnp.float32))
+        ref = np.asarray(paged_attention_reference(q, own, *args, **kw).astype(jnp.float32))
+        np.testing.assert_array_equal(out, alone)
+        np.testing.assert_allclose(out, ref, atol=3e-2)
+        # The gather path reads the same layer without slicing it out: bitwise pool[l].
+        from accelerate_tpu.ops.paged_attention import gather_pages
+
+        np.testing.assert_array_equal(
+            np.asarray(gather_pages(stack, "k", args[0], MP * ps, jnp.float32, layer=l)),
+            np.asarray(gather_pages(own, "k", args[0], MP * ps, jnp.float32)))
 
 
 @pytest.mark.parametrize("window,softcap", [(7, 0.0), (0, 30.0), (5, 20.0)])

@@ -462,3 +462,92 @@ def test_dispatch_span_counts_the_pages_the_kernel_walks(setup, prefix_cache, tm
     assert seen and all(0 < s["pages_live"] <= s["pages_walked"] for s in seen)
     spans = [s for s in program_spans.load(str(tmp_path)) if s.name == "engine.decode.dispatch"]
     assert [{k: int(s.attrs[k]) for k in ("pages_live", "pages_walked")} for s in spans] == seen
+
+
+# The cache rides the layer scan's carry (ISSUE 29). Four layers, so that window_every=2
+# scans two groups; a window shorter than the sequences, so that the banding matters.
+_SCAN_VARIANTS = {
+    "plain": dict(),
+    "window_every2": dict(sliding_window=6, window_every=2),
+    "kv_quant": dict(kv_quant=True),
+    "window_every2_kv_quant": dict(sliding_window=6, window_every=2, kv_quant=True),
+}
+
+
+def _scan_cfg(variant):
+    return dataclasses.replace(CFG, n_layers=4, scan_layers=True, **_SCAN_VARIANTS[variant])
+
+
+@pytest.mark.parametrize("spec_k", [0, 2], ids=["decode", "spec_verify"])
+@pytest.mark.parametrize("variant", ["window_every2", "kv_quant", "window_every2_kv_quant"])
+def test_scan_layers_variants_paged_parity(variant, spec_k):
+    """Paged = dense token for token under ``scan_layers`` where the carried cache has
+    more than the plain bf16 planes: grouped layers (every second one banded), int8
+    planes with their scale planes, and both — for the T == 1 decode and for the
+    T == spec_k + 1 verify, which writes several slots a lane into its layer's plane."""
+    cfg = _scan_cfg(variant)
+    params = llama.init_params(cfg)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, CFG.vocab_size, int(n)).astype(np.int32) for n in (5, 9, 12)]
+
+    def run(page_size):
+        eng = ContinuousBatcher(params, cfg, max_slots=2, max_len=64,
+                                prompt_bucket=16, page_size=page_size, spec_k=spec_k)
+        reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        eng.run()
+        return [r.tokens for r in reqs]
+
+    assert run(0) == run(8)
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, those nested in other equations included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("variant", list(_SCAN_VARIANTS))
+@pytest.mark.parametrize("program", ["forward_slots", "forward_slots_multi"])
+def test_no_scan_takes_the_cache_as_xs_or_returns_it_as_ys(program, variant, layout):
+    """The stacked cache is a CARRY of every scan it passes through, never a scan's
+    ``xs`` or ``ys``: a scan slices its xs a layer at a time and stacks its ys into a
+    new buffer, which on the chip was six passes over the whole pool every decode step
+    (147 ms of a 195 ms dispatch). Planes are told by size and dtype, so the grouped
+    ``[L/2, 2, ...]`` view of them would be caught too."""
+    cfg = _scan_cfg(variant)
+    B, max_len, ps = 2, 40, 8
+    params = jax.eval_shape(lambda: llama.init_params(cfg))
+    if layout == "paged":
+        cache = jax.eval_shape(lambda: llama.init_paged_cache(cfg, B, max_len, 10, ps))
+        paged = dict(tables=jnp.zeros((B, max_len // ps), jnp.int32), page_size=ps)
+    else:
+        cache = jax.eval_shape(lambda: llama.init_cache(cfg, B, max_len))
+        paged = {}
+    leaves = jax.tree_util.tree_leaves(cache["layers"])
+    planes = {(int(np.prod(x.shape)), x.dtype) for x in leaves}
+    assert not planes & {(int(np.prod(x.shape)), x.dtype)
+                         for x in jax.tree_util.tree_leaves(params)}
+    tok, pos = jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32)
+    if program == "forward_slots":
+        fn = lambda p, c: llama.forward_slots(p, tok[:, None], c, pos, cfg, **paged)  # noqa: E731
+    else:
+        fn = lambda p, c: llama.forward_slots_multi(  # noqa: E731
+            p, c, tok, pos, jnp.ones((B,), bool), jnp.full((B,), 9, jnp.int32),
+            jnp.full((B,), -1, jnp.int32),
+            lambda logits, _: jnp.argmax(logits, axis=-1).astype(jnp.int32), None, 4, cfg,
+            **paged)
+    is_plane = lambda v: (int(np.prod(v.aval.shape)), v.aval.dtype) in planes  # noqa: E731
+    carried = 0
+    for eqn in _scans(jax.make_jaxpr(fn)(params, cache).jaxpr):
+        consts, carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        assert not any(map(is_plane, eqn.invars[consts + carry:])), "the cache is a scan's xs"
+        assert not any(map(is_plane, eqn.outvars[carry:])), "the cache is a scan's ys"
+        carried += all(
+            sum(map(is_plane, vs)) == len(leaves)
+            for vs in (eqn.invars[consts:consts + carry], eqn.outvars[:carry]))
+    # the layer scan, and around it the scan over decode steps
+    assert carried == (1 if program == "forward_slots" else 2)
