@@ -3,7 +3,7 @@
 graftlint (the AST tier) sees Python source; this tier sees the *traced
 program* — the jaxpr and StableHLO that XLA actually receives. A
 :class:`ProgramCapture` is one warmed call signature of one program label
-(``train_step.fused``, ``serving.decode`` …) with everything a rule needs:
+(``train_step.fused``, ``serving.decode_multi`` …) with everything a rule needs:
 
 - the ``jax.stages.Lowered`` object and its StableHLO text,
 - the closed jaxpr (via ``jitted.trace``; ``None`` on jax builds without it),

@@ -8,7 +8,7 @@ entry that stops matching anything is reported stale (the ratchet direction —
 suppressions only shrink).
 
 Match semantics: ``program`` is an ``fnmatch`` glob over the program label
-(``train_step.*``, ``serving.decode``); ``match`` is a substring of the
+(``train_step.*``, ``serving.decode_multi``); ``match`` is a substring of the
 finding's stable ``code`` string ("" matches any finding of that rule in that
 program).
 """

@@ -1702,8 +1702,8 @@ class _EngineMeter:
 
         wrap("_admit", "admit")
         if getattr(engine, "role", "mixed") != "prefill":
-            wrap("_plain_step", "decode")
-            wrap("_spec_step", "decode")
+            for loop in ("_multi_step", "_spec_multi", "_spec_step"):
+                wrap(loop, "decode")
         if hasattr(engine, "adopt_handoff"):
             wrap("adopt_handoff", "admit")
 

@@ -226,9 +226,9 @@ def run_warmup(
         # NO prefill programs at all (handoff import + COW copy + lane-valid
         # setup instead), a prefill-role one swaps decode/verify for the page
         # export gather — the manifest records which slice it is warm FOR.
-        # ``decode_steps > 1`` adds the multi-step super-step pair (both sample
-        # variants, dense or paged per the layout above) to the warmed surface;
-        # combined with ``spec_k > 0`` and a resident drafter it ALSO warms the
+        # ``decode_steps`` is the depth of the decode scan, warmed in both sample
+        # variants (dense or paged per the layout above); above 1 and combined
+        # with ``spec_k > 0`` and a resident drafter it ALSO warms the
         # fused speculative super-step pair (``serving.spec_multi[_paged]``) —
         # the manifest's ``spec_fused`` records which geometry that is.
         engine = ContinuousBatcher(

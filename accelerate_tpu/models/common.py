@@ -286,12 +286,12 @@ def multi_step_decode(forward_one: Callable, cache, tokens: jax.Array,
     position is clamped to ``max_len`` so the dense scatter and the paged
     sentinel route both DROP the write (see :func:`write_kv` /
     :func:`paged_write_coords`) — which is also why the final emitted token of a
-    finishing lane is never written, bitwise matching the N=1 loop where the
-    engine frees the lane before the next dispatch.
+    finishing lane is never written: the engine frees the lane before the next
+    dispatch, at every N.
 
     ``active`` bool[B] marks live lanes (idle lanes start frozen and never write
-    — their host-side position stays put, unlike the N=1 path's harmless
-    garbage write; both states are fully re-initialized at admit). ``budgets``
+    — their host-side position stays put; a lane is fully re-initialized at
+    admit). ``budgets``
     int32[B] is each lane's REMAINING token budget (emission stops at exactly
     ``budgets`` tokens — the drain clamps again host-side, belt and braces).
     ``eos_ids`` int32[B] uses −1 for "no EOS".

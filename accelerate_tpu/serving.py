@@ -13,7 +13,9 @@ Design (static shapes throughout):
 - Prefill runs the existing single-row compiled path (``llama.forward_cached`` with the
   prompt left-padded to a bucketed width — one executable per bucket) and the resulting
   cache ROW is scattered into the engine cache at the freed slot (one compiled insert).
-- Decode is ``_decode_step`` (one token per slot per call) or — with ``spec_k > 0`` —
+- Decode is ONE loop, the scan: ``_decode_multi_step`` runs ``decode_steps = N >= 1``
+  steps as one dispatched ``lax.scan`` (sampling, EOS and budget masking on the device;
+  one token per slot per call at the default N = 1) — or, with ``spec_k > 0``,
   the batched SPECULATIVE step: a ``spec_decode.DraftSource`` proposes k tokens per
   active slot, ONE fused target forward over ``[B, k+1]`` (``_spec_verify_step``, the
   per-slot ``llama.forward_slots``) verifies them, and each slot accepts a
@@ -114,10 +116,12 @@ def _model(cfg):
 _PATH_CALLS = {
     "prefill": ("init_cache", "forward_cached"),
     "prefix_cache": ("forward_cached_logits",),
+    "decode": ("forward_slots_multi",),
+    # the one-token body of the scan over dense rows
     "dense rows (page_size=0)": ("forward_slots",),
-    "paged": ("init_paged_cache", "forward_slots_paged", "paged_walk_shape"),
-    "decode_steps > 1": ("forward_slots_multi",),
-    "spec_k": ("forward_slots", "forward_slots_spec_multi"),
+    "paged": ("init_paged_cache", "paged_walk_shape"),
+    # the host loop's verify, dense and paged, and the fused rounds
+    "spec_k": ("forward_slots", "forward_slots_paged", "forward_slots_spec_multi"),
 }
 
 
@@ -248,9 +252,9 @@ class Request:
             self._step_keys = None
 
     def _sample(self, logits_row):
-        """Draw this request's next token from an ON-DEVICE logits row (sampled requests;
-        the greedy path uses the fused argmax and never calls this). Only the drawn int
-        crosses to host."""
+        """Draw this request's next token from an ON-DEVICE logits row: the prefill's
+        first token of a sampled request (greedy ones take the fused argmax; every later
+        token is drawn inside the decode program). Only the drawn int crosses to host."""
         if self.gen.temperature <= 0.0:
             return int(np.asarray(jnp.argmax(logits_row)))
         key = self._step_keys[len(self.tokens)]
@@ -260,28 +264,13 @@ class Request:
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
-def _decode_step(params, cache, tokens, positions, cfg):
-    """Advance every slot one token: (greedy_token [B] int32, logits [B, V] fp32, new
-    cache) — the T == 1 instance of ``llama.forward_slots`` (per-slot write positions,
-    per-slot causal/valid masking).
-
-    The greedy argmax stays fused on-device; the logits matrix is only fetched host-side
-    when a sampled (temperature > 0) request is active."""
-    logits, cache = _model(cfg).forward_slots(params, tokens[:, None], cache, positions, cfg)
-    logits = logits[:, -1, :]
-    with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return greedy, logits, cache
-
-
-@partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
 def _spec_verify_step(params, cache, tokens, positions, cfg):
     """Batched speculative VERIFY: score ``tokens`` [B, k+1] (each lane's pending token
     + k draft proposals) in ONE fused target forward → (greedy [B, k+1] int32, logits
     [B, k+1, V] fp32, new cache).
 
     Column j of the output is the target's next-token distribution AFTER input j given
-    that lane's accepted context — exactly what j sequential ``_decode_step`` calls
+    that lane's accepted context — exactly what j sequential one-token decode steps
     would have produced (same rope positions, same masking, dense MoE routing), which
     is what makes prefix acceptance lossless. Rejected proposals leave garbage K/V
     above the lane's rewound position; the causal mask hides it until the next step's
@@ -353,22 +342,6 @@ def _insert_row(cache, row_cache, slot: int, scan_layers: bool):
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size"), donate_argnums=(1,))
-def _decode_step_paged(params, cache, tables, tokens, positions, cfg, page_size: int):
-    """:func:`_decode_step` over the PAGED cache: K/V writes route through each lane's
-    block-table row into shared pool pages, attention reads through the paged dispatch
-    (Pallas kernel on TPU, gather + the same dense math on CPU — bitwise the dense
-    engine there). ``tables`` [B, MP] is uploaded per step (host-side page allocation
-    never rebuilds device state)."""
-    logits, cache = _model(cfg).forward_slots_paged(
-        params, tokens[:, None], cache, tables, positions, cfg, page_size
-    )
-    logits = logits[:, -1, :]
-    with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return greedy, logits, cache
-
-
-@partial(jax.jit, static_argnames=("cfg", "page_size"), donate_argnums=(1,))
 def _spec_verify_step_paged(params, cache, tables, tokens, positions, cfg,
                             page_size: int):
     """:func:`_spec_verify_step` over the paged cache — ONE fused [B, k+1] verify
@@ -384,16 +357,16 @@ def _spec_verify_step_paged(params, cache, tables, tokens, positions, cfg,
 def _multi_select(sample: bool, keys, temps, top_ps, top_ks):
     """(select_token, xs) for the multi-step scan body.
 
-    ``sample=False`` (every live lane greedy) is the fused argmax — the exact op
-    ``_decode_step`` returns. ``sample=True`` folds the host-built per-lane
-    EMISSION-INDEXED key windows in as scan xs (``keys`` [B, N, 2] → [N, B, 2]:
-    step j consumes each lane's key for emission ``len(tokens)+j``, exactly the
-    key :meth:`Request._sample` would hand ``_draw`` at that emission) and draws
-    every sampled lane via the vmapped ``sampling_core_dyn_k`` — the same
-    row[None]-shaped draw ``_draw``/``_replay_draws`` dispatch, so sampled
-    output is bitwise the N=1 path's. Greedy lanes ride along with a safe
-    temperature of 1.0 and their draw DISCARDED in favor of the argmax (a
-    divide-by-zero guard, not a semantic: the where picks the argmax)."""
+    ``sample=False`` (every live lane greedy) is the fused argmax. ``sample=True``
+    folds the host-built per-lane EMISSION-INDEXED key windows in as scan xs
+    (``keys`` [B, N, 2] → [N, B, 2]: step j consumes each lane's key for emission
+    ``len(tokens)+j``, exactly the key :meth:`Request._sample` hands ``_draw`` for
+    the prefill's first token) and draws every sampled lane via the vmapped
+    ``sampling_core_dyn_k`` — the same row[None]-shaped draw ``_draw`` /
+    ``_replay_draws`` dispatch, so sampled output is bitwise ``generate()``'s at
+    every N. Greedy lanes ride along with a safe temperature of 1.0 and their draw
+    DISCARDED in favor of the argmax (a divide-by-zero guard, not a semantic: the
+    where picks the argmax)."""
     if not sample:
         return (lambda logits, _: jnp.argmax(logits, axis=-1).astype(jnp.int32)), None
     safe_temps = jnp.where(temps > 0.0, temps, 1.0)
@@ -714,7 +687,7 @@ class ContinuousBatcher:
         #: call on it, checked by name before any program is built.
         self.model = _model(cfg)
         paths = ["prefill", "paged" if page_size else "dense rows (page_size=0)"]
-        paths += ["decode_steps > 1"] * (decode_steps > 1) + ["spec_k"] * bool(spec_k)
+        paths += ["decode"] * (role != "prefill") + ["spec_k"] * bool(spec_k)
         paths += ["prefix_cache"] * bool(prefix_cache)
         for path in paths:
             for fn in _PATH_CALLS[path]:
@@ -763,11 +736,11 @@ class ContinuousBatcher:
                 "spec_k was given on a prefill-role engine: it never dispatches "
                 "decode, so the verify/draft programs would be dead weight"
             )
-        # Multi-step decode (docs/multistep_decode.md): ``decode_steps=N`` fuses N
-        # decode steps into ONE dispatched lax.scan super-step — sampling,
+        # The decode loop (docs/multistep_decode.md): ``decode_steps=N`` runs N
+        # decode steps as ONE dispatched lax.scan super-step — sampling,
         # EOS/budget masking and lane freezing happen on-device, and the host
-        # drains a [N, B] token buffer once per super-step (bitwise the N=1
-        # output, greedy and sampled, dense and paged). Coexists with spec_k:
+        # drains a [N, B] token buffer once per super-step (the same tokens at
+        # every N >= 1, greedy and sampled, dense and paged). Coexists with spec_k:
         # speculation wins while ``spec_enabled``; the super-step is the decode
         # path speculation degrades INTO when the gateway disables it (safe —
         # both paths consume the same emission-indexed key schedule).
@@ -777,8 +750,8 @@ class ContinuousBatcher:
                 f"decode_steps must be an int, got {type(decode_steps).__name__}")
         if decode_steps < 1:
             raise ValueError(
-                f"decode_steps={decode_steps} must be >= 1 (1 = the classic "
-                "one-token dispatch)")
+                f"decode_steps={decode_steps} must be >= 1 (1 = one token a "
+                "dispatch)")
         if role == "prefill" and decode_steps > 1:
             raise ValueError(
                 "decode_steps>1 was given on a prefill-role engine: it never "
@@ -807,8 +780,8 @@ class ContinuousBatcher:
             )
         # Batched speculative decoding: ``spec_k`` draft proposals per active slot per
         # step, verified by ONE fused [B, spec_k+1] target forward; each slot accepts a
-        # variable-length prefix. 0 (default) = the classic one-token decode step,
-        # byte-identical to the pre-speculative engine. ``drafter`` is a
+        # variable-length prefix. 0 (default) = no speculation: the scan at this
+        # engine's ``decode_steps``. ``drafter`` is a
         # ``spec_decode.DraftSource`` (default: the model-free NgramDrafter).
         # ``spec_accept`` picks the sampled-slot acceptance test: "replay" (bitwise
         # parity with spec_k=0 under a fixed key schedule) or "residual" (vectorized
@@ -843,9 +816,6 @@ class ContinuousBatcher:
             compile_cache is not None and compile_cache.enabled
         ) else None
         cc = self.compile_cache
-        self._decode_fn = as_cached(_decode_step, cc, "serving.decode", ("cfg",))
-        self._spec_verify_fn = as_cached(
-            _spec_verify_step, cc, "serving.spec_verify", ("cfg",))
         self._prefill_fn = as_cached(
             _prefill_jit, cc, "serving.prefill", ("cfg", "max_len"))
         self._prefill_chunk_fn = as_cached(
@@ -857,23 +827,23 @@ class ContinuousBatcher:
             _prefill_chunk_keep_jit, cc, "serving.prefill_chunk_keep", ("cfg",))
         self._insert_row_fn = as_cached(
             _insert_row, cc, "serving.insert_row", ("slot", "scan_layers"))
-        self._decode_paged_fn = as_cached(
-            _decode_step_paged, cc, "serving.decode_paged", ("cfg", "page_size"))
-        self._decode_multi_fn = as_cached(
-            _decode_multi_step, cc, "serving.decode_multi",
-            ("cfg", "n_steps", "sample"))
-        self._decode_multi_paged_fn = as_cached(
-            _decode_multi_step_paged, cc, "serving.decode_multi_paged",
-            ("cfg", "n_steps", "sample", "page_size"))
-        self._spec_verify_paged_fn = as_cached(
-            _spec_verify_step_paged, cc, "serving.spec_verify_paged",
-            ("cfg", "page_size"))
-        self._spec_multi_fn = as_cached(
-            _spec_multi_step, cc, "serving.spec_multi",
-            ("cfg", "n_steps", "spec_k", "max_ngram", "sample"))
-        self._spec_multi_paged_fn = as_cached(
-            _spec_multi_step_paged, cc, "serving.spec_multi_paged",
-            ("cfg", "n_steps", "spec_k", "max_ngram", "sample", "page_size"))
+        #: The decode programs, by name, in THIS engine's KV layout: ``(compile label,
+        #: program)``. Each is a pair of twin jits that differ by the block tables
+        #: (``_tables``) and the static ``page_size`` (``_layout_statics``) alone;
+        #: ``_dispatch`` is their one call site.
+        self._decode_programs = {}
+        self._layout_statics = {"page_size": self.page_size} if self.paged else {}
+        for name, dense, paged, statics in (
+            ("decode_multi", _decode_multi_step, _decode_multi_step_paged,
+             ("cfg", "n_steps", "sample")),
+            ("spec_verify", _spec_verify_step, _spec_verify_step_paged, ("cfg",)),
+            ("spec_multi", _spec_multi_step, _spec_multi_step_paged,
+             ("cfg", "n_steps", "spec_k", "max_ngram", "sample")),
+        ):
+            label, fn = (f"serving.{name}_paged", paged) if self.paged else (
+                f"serving.{name}", dense)
+            self._decode_programs[name] = (label, as_cached(
+                fn, cc, label, statics + ("page_size",) * self.paged))
         self._insert_paged_fn = as_cached(
             _insert_row_paged, cc, "serving.insert_paged",
             ("page_size", "scan_layers"))
@@ -1008,7 +978,7 @@ class ContinuousBatcher:
         #: Speculative decoding master switch: the gateway's degradation rungs
         #: flip it under pressure. Disabling mid-run is always output-safe
         #: (verification guarantees correctness; a stale draft cache only
-        #: lowers acceptance), it just reverts decode to one token per step.
+        #: lowers acceptance), it just reverts decode to the scan.
         self.spec_enabled = True
         #: Set when an injected ``crash`` killed this engine (EngineCrashed
         #: escaped a dispatch): the object must not serve again — the fleet
@@ -1262,13 +1232,11 @@ class ContinuousBatcher:
     def set_spec_enabled(self, enabled: bool) -> None:
         """Toggle speculative decoding at runtime (the gateway degradation
         rung). Always output-safe: speculation never changes emitted tokens,
-        only how many a dispatch produces — disabling reverts to the plain
-        decode path for this engine's ``decode_steps`` (the one-token step, or
-        the fused ``decode_multi`` super-step when ``decode_steps > 1`` — never
-        N=1; both are warmed alongside the verify/fused-spec programs, so the
-        toggle costs no compiles); re-enabling resumes proposals (a
-        ModelDrafter's stale lane cache only lowers acceptance until its lanes
-        cycle)."""
+        only how many a dispatch produces — disabling lands on the scan at this
+        engine's ``decode_steps`` (``serving.decode_multi[_paged]``, warmed
+        alongside the verify/fused-spec programs, so the toggle costs no
+        compiles); re-enabling resumes proposals (a ModelDrafter's stale lane
+        cache only lowers acceptance until its lanes cycle)."""
         if self.spec_k:
             self.spec_enabled = bool(enabled)
 
@@ -1483,11 +1451,11 @@ class ContinuousBatcher:
                 self._suspects = None
 
     def step(self) -> list[Request]:
-        """Admit queued requests, then advance every active slot: one token each
-        (``spec_k == 0``) or a verified 1..spec_k+1-token prefix each (speculative),
-        or up to ``decode_steps`` tokens each in one device-resident super-step
-        (``decode_steps > 1`` — admission, eviction and deadline checks then act
-        at SUPER-STEP boundaries; docs/multistep_decode.md).
+        """Admit queued requests, then advance every active slot: up to
+        ``decode_steps`` tokens each in one device-resident super-step (one token
+        at the default; admission, eviction and deadline checks act at SUPER-STEP
+        boundaries; docs/multistep_decode.md), or — speculative — a verified
+        1..spec_k+1-token prefix each a round.
 
         With recovery armed (``faults``/``step_timeout_s``/``recover=True``) a
         failed dispatch no longer kills the process: the poison request is
@@ -1515,24 +1483,22 @@ class ContinuousBatcher:
                 if finished_at_admit:
                     self._emit_telemetry()  # admissions alone still move the counters
                 return finished_at_admit
-            # Decode-path routing: speculation wins while enabled (it already emits
-            # multiple tokens per dispatch); the multi-step super-step is BOTH the
-            # standalone fused path and what speculation degrades into when the
-            # gateway's pressure rungs flip ``spec_enabled`` off — safe mid-request,
-            # because every path consumes the same emission-indexed key schedule.
+            # Decode-path routing, three targets: speculation wins while enabled (it
+            # already emits multiple tokens per dispatch) — fused into one scan of N
+            # draft→verify→accept rounds where it can be (``_spec_fused``; docs/
+            # speculative_serving.md), else one round a dispatch in the host loop;
+            # the scan at this engine's N is BOTH the standalone path and what
+            # speculation degrades into when the gateway's pressure rungs flip
+            # ``spec_enabled`` off — safe mid-request, because every path consumes
+            # the same emission-indexed key schedule.
             use_spec = self.spec_k and self.spec_enabled
-            n_steps = 1
+            n_steps = self.multi_step
             if use_spec and self._spec_fused():
-                # Fused speculative super-step: N draft→verify→accept rounds in ONE
-                # dispatch (docs/speculative_serving.md). Flipping spec off lands on
-                # the plain decode_multi super-step below, never on N=1.
-                decode, n_steps = self._spec_multi, self.multi_step
+                decode = self._spec_multi
             elif use_spec:
-                decode = self._spec_step
-            elif self.multi_step > 1:
-                decode, n_steps = self._multi_step, self.multi_step
+                decode, n_steps = self._spec_step, 1
             else:
-                decode = self._plain_step
+                decode = self._multi_step
             # The decode path chosen, all of it: one phase, and the tracer-clock t0
             # every path's per-lane "decode" span records share.
             ph = EnginePhase(self.tracer, "engine.decode", lanes=len(active),
@@ -1740,63 +1706,6 @@ class ContinuousBatcher:
                 on_token(int(tok))
         return req
 
-    def _plain_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
-        """Classic decode: ONE compiled dispatch advances every lane one token."""
-        tracing = ph.tracer is not None
-        traced = [self.slot_req[i] for i in active] if tracing else ()
-        t_guard = self._pre_dispatch("serving.decode", active)
-        if self.paged:
-            with compile_label("serving.decode_paged"), phase("engine.decode.dispatch"):
-                greedy, logits, self.cache = self._decode_paged_fn(
-                    self.params, self.cache, jnp.asarray(self.block_mgr.tables),
-                    jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                    cfg=self.cfg, page_size=self.page_size,
-                )
-        else:
-            with compile_label("serving.decode"), phase("engine.decode.dispatch"):
-                greedy, logits, self.cache = self._decode_fn(
-                    self.params, self.cache, jnp.asarray(self.tokens),
-                    jnp.asarray(self.positions), cfg=self.cfg,
-                )
-        with phase("engine.decode.fetch"):
-            greedy_host = np.asarray(greedy)
-        self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
-        finished = []
-        # Every lane wrote one slot (idle lanes too — static shapes); clamp so an idle
-        # lane's position can never run past the cache (its writes then drop out of bounds
-        # and its lane is fully re-initialized at the next admit anyway).
-        self.positions = np.minimum(self.positions + 1, self.max_len - 1)
-        for i in active:
-            req = self.slot_req[i]
-            tok = (
-                int(greedy_host[i]) if req.gen.temperature <= 0.0
-                # sampled lane: the device row goes straight into the jitted draw;
-                # only the drawn token id crosses to host
-                else req._sample(logits[i])
-            )
-            self.tokens[i] = tok
-            req.tokens.append(tok)
-            if req.on_token is not None:
-                req.on_token(tok)
-            hit_eos = req.gen.eos_token_id is not None and tok == req.gen.eos_token_id
-            if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
-                req.done = True
-                finished.append(req)
-                self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
-                self._release_lane(i)
-        self.decode_steps += 1
-        self.decode_tokens += len(active)
-        if tracing:
-            # One span per traced lane, all sharing this dispatch's [t0, t1] and
-            # step index — the index joins these spans to the serving/kv records
-            # the same step emits. ``host_s`` is the measured inter-dispatch gap
-            # (previous dispatch's end → this one's start): the host dead time
-            # trace-report's host-time column aggregates and multi-step decode
-            # exists to amortize.
-            self._decode_spans(ph, ((req, {}) for req in traced),
-                               occupancy=len(active), tokens=1)
-        return finished
-
     def _decode_spans(self, ph: EnginePhase, lanes, **shared) -> None:
         """The Tracer's "decode" span records of one dispatch: one per traced
         lane ``(request, its own attributes)``, all sharing ``ph``'s [t0, now],
@@ -1836,131 +1745,200 @@ class ContinuousBatcher:
             T=1, window=window, page_size=self.page_size, block=block)
         return {"pages_live": int(pages.sum()), "pages_walked": int(blocks.sum()) * block}
 
+    # ---------------------------------------------------------------- decode loops
+    # step() chooses among three: ``_multi_step`` (the scan, every cell's path),
+    # ``_spec_multi`` (speculation fused into a scan) and ``_spec_step`` (speculation,
+    # one round a dispatch). Each keeps what is its own — how it turns the program's
+    # buffers into per-lane emissions; the lane arguments, the dense-or-paged
+    # dispatch, the landing and the speculative record are built once, below.
+    def _lane_args(self, active: list[int], key_window: int):
+        """The per-lane arguments of a scan dispatch → ``(sampled, args)``.
+
+        ``args`` are on the device and in the programs' order after the cache (and,
+        paged, the block tables): each lane's pending token and write position, whether
+        it is live, its remaining budget, its EOS id (−1: none), its next ``key_window``
+        emission keys, its temperature, top-p and top-k. Scan step j of a sampled lane
+        consumes the key of emission ``len(tokens)+j`` — the key ``generate()`` would
+        draw that token with (the window is clamped at the final key: draws past the
+        budget are frozen). ``sampled`` (any live lane samples) is the programs' static
+        ``sample``; without it the keys are zeros nobody reads.
+
+        With no lane active this is what :meth:`warm_programs` lowers each scan from:
+        the shapes and dtypes of a dispatch are stated here and nowhere else."""
+        B = self.max_slots
+        active_mask = np.zeros((B,), bool)
+        budgets = np.ones((B,), np.int32)   # idle lanes: frozen at step 0, never read
+        eos_ids = np.full((B,), -1, np.int32)
+        temps = np.zeros((B,), np.float32)
+        top_ps = np.ones((B,), np.float32)
+        top_ks = np.zeros((B,), np.int32)
+        sampled = False
+        key_rows: list = [None] * B
+        for i in active:
+            req = self.slot_req[i]
+            active_mask[i] = True
+            budgets[i] = req.gen.max_new_tokens - len(req.tokens)
+            if req.gen.eos_token_id is not None:
+                eos_ids[i] = req.gen.eos_token_id
+            if req.gen.temperature > 0.0:
+                sampled = True
+                temps[i] = req.gen.temperature
+                top_ps[i] = req.gen.top_p
+                top_ks[i] = req.gen.top_k
+                key_rows[i] = self._step_keys_window(req, len(req.tokens), key_window)
+        if sampled:
+            filler = jnp.zeros_like(
+                next(k for k in key_rows if k is not None)
+            )  # greedy/idle lanes: key bits are never consumed (temp 0 → argmax)
+            keys = jnp.stack([k if k is not None else filler for k in key_rows])
+        else:
+            keys = jnp.zeros((B, key_window, 2), jnp.uint32)
+        return sampled, tuple(jnp.asarray(a) for a in (
+            self.tokens, self.positions, active_mask, budgets, eos_ids, keys,
+            temps, top_ps, top_ks))
+
+    def _tables(self) -> tuple:
+        """What a paged program takes ahead of its lane arguments: the block tables,
+        uploaded once a dispatch (host-side page allocation never rebuilds device
+        state). Nothing over dense rows."""
+        return (jnp.asarray(self.block_mgr.tables),) if self.paged else ()
+
+    def _dispatch(self, name: str, active: list[int], tables: tuple, args: tuple,
+                  walk=None, **statics):
+        """Dispatch decode program ``name`` in this engine's KV layout — the ONE call
+        site of a dense/paged pair — under its compile label, inside the
+        ``engine.decode.dispatch`` phase (``walk``: its attributes) and behind the
+        fault guard. → ``(t_guard, the program's outputs)``; the caller fetches, then
+        checks the watchdog (``_post_dispatch(t_guard)``) before any token lands."""
+        label, fn = self._decode_programs[name]
+        t_guard = self._pre_dispatch("serving.decode", active)
+        with compile_label(label), phase("engine.decode.dispatch", **(walk or {})):
+            return t_guard, fn(self.params, self.cache, *tables, *args, cfg=self.cfg,
+                               **self._layout_statics, **statics)
+
+    def _land(self, active: list[int], emissions, **counted):
+        """Land one dispatch's tokens → ``(finished requests, [(lane, its request,
+        tokens it landed)] over the active lanes)``.
+
+        ``emissions`` yields ``(lane, tokens)`` in generation order (step-major or
+        round-major, lane-minor): each token is appended and streamed in that order,
+        clamped to the lane's remaining budget (belt and braces over the programs' own
+        masks: a gateway deadline acts only between dispatches, so nothing past the
+        budget may surface). Then, per lane: the last token becomes the pending one
+        (emitted, not yet written), the position advances by what landed, and EOS or an
+        exhausted budget finishes the request and frees its lane and pages (a dense row
+        is overwritten at the next admit). ``counted`` joins ``tokens`` on the
+        ``engine.decode.drain`` phase."""
+        landed = [0] * self.max_slots
+        with phase("engine.decode.drain") as drain:
+            for i, toks in emissions:
+                req = self.slot_req[i]
+                for tok in toks:
+                    if len(req.tokens) >= req.gen.max_new_tokens:
+                        break
+                    req.tokens.append(tok)
+                    landed[i] += 1
+                    if req.on_token is not None:
+                        req.on_token(tok)
+            finished, lanes = [], []
+            for i in active:
+                req = self.slot_req[i]
+                lanes.append((i, req, landed[i]))
+                if landed[i]:
+                    self.tokens[i] = req.tokens[-1]
+                    self.positions[i] += landed[i]
+                eos = req.gen.eos_token_id
+                if ((eos is not None and req.tokens[-1] == eos)
+                        or len(req.tokens) >= req.gen.max_new_tokens):
+                    req.done = True
+                    finished.append(req)
+                    self.slot_req[i] = None
+                    self._release_lane(i)
+            # A lane at the end of its window stays inside the cache (its writes drop).
+            self.positions = np.minimum(self.positions, self.max_len - 1)
+            step_tokens = sum(landed)
+            drain.set_metadata(tokens=step_tokens, **counted)
+        self.decode_steps += 1
+        self.decode_tokens += step_tokens
+        return finished, lanes
+
+    def _count_spec(self, rounds: int, lanes: int, proposed: int, accepted: int,
+                    tokens: int) -> None:
+        """Add one speculative dispatch to the acceptance counters and emit its
+        ``serving.spec/v1`` record (after :meth:`_land`, so ``step`` is the causality
+        key the dispatch's ``trace.span/v1`` decode spans and ``serving.kv/v1`` record
+        carry)."""
+        self.spec_proposed += proposed
+        self.spec_accepted += accepted
+        tel = self.telemetry
+        if tel is None or not tel.enabled:
+            return
+        from .telemetry import TELEMETRY_REV
+
+        tel.emit({
+            "schema": SERVING_SPEC_SCHEMA,
+            "telemetry_rev": TELEMETRY_REV,
+            "step": self.decode_steps,
+            "spec_k": self.spec_k,
+            "rounds": rounds,  # the host loop is one round per dispatch
+            "active_slots": lanes,
+            "step_proposed": proposed,
+            "step_accepted": accepted,
+            "step_tokens": tokens,
+            "proposed_total": self.spec_proposed,
+            "accepted_total": self.spec_accepted,
+            "spec_accept_rate": (
+                round(self.spec_accepted / self.spec_proposed, 4)
+                if self.spec_proposed else None
+            ),
+            "tokens_per_step": (
+                round(self.decode_tokens / self.decode_steps, 4)
+                if self.decode_steps else None
+            ),
+        })
+
     def _multi_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
-        """Device-resident super-step: ``decode_steps=N`` decode steps in ONE
-        dispatched scan (``serving.decode_multi``/``decode_multi_paged``), then
-        ONE drain of the [N, B] token buffer.
+        """The decode loop: ``decode_steps = N >= 1`` decode steps in ONE dispatched
+        scan (``serving.decode_multi``/``decode_multi_paged``), then ONE drain of
+        the [N, B] token buffer.
 
         The program freezes finishing lanes in-scan (EOS / remaining-budget
         masking — a frozen lane's writes drop out of bounds, so the final
-        emitted token is never written, exactly the N=1 pending-token pattern),
-        which is what makes the emitted streams BITWISE the N=1 engine's:
-        greedy lanes ride the fused argmax, sampled lanes consume their
-        emission-indexed key windows through the same ``sampling_core`` filter
-        ops ``_draw`` dispatches (see ``_multi_select``). The drain is
-        step-major, lane-minor — exact generation order, so ``on_token``
-        streaming transcripts equal the final token lists — and clamps each
-        lane to its remaining budget (belt and braces over the in-scan mask:
-        a gateway deadline can act only at super-step boundaries, so emissions
-        past the budget must never surface). Admission/eviction/deadlines act
-        between super-steps; the fault boundary + watchdog wrap the whole
-        dispatch, so fault attribution and bisection run at super-step
-        granularity (docs/multistep_decode.md)."""
+        emitted token is never written: the pending-token pattern), which is
+        what makes the emitted streams the same at every N: greedy lanes ride
+        the fused argmax, sampled lanes consume their emission-indexed key
+        windows through the ``sampling_core`` filter ops (see ``_multi_select``).
+        The drain is step-major, lane-minor — exact generation order, so
+        ``on_token`` streaming transcripts equal the final token lists.
+        Admission/eviction/deadlines act between super-steps; the fault boundary
+        + watchdog wrap the whole dispatch, so fault attribution and bisection
+        run at super-step granularity (docs/multistep_decode.md)."""
         N = self.multi_step
-        B = self.max_slots
-        tracing = ph.tracer is not None
-        traced = [(i, self.slot_req[i]) for i in active] if tracing else ()
         with phase("engine.decode.prepare"):
-            active_mask = np.zeros((B,), bool)
-            budgets = np.ones((B,), np.int32)   # idle lanes: frozen at step 0, never read
-            eos_ids = np.full((B,), -1, np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_ps = np.ones((B,), np.float32)
-            top_ks = np.zeros((B,), np.int32)
-            sampled = False
-            key_rows: list = [None] * B
-            for i in active:
-                req = self.slot_req[i]
-                active_mask[i] = True
-                budgets[i] = req.gen.max_new_tokens - len(req.tokens)
-                if req.gen.eos_token_id is not None:
-                    eos_ids[i] = req.gen.eos_token_id
-                if req.gen.temperature > 0.0:
-                    sampled = True
-                    temps[i] = req.gen.temperature
-                    top_ps[i] = req.gen.top_p
-                    top_ks[i] = req.gen.top_k
-                    # Scan step j consumes this lane's key for emission
-                    # len(tokens)+j — the exact key Request._sample would hand
-                    # _draw at that emission (window clamped at the final key,
-                    # like the spec verify surplus: past-budget draws are frozen).
-                    key_rows[i] = self._step_keys_window(req, len(req.tokens), N)
-            if sampled:
-                filler = jnp.zeros_like(
-                    next(k for k in key_rows if k is not None)
-                )  # greedy/idle lanes: key bits are never consumed (temp 0 → argmax)
-                keys = jnp.stack([k if k is not None else filler for k in key_rows])
-            else:
-                keys = jnp.zeros((B, N, 2), jnp.uint32)
-            # Host → device, in the programs' argument order after the cache
-            # (and, paged, the block tables uploaded once per super-step).
-            lane_args = tuple(jnp.asarray(a) for a in (
-                self.tokens, self.positions, active_mask, budgets, eos_ids, keys,
-                temps, top_ps, top_ks))
-            tables = jnp.asarray(self.block_mgr.tables) if self.paged else None
-        t_guard = self._pre_dispatch("serving.decode", active)
-        if self.paged:
-            with compile_label("serving.decode_multi_paged"), \
-                    phase("engine.decode.dispatch", **self._paged_walk(active)):
-                tok_buf, counts, self.cache, *model_counts = self._decode_multi_paged_fn(
-                    self.params, self.cache, tables, *lane_args,
-                    cfg=self.cfg, n_steps=N, sample=sampled,
-                    page_size=self.page_size,
-                )
-        else:
-            with compile_label("serving.decode_multi"), \
-                    phase("engine.decode.dispatch"):
-                tok_buf, counts, self.cache, *model_counts = self._decode_multi_fn(
-                    self.params, self.cache, *lane_args,
-                    cfg=self.cfg, n_steps=N, sample=sampled,
-                )
+            sampled, lane_args = self._lane_args(active, N)
+            tables = self._tables()
+        t_guard, (tok_buf, counts, self.cache, *model_counts) = self._dispatch(
+            "decode_multi", active, tables, lane_args,
+            self._paged_walk(active) if self.paged else None,
+            n_steps=N, sample=sampled)
         with phase("engine.decode.fetch"):
-            tok_host = np.asarray(tok_buf)     # [N, B]
-            counts_host = np.asarray(counts)   # [B]
+            tok_host = np.asarray(tok_buf).tolist()     # [N, B]
+            counts_host = np.asarray(counts).tolist()   # [B]
             # what the model counted beside its tokens (its DECODE_COUNTERS), if anything
             model_counts = [np.asarray(c) for c in model_counts]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
-        # Drain in exact generation order (step-major, lane-minor — the order N
-        # sequential _plain_step calls would have appended), clamped to each
-        # lane's remaining budget.
-        with phase("engine.decode.drain") as drain:
-            for j in range(N):
-                for i in active:
-                    req = self.slot_req[i]
-                    if j >= counts_host[i] or len(req.tokens) >= req.gen.max_new_tokens:
-                        continue
-                    tok = int(tok_host[j, i])
-                    req.tokens.append(tok)
-                    if req.on_token is not None:
-                        req.on_token(tok)
-            finished = []
-            step_tokens = 0
-            for i in active:
-                req = self.slot_req[i]
-                c = int(counts_host[i])
-                step_tokens += c
-                self.tokens[i] = int(tok_host[c - 1, i])  # the new pending token
-                self.positions[i] += c
-                eos = req.gen.eos_token_id
-                hit_eos = eos is not None and req.tokens and req.tokens[-1] == eos
-                if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
-                    req.done = True
-                    finished.append(req)
-                    self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
-                    self._release_lane(i)
-            self.positions = np.minimum(self.positions, self.max_len - 1)
-            drain.set_metadata(tokens=step_tokens, **{
-                name: int(v) for c in model_counts
-                for name, v in zip(self.model.DECODE_COUNTERS, c)})
-        self.decode_steps += 1
-        self.decode_tokens += step_tokens
-        if tracing:
+        finished, lanes = self._land(
+            active,
+            ((i, tok_host[j][i:i + 1]) for j in range(N) for i in active
+             if j < counts_host[i]),
+            **{name: int(v) for c in model_counts
+               for name, v in zip(self.model.DECODE_COUNTERS, c)})
+        if ph.tracer is not None:
             # One span per traced lane for the whole super-step: ``tokens`` is
             # that lane's real emission count, ``n_steps`` the fused depth, and
-            # ``host_s`` the measured inter-dispatch gap — N tokens now share
-            # ONE gap, which is the whole point.
+            # ``host_s`` the measured inter-dispatch gap — N tokens share ONE gap.
             self._decode_spans(
-                ph, ((req, {"tokens": int(counts_host[i])}) for i, req in traced),
+                ph, ((req, {"tokens": n}) for _, req, n in lanes),
                 occupancy=len(active), n_steps=N)
         return finished
 
@@ -1971,184 +1949,60 @@ class ContinuousBatcher:
         ZERO host involvement between rounds.
 
         Drafting runs in-scan (the resident n-gram gather over each lane's
-        carried prompt+generated history), the verify is the PR-6 fused
+        carried prompt+generated history), the verify is the fused
         [B, spec_k+1] forward as the scan body, and acceptance advances each
         lane's emission-key CURSOR by its own ``n_emit`` — so sampled lanes
         consume exactly the keys the host loop's ``_replay_round`` would, and
         emitted streams are BITWISE the host-loop spec path's (hence bitwise
         ``spec_k=0``; see docs/speculative_serving.md). The drain is
         round-major, lane-minor — the exact order N sequential ``_spec_step``
-        calls would have appended, so ``on_token`` streaming transcripts equal
-        the final token lists. Admission/eviction/deadlines and the fault
+        calls would have appended. Admission/eviction/deadlines and the fault
         boundary + watchdog act at super-step granularity, exactly as in
         ``_multi_step``."""
         N = self.multi_step
         k = self.spec_k
-        T = k + 1
-        B = self.max_slots
-        tracing = ph.tracer is not None
-        traced = [(i, self.slot_req[i]) for i in active] if tracing else ()
-        active_mask = np.zeros((B,), bool)
-        budgets = np.ones((B,), np.int32)   # idle lanes: frozen at step 0, never read
-        eos_ids = np.full((B,), -1, np.int32)
-        temps = np.zeros((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        top_ks = np.zeros((B,), np.int32)
-        # Drafting history: prompt + generated so far, packed from column 0 —
-        # compact token order, so it works unchanged with prefix-cached and
-        # paged layouts (it is NOT the cache layout, just the token sequence).
-        history = np.zeros((B, self.max_len), np.int32)
-        hist_lens = np.zeros((B,), np.int32)
-        sampled = False
-        key_rows: list = [None] * B
-        for i in active:
-            req = self.slot_req[i]
-            active_mask[i] = True
-            budgets[i] = req.gen.max_new_tokens - len(req.tokens)
-            if req.gen.eos_token_id is not None:
-                eos_ids[i] = req.gen.eos_token_id
-            ctx = np.concatenate(
-                [np.asarray(req.prompt, np.int32),
-                 np.asarray(req.tokens, np.int32)]
-            )[-self.max_len:]
-            history[i, :len(ctx)] = ctx
-            hist_lens[i] = len(ctx)
-            if req.gen.temperature > 0.0:
-                sampled = True
-                temps[i] = req.gen.temperature
-                top_ps[i] = req.gen.top_p
-                top_ks[i] = req.gen.top_k
-                # Per-lane key TABLE: the next N*(k+1) emission keys from this
-                # lane's schedule (the worst case — N full acceptances). The
-                # scan's per-lane cursor (its emission count) indexes into it,
-                # so round r consumes exactly the keys _replay_round would at
-                # the same emission offsets (window clamped at the final key,
-                # like the host loop's).
-                key_rows[i] = self._step_keys_window(req, len(req.tokens), N * T)
-        if sampled:
-            filler = jnp.zeros_like(
-                next(kr for kr in key_rows if kr is not None)
-            )  # greedy/idle lanes: key bits are never consumed (temp 0 → argmax)
-            key_tab = jnp.stack([kr if kr is not None else filler
-                                 for kr in key_rows])
-        else:
-            key_tab = jnp.zeros((B, N * T, 2), jnp.uint32)
-        max_ngram = int(self.drafter.max_ngram)
-        t_guard = self._pre_dispatch("serving.decode", active)
-        if self.paged:
-            with compile_label("serving.spec_multi_paged"), \
-                    phase("engine.decode.dispatch"):
-                tok_buf, emits, counts, proposed, accepted, self.cache = (
-                    self._spec_multi_paged_fn(
-                        self.params, self.cache,
-                        jnp.asarray(self.block_mgr.tables),
-                        jnp.asarray(self.tokens), jnp.asarray(self.positions),
-                        jnp.asarray(active_mask), jnp.asarray(budgets),
-                        jnp.asarray(eos_ids), key_tab, jnp.asarray(temps),
-                        jnp.asarray(top_ps), jnp.asarray(top_ks),
-                        jnp.asarray(history), jnp.asarray(hist_lens),
-                        cfg=self.cfg, n_steps=N, spec_k=k, max_ngram=max_ngram,
-                        sample=sampled, page_size=self.page_size,
-                    )
-                )
-        else:
-            with compile_label("serving.spec_multi"), \
-                    phase("engine.decode.dispatch"):
-                tok_buf, emits, counts, proposed, accepted, self.cache = (
-                    self._spec_multi_fn(
-                        self.params, self.cache, jnp.asarray(self.tokens),
-                        jnp.asarray(self.positions), jnp.asarray(active_mask),
-                        jnp.asarray(budgets), jnp.asarray(eos_ids), key_tab,
-                        jnp.asarray(temps), jnp.asarray(top_ps),
-                        jnp.asarray(top_ks), jnp.asarray(history),
-                        jnp.asarray(hist_lens),
-                        cfg=self.cfg, n_steps=N, spec_k=k, max_ngram=max_ngram,
-                        sample=sampled,
-                    )
-                )
-        with phase("engine.decode.fetch"):
-            ref_host = np.asarray(tok_buf)      # [N, B, k+1]
-            emits_host = np.asarray(emits)      # [N, B]
-            counts_host = np.asarray(counts)    # [B]
-            prop_host = np.asarray(proposed)    # [B]
-            acc_host = np.asarray(accepted)     # [B]
-        self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
-        # Drain in exact generation order (round-major, lane-minor — the order N
-        # sequential _spec_step calls would have appended), clamped to each
-        # lane's remaining budget (belt and braces over the in-scan cap).
-        last_tok = [0] * B
-        for r in range(N):
+        with phase("engine.decode.prepare"):
+            # Per-lane key TABLE: the next N*(k+1) emission keys (the worst case —
+            # N full acceptances); the scan's per-lane cursor (its emission count)
+            # indexes into it.
+            sampled, lane_args = self._lane_args(active, N * (k + 1))
+            # Drafting history: prompt + generated so far, packed from column 0 —
+            # compact token order, so it works unchanged with prefix-cached and
+            # paged layouts (it is NOT the cache layout, just the token sequence).
+            history = np.zeros((self.max_slots, self.max_len), np.int32)
+            hist_lens = np.zeros((self.max_slots,), np.int32)
             for i in active:
                 req = self.slot_req[i]
-                m = int(emits_host[r, i])
-                for j in range(m):
-                    if len(req.tokens) >= req.gen.max_new_tokens:
-                        break
-                    tok = int(ref_host[r, i, j])
-                    last_tok[i] = tok
-                    req.tokens.append(tok)
-                    if req.on_token is not None:
-                        req.on_token(tok)
-        finished = []
-        step_tokens = 0
-        for i in active:
-            req = self.slot_req[i]
-            c = int(counts_host[i])
-            step_tokens += c
-            self.tokens[i] = last_tok[i]  # the new pending token (c >= 1 always)
-            self.positions[i] += c
-            eos = req.gen.eos_token_id
-            hit_eos = eos is not None and req.tokens and req.tokens[-1] == eos
-            if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
-                req.done = True
-                finished.append(req)
-                self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
-                self._release_lane(i)
-        self.positions = np.minimum(self.positions, self.max_len - 1)
-        step_proposed = int(prop_host.sum())
-        step_accepted = int(acc_host.sum())
-        self.decode_steps += 1
-        self.decode_tokens += step_tokens
-        self.spec_proposed += step_proposed
-        self.spec_accepted += step_accepted
-        if tracing:
-            # One span per traced lane for the whole super-step: ``tokens`` is
-            # that lane's real emission count (every emitted token accounted),
-            # ``proposed``/``accepted`` its per-lane round totals, ``n_steps``
-            # the fused depth, ``host_s`` the measured inter-dispatch gap — all
-            # N rounds now share ONE gap, which is the whole point.
+                ctx = np.concatenate(
+                    [np.asarray(req.prompt, np.int32),
+                     np.asarray(req.tokens, np.int32)]
+                )[-self.max_len:]
+                history[i, :len(ctx)] = ctx
+                hist_lens[i] = len(ctx)
+            lane_args += (jnp.asarray(history), jnp.asarray(hist_lens))
+            tables = self._tables()
+        t_guard, (tok_buf, emits, _, proposed, accepted, self.cache) = (
+            self._dispatch("spec_multi", active, tables, lane_args,
+                           n_steps=N, spec_k=k,
+                           max_ngram=int(self.drafter.max_ngram), sample=sampled))
+        with phase("engine.decode.fetch"):
+            ref_host = np.asarray(tok_buf).tolist()    # [N, B, k+1]
+            emits_host = np.asarray(emits).tolist()    # [N, B]
+            prop_host = np.asarray(proposed)           # [B]
+            acc_host = np.asarray(accepted)            # [B]
+        self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
+        finished, lanes = self._land(
+            active, ((i, ref_host[r][i][:emits_host[r][i]])
+                     for r in range(N) for i in active))
+        if ph.tracer is not None:
+            # As in ``_multi_step``, with the lane's ``proposed``/``accepted`` totals
+            # over the N rounds.
             self._decode_spans(
-                ph, ((req, {"tokens": int(counts_host[i]),
-                            "proposed": int(prop_host[i]),
-                            "accepted": int(acc_host[i])}) for i, req in traced),
+                ph, ((req, {"tokens": n, "proposed": int(prop_host[i]),
+                            "accepted": int(acc_host[i])}) for i, req, n in lanes),
                 occupancy=len(active), n_steps=N)
-        tel = self.telemetry
-        if tel is not None and tel.enabled:
-            from .telemetry import TELEMETRY_REV
-
-            tel.emit({
-                "schema": SERVING_SPEC_SCHEMA,
-                "telemetry_rev": TELEMETRY_REV,
-                # Causality key shared with trace.span/v1 decode spans (and the
-                # serving.kv/v1 record) of this same dispatch.
-                "step": self.decode_steps,
-                "spec_k": k,
-                "rounds": N,
-                "active_slots": len(active),
-                "step_proposed": step_proposed,
-                "step_accepted": step_accepted,
-                "step_tokens": step_tokens,
-                "proposed_total": self.spec_proposed,
-                "accepted_total": self.spec_accepted,
-                "spec_accept_rate": (
-                    round(self.spec_accepted / self.spec_proposed, 4)
-                    if self.spec_proposed else None
-                ),
-                "tokens_per_step": (
-                    round(self.decode_tokens / self.decode_steps, 4)
-                    if self.decode_steps else None
-                ),
-            })
+        self._count_spec(N, len(active), int(prop_host.sum()), int(acc_host.sum()),
+                         sum(n for _, _, n in lanes))
         return finished
 
     def _spec_step(self, active: list[int], ph: EnginePhase) -> list[Request]:
@@ -2164,51 +2018,33 @@ class ContinuousBatcher:
         out-of-bounds draft write."""
         k = self.spec_k
         T = k + 1
-        tracing = ph.tracer is not None
-        traced: list = []
-        proposals = np.asarray(
-            self.drafter.propose(self.slot_req, self.tokens, self.positions, k),
-            np.int32,
-        )
-        seq = np.zeros((self.max_slots, T), np.int32)
-        seq[:, 0] = self.tokens  # pending token: emitted last step, not yet written
-        seq[:, 1:] = proposals
-        t_guard = self._pre_dispatch("serving.decode", active)
-        if self.paged:
-            with compile_label("serving.spec_verify_paged"), \
-                    phase("engine.decode.dispatch"):
-                greedy, logits, self.cache = self._spec_verify_paged_fn(
-                    self.params, self.cache, jnp.asarray(self.block_mgr.tables),
-                    jnp.asarray(seq), jnp.asarray(self.positions),
-                    cfg=self.cfg, page_size=self.page_size,
-                )
-        else:
-            with compile_label("serving.spec_verify"), \
-                    phase("engine.decode.dispatch"):
-                greedy, logits, self.cache = self._spec_verify_fn(
-                    self.params, self.cache, jnp.asarray(seq),
-                    jnp.asarray(self.positions), cfg=self.cfg,
-                )
+        with phase("engine.decode.prepare"):
+            proposals = np.asarray(
+                self.drafter.propose(self.slot_req, self.tokens, self.positions, k),
+                np.int32,
+            )
+            seq = np.zeros((self.max_slots, T), np.int32)
+            seq[:, 0] = self.tokens  # pending token: emitted last step, not yet written
+            seq[:, 1:] = proposals
+            tables = self._tables()
+        t_guard, (greedy, logits, self.cache) = self._dispatch(
+            "spec_verify", active, tables,
+            (jnp.asarray(seq), jnp.asarray(self.positions)))
         with phase("engine.decode.fetch"):
             greedy_host = np.asarray(greedy)  # [B, T]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
-        finished = []
-        step_tokens = step_accepted = 0
+        emissions = []
+        accepted = {}
         for i in active:
             req = self.slot_req[i]
             # Budget cap: emitting more would overrun the validated cache window.
             limit = min(T, req.gen.max_new_tokens - len(req.tokens))
-            if req.gen.temperature <= 0.0:
-                ref = greedy_host[i]
-                n = 0
-                while n < k and proposals[i, n] == ref[n]:
-                    n += 1
-                emitted = [int(t) for t in ref[: min(n + 1, limit)]]
-            elif self.spec_accept == "residual":
+            if req.gen.temperature > 0.0 and self.spec_accept == "residual":
                 emitted_vec, count = self._residual_round(req, logits[i], proposals[i])
                 emitted = [int(t) for t in emitted_vec[: min(int(count), limit)]]
             else:
-                ref = self._replay_round(req, logits[i])
+                ref = (greedy_host[i] if req.gen.temperature <= 0.0
+                       else self._replay_round(req, logits[i]))
                 n = 0
                 while n < k and proposals[i, n] == ref[n]:
                     n += 1
@@ -2216,65 +2052,20 @@ class ContinuousBatcher:
             eos = req.gen.eos_token_id
             if eos is not None and eos in emitted:
                 emitted = emitted[: emitted.index(eos) + 1]
+            emissions.append((i, emitted))
             # Accepted = emitted tokens that were draft proposals (the trailing
             # correction/bonus is the target's own, never a proposal credit).
-            accepted_i = sum(
+            accepted[i] = sum(
                 1 for j, t in enumerate(emitted) if j < k and t == int(proposals[i, j])
             )
-            if tracing:
-                traced.append((req, len(emitted), accepted_i))
-            step_accepted += accepted_i
-            step_tokens += len(emitted)
-            self.tokens[i] = emitted[-1]
-            self.positions[i] += len(emitted)
-            for tok in emitted:
-                req.tokens.append(tok)
-                if req.on_token is not None:
-                    req.on_token(tok)
-            hit_eos = eos is not None and emitted[-1] == eos
-            if hit_eos or len(req.tokens) >= req.gen.max_new_tokens:
-                req.done = True
-                finished.append(req)
-                self.slot_req[i] = None  # slot frees; cache row overwritten on next admit
-                self._release_lane(i)
-        self.positions = np.minimum(self.positions, self.max_len - 1)
-        self.decode_steps += 1
-        self.decode_tokens += step_tokens
-        self.spec_proposed += k * len(active)
-        self.spec_accepted += step_accepted
-        if tracing:
+        finished, lanes = self._land(active, emissions)
+        if ph.tracer is not None:
             self._decode_spans(
-                ph, ((req, {"tokens": n_emitted, "proposed": k,
-                            "accepted": n_accepted})
-                     for req, n_emitted, n_accepted in traced),
+                ph, ((req, {"tokens": n, "proposed": k,
+                            "accepted": accepted[i]}) for i, req, n in lanes),
                 occupancy=len(active))
-        tel = self.telemetry
-        if tel is not None and tel.enabled:
-            from .telemetry import TELEMETRY_REV
-
-            tel.emit({
-                "schema": SERVING_SPEC_SCHEMA,
-                "telemetry_rev": TELEMETRY_REV,
-                # Causality key shared with trace.span/v1 decode spans (and the
-                # serving.kv/v1 record) of this same dispatch.
-                "step": self.decode_steps,
-                "spec_k": k,
-                "rounds": 1,  # the host loop is one round per dispatch
-                "active_slots": len(active),
-                "step_proposed": k * len(active),
-                "step_accepted": step_accepted,
-                "step_tokens": step_tokens,
-                "proposed_total": self.spec_proposed,
-                "accepted_total": self.spec_accepted,
-                "spec_accept_rate": (
-                    round(self.spec_accepted / self.spec_proposed, 4)
-                    if self.spec_proposed else None
-                ),
-                "tokens_per_step": (
-                    round(self.decode_tokens / self.decode_steps, 4)
-                    if self.decode_steps else None
-                ),
-            })
+        self._count_spec(1, len(active), k * len(active), sum(accepted.values()),
+                         sum(n for _, _, n in lanes))
         return finished
 
     def _step_keys_window(self, req: Request, start: int, T: int):
@@ -2340,110 +2131,71 @@ class ContinuousBatcher:
             return out, tokens_per_sec
         return out
 
-    def _multi_warm_args(self):
-        """(traced args, static kwargs) pairs covering the multi-step decode
-        surface for :meth:`warm_programs`: the per-lane vectors after the
-        ``params``/``cache``(/``tables``) prefix, for both ``sample`` variants
-        — shapes/dtypes exactly what ``_multi_step`` uploads at runtime."""
-        B, N = self.max_slots, self.multi_step
-        lanes = jnp.zeros((B,), jnp.int32)
-        args = (
-            lanes, lanes, jnp.zeros((B,), bool), jnp.ones((B,), jnp.int32),
-            jnp.full((B,), -1, jnp.int32), jnp.zeros((B, N, 2), jnp.uint32),
-            jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
-            jnp.zeros((B,), jnp.int32),
-        )
-        return [(args, {"n_steps": N, "sample": s}) for s in (False, True)]
-
-    def _spec_multi_warm_args(self):
-        """(traced args, static kwargs) pairs covering the FUSED speculative
-        super-step surface for :meth:`warm_programs`: the per-lane vectors +
-        key table + drafting history after the ``params``/``cache``(/``tables``)
-        prefix, for both ``sample`` variants — shapes/dtypes exactly what
-        ``_spec_multi`` uploads at runtime."""
-        B, N, T = self.max_slots, self.multi_step, self.spec_k + 1
-        lanes = jnp.zeros((B,), jnp.int32)
-        args = (
-            lanes, lanes, jnp.zeros((B,), bool), jnp.ones((B,), jnp.int32),
-            jnp.full((B,), -1, jnp.int32),
-            jnp.zeros((B, N * T, 2), jnp.uint32),
-            jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
-            jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B, self.max_len), jnp.int32),
-            jnp.zeros((B,), jnp.int32),
-        )
-        statics = {"n_steps": N, "spec_k": self.spec_k,
-                   "max_ngram": int(self.drafter.max_ngram)}
-        return [(args, {**statics, "sample": s}) for s in (False, True)]
-
     def warm_programs(self, max_new_tokens: int = 32) -> list:
         """Pre-compile this engine's whole program surface into the AOT cache
         WITHOUT executing anything (``python -m accelerate_tpu warmup --serve``).
 
-        Covers: the decode step (``spec_k == 0``) or the fused [B, spec_k+1]
-        speculative verify plus the draft source's own programs (``spec_k > 0`` —
-        draft AND verify ride the same bucket ladder and warmup manifest, so a
-        spec-enabled replica restart compiles nothing), the multi-step super-step
-        pair when ``decode_steps > 1`` (both ``sample`` variants — a mixed
-        workload alternates greedy-only and sampled super-steps), the FUSED
-        speculative super-step pair when both combine and the drafter is
-        resident (``serving.spec_multi[_paged]`` — the program such an engine
-        actually dispatches; verify + decode_multi stay warm as its degradation
+        Covers: the decode scan at this engine's ``decode_steps`` in both ``sample``
+        variants (a mixed workload alternates greedy-only and sampled super-steps),
+        with ``spec_k > 0`` the fused [B, spec_k+1] speculative verify plus the
+        draft source's own programs (draft AND verify ride the same bucket ladder
+        and warmup manifest, so a spec-enabled replica restart compiles nothing)
+        and, where the drafter is resident, the FUSED speculative super-step pair
+        (``serving.spec_multi[_paged]`` — the program such an engine actually
+        dispatches; verify + decode_multi stay warm as its degradation
         targets), one prefill per bucket
         that ``_plan_prefill`` can actually route a ``max_new_tokens``-budget
         request to, the first-chunk + chunk-append pair (the fallback for
         prompts/budgets no bucket fits — always part of the live surface), and
         the row-insert programs — per-slot scatters dense, the single
-        dynamic-slot page scatter (plus prefix gather/copy) paged. A paged
-        engine warms ITS surface; the manifest's page geometry records which
-        layout the cache directory is warm for. Returns warmup-manifest
-        entries; empty when no enabled compile cache is attached."""
+        dynamic-slot page scatter (plus prefix gather/copy) paged. An engine
+        warms ITS surface, nothing it cannot dispatch; the manifest's page
+        geometry records which layout the cache directory is warm for. Returns
+        warmup-manifest entries; empty when no enabled compile cache is attached."""
         if self.compile_cache is None:
             return []
         entries = []
-        lanes = jnp.zeros((self.max_slots,), jnp.int32)
+        if self.role != "prefill":
+            # The decode programs of this layout, lowered from the lane arguments a
+            # dispatch with no live lane would upload. Role engines warm THEIR slice
+            # of the surface: a prefill-role replica never dispatches decode.
+            tables = self._tables()
+
+            def warm(name, args, **statics):
+                _, fn = self._decode_programs[name]
+                entries.append(fn.warm(self.params, self.cache, *tables, *args,
+                                       cfg=self.cfg, **self._layout_statics, **statics))
+
+            # Both sample variants: the engine picks per super-step by whether any
+            # live lane samples, so a mixed workload needs the pair warm.
+            _, lane_args = self._lane_args([], self.multi_step)
+            for sample in (False, True):
+                warm("decode_multi", lane_args, n_steps=self.multi_step, sample=sample)
+            if self.spec_k:
+                warm("spec_verify", (
+                    jnp.zeros((self.max_slots, self.spec_k + 1), jnp.int32),
+                    jnp.asarray(self.positions)))
+                if self._spec_fused():
+                    # The fused spec super-step pair: what this engine dispatches
+                    # while spec_enabled; the host-loop verify above stays warm as
+                    # its degradation target alongside decode_multi.
+                    _, lane_args = self._lane_args(
+                        [], self.multi_step * (self.spec_k + 1))
+                    lane_args += (jnp.zeros((self.max_slots, self.max_len), jnp.int32),
+                                  jnp.zeros((self.max_slots,), jnp.int32))
+                    for sample in (False, True):
+                        warm("spec_multi", lane_args, n_steps=self.multi_step,
+                             spec_k=self.spec_k,
+                             max_ngram=int(self.drafter.max_ngram), sample=sample)
+                entries.extend(self.drafter.warm_programs(self, max_new_tokens))
         if self.paged:
-            # Paged surface: the block-table-indirected decode/verify pair plus the
-            # dynamic-slot page scatter (ONE program for every slot/row — the table
-            # made the lane index data) and, with prefix caching, the page gather +
-            # partial-page copy. Prefill programs below are layout-shared with dense.
-            # Role engines warm THEIR slice of the surface: a decode-role replica
-            # has no prefill/insert programs at all (the handoff import + COW copy
-            # + lane-valid setup replace them), and a prefill-role replica warms
-            # the page-export gather instead of decode/verify.
-            tables = jnp.asarray(self.block_mgr.tables)
-            if self.role != "prefill":
-                entries.append(self._decode_paged_fn.warm(
-                    self.params, self.cache, tables, lanes, lanes,
-                    cfg=self.cfg, page_size=self.page_size,
-                ))
-                if self.multi_step > 1:
-                    # Both sample variants: the engine picks per super-step by
-                    # whether any live lane samples, so a mixed workload needs
-                    # the pair warm (greedy-only AND sampled super-steps).
-                    for args, statics in self._multi_warm_args():
-                        entries.append(self._decode_multi_paged_fn.warm(
-                            self.params, self.cache, tables, *args,
-                            cfg=self.cfg, page_size=self.page_size, **statics,
-                        ))
-                if self.spec_k:
-                    seq = jnp.zeros((self.max_slots, self.spec_k + 1), jnp.int32)
-                    entries.append(self._spec_verify_paged_fn.warm(
-                        self.params, self.cache, tables, seq, lanes,
-                        cfg=self.cfg, page_size=self.page_size,
-                    ))
-                    if self._spec_fused():
-                        # The fused spec super-step pair (both sample variants):
-                        # the program this engine actually dispatches while
-                        # spec_enabled; the host-loop verify above stays warm as
-                        # its degradation target alongside decode_multi.
-                        for args, statics in self._spec_multi_warm_args():
-                            entries.append(self._spec_multi_paged_fn.warm(
-                                self.params, self.cache, tables, *args,
-                                cfg=self.cfg, page_size=self.page_size,
-                                **statics,
-                            ))
-                    entries.extend(self.drafter.warm_programs(self, max_new_tokens))
+            # Paged surface: the dynamic-slot page scatter (ONE program for every
+            # slot/row — the table made the lane index data) and, with prefix
+            # caching, the page gather + partial-page copy. Prefill programs below
+            # are layout-shared with dense. A decode-role replica has no
+            # prefill/insert programs at all (the handoff import + COW copy +
+            # lane-valid setup replace them), and a prefill-role replica warms the
+            # page-export gather.
             write_ids = jnp.zeros((self.block_mgr.max_pages,), jnp.int32)
             if self.role == "decode":
                 page_axis = 1 if self.cfg.scan_layers else 0
@@ -2484,34 +2236,6 @@ class ContinuousBatcher:
                 entries.append(self._copy_page_fn.warm(
                     self.cache, 0, 0, scan_layers=self.cfg.scan_layers,
                 ))
-        else:
-            # The plain decode step is warmed for spec engines too: a spec-enabled
-            # replica only dispatches the verify, but warming decode keeps the same
-            # cache directory serving a spec_k=0 restart (toggling speculation off
-            # must not cost compiles).
-            entries.append(self._decode_fn.warm(
-                self.params, self.cache, lanes, lanes, cfg=self.cfg
-            ))
-            if self.multi_step > 1:
-                for args, statics in self._multi_warm_args():
-                    entries.append(self._decode_multi_fn.warm(
-                        self.params, self.cache, *args, cfg=self.cfg, **statics,
-                    ))
-            if self.spec_k:
-                seq = jnp.zeros((self.max_slots, self.spec_k + 1), jnp.int32)
-                entries.append(self._spec_verify_fn.warm(
-                    self.params, self.cache, seq, lanes, cfg=self.cfg
-                ))
-                if self._spec_fused():
-                    # Fused spec super-step pair (both sample variants) — the
-                    # dispatched program while spec_enabled; the host-loop
-                    # verify stays warm as its degradation target.
-                    for args, statics in self._spec_multi_warm_args():
-                        entries.append(self._spec_multi_fn.warm(
-                            self.params, self.cache, *args, cfg=self.cfg,
-                            **statics,
-                        ))
-                entries.extend(self.drafter.warm_programs(self, max_new_tokens))
         if self.prompt_buckets is not None and not self.prefix_cache_size:
             # Only buckets a request with this generation budget can land in —
             # a bucket with b + max_new > max_len is unreachable via _plan_prefill.

@@ -237,7 +237,7 @@ SCHEMA_REGISTRY: Dict[str, RecordSchema] = {
             ("telemetry_rev", "step", "spec_k", "rounds", "active_slots",
              "step_proposed", "step_accepted", "step_tokens", "proposed_total",
              "accepted_total"),
-            "ContinuousBatcher._spec_step / _spec_multi",
+            "ContinuousBatcher._count_spec (_spec_step / _spec_multi)",
             "speculative proposal/acceptance per dispatch (rounds=1 host loop; "
             "rounds=N fused super-step)",
         ),

@@ -58,7 +58,7 @@ def test_default_enumeration_covers_the_warmup_surface(default_captures):
     labels = {c.label for c in default_captures}
     assert "train_step.apply" in labels
     assert "eval_step" in labels
-    assert "serving.decode" in labels
+    assert "serving.decode_multi" in labels
     assert any(l.startswith("serving.prefill") for l in labels), labels
     assert any("insert" in l for l in labels), labels
     # The speculative surface (ISSUE 6): the fused [B, k+1] verify and the draft
@@ -71,7 +71,7 @@ def test_default_enumeration_covers_the_warmup_surface(default_captures):
     # layout alongside the dense one — block-table decode/verify, the
     # dynamic-slot page scatter, and the prefix gather/copy pair — so the empty
     # ratchet baselines cover both layouts.
-    assert {"serving.decode_paged", "serving.spec_verify_paged",
+    assert {"serving.decode_multi_paged", "serving.spec_verify_paged",
             "serving.insert_paged", "serving.gather_row_paged",
             "serving.copy_page"} <= labels, labels
     # The fused speculative super-step pair (ISSUE 18): the dense program rides
@@ -79,7 +79,7 @@ def test_default_enumeration_covers_the_warmup_surface(default_captures):
     # is not resident), the paged twin rides the paged pass — both under the
     # same empty ratchet baselines.
     assert {"serving.spec_multi", "serving.spec_multi_paged"} <= labels, labels
-    # Multi-step decode fallback pair stays on the surface too.
+    # The decode scan, both layouts: what every decoding engine dispatches.
     assert {"serving.decode_multi", "serving.decode_multi_paged"} <= labels, labels
     # The MPMD stage-program surface (ISSUE 11): the alternative TRAINING
     # layout is lowered alongside the SPMD step, and the inventory audits the

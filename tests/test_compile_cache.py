@@ -223,9 +223,11 @@ def test_fused_train_step_compiles_exactly_once():
 
 
 def test_serving_decode_compiles_bounded_by_buckets():
-    """Regression guard: across varied prompt lengths, serving compiles at most
-    one decode + one prefill per bucket + one insert per slot — and a second
-    varied-length workload compiles NOTHING new."""
+    """Regression guard: across varied prompt lengths, a greedy workload compiles
+    at most one decode scan (its greedy ``sample`` variant; a sampled lane would
+    add the other) + the zero key window a greedy dispatch hands it + one prefill
+    per bucket + one insert per slot — and a second varied-length workload
+    compiles NOTHING new."""
     from accelerate_tpu.models import llama
     from accelerate_tpu.serving import ContinuousBatcher
 
@@ -254,7 +256,9 @@ def test_serving_decode_compiles_bounded_by_buckets():
         engine.run()
     finally:
         mon.stop()
-    bound = len(buckets) + 1 + engine.max_slots  # prefill/bucket + decode + inserts
+    # prefill/bucket + serving.decode_multi (sample=False) + the jnp.zeros key
+    # filler (a broadcast program) + inserts: 3 + 1 + 1 + 2 = 7
+    bound = len(buckets) + 1 + 1 + engine.max_slots
     assert first_workload <= bound, (first_workload, bound)
     assert mon.count == first_workload, (
         f"second varied-length workload recompiled {mon.count - first_workload} programs"
@@ -335,9 +339,9 @@ def test_paged_serving_second_varied_workload_compiles_zero():
     """Paged-engine compile surface (ISSUE 7): per-request page allocation, block
     tables, slot choice and pool occupancy are DATA — a second varied workload on
     a paged engine (different prompts, lengths, budgets, lane churn) compiles
-    zero new programs. First-workload bound: one paged decode + one prefill per
-    touched bucket + ONE dynamic-slot page scatter (the paged insert needs no
-    per-slot variants)."""
+    zero new programs. First-workload bound: one paged decode scan (greedy
+    variant) + its zero key window + one prefill per touched bucket + ONE
+    dynamic-slot page scatter (the paged insert needs no per-slot variants)."""
     from accelerate_tpu.models import llama
     from accelerate_tpu.serving import ContinuousBatcher
 
@@ -366,7 +370,9 @@ def test_paged_serving_second_varied_workload_compiles_zero():
         engine.run()
     finally:
         mon.stop()
-    bound = len(buckets) + 1 + 1  # prefill/bucket + paged decode + page scatter
+    # prefill/bucket + serving.decode_multi_paged (sample=False) + the jnp.zeros
+    # key filler + the page scatter: 3 + 1 + 1 + 1 = 6
+    bound = len(buckets) + 1 + 1 + 1
     assert first_workload <= bound, (first_workload, bound)
     assert mon.count == first_workload, (
         f"second paged workload recompiled {mon.count - first_workload} programs"
@@ -505,7 +511,7 @@ def test_warmup_enumerates_multistep_programs(tmp_path):
     assert manifest["decode_steps"] == 4
     labels = {e["label"] for e in manifest["programs"]}
     assert "serving.decode_multi" in labels, labels
-    assert "serving.decode" in labels  # one-token restarts stay warm too
+    assert "serving.decode" not in labels  # the scan is the only decode program
     paged = run_warmup(
         cache=LowerOnlyCache(), emit_manifest=False,
         preset="smoke", batch_size=2, seq_len=16, train=False, eval_step=False,
@@ -579,7 +585,7 @@ def test_warmup_enumerates_paged_programs(tmp_path):
     assert manifest["kv_pages"] == 2 * -(-128 // 24)
     assert manifest["prefix_cache"] == 2
     labels = {e["label"] for e in manifest["programs"]}
-    assert {"serving.decode_paged", "serving.spec_verify_paged",
+    assert {"serving.decode_multi_paged", "serving.spec_verify_paged",
             "serving.insert_paged", "serving.gather_row_paged",
             "serving.copy_page"} <= labels, labels
     # paged args without serve would warm nothing — must be loud.
@@ -607,7 +613,7 @@ def test_warmup_enumerates_spec_and_draft_programs(tmp_path):
     assert manifest["spec_k"] == 2 and manifest["spec_draft"] == "half"
     labels = {e["label"] for e in manifest["programs"]}
     assert "serving.spec_verify" in labels, labels
-    assert "serving.decode" in labels  # spec-off restarts stay warm too
+    assert "serving.decode_multi" in labels  # spec-off restarts stay warm too
     assert {"serving.draft.decode", "serving.draft.prefill",
             "serving.draft.prefill_chunk", "serving.draft.insert_row"} <= labels, labels
     # spec_k without serve would warm nothing and stamp spec_k=0 — must be loud.

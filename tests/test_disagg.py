@@ -445,7 +445,7 @@ def test_decode_only_warm_surface():
                           max_new_tokens=16, page_size=8, role="decode")
     labels = {c.label for c in cache.capture}
     assert manifest["role"] == "decode"
-    assert {"serving.decode_paged", "serving.import_pages",
+    assert {"serving.decode_multi_paged", "serving.import_pages",
             "serving.copy_page", "serving.lane_valid"} <= labels, labels
     assert not any("prefill" in l or "insert" in l for l in labels), labels
 

@@ -70,8 +70,8 @@ def test_estimates_cover_the_default_surface(default_captures):
     _findings, estimates, _stale, _notices = run_memaudit(
         captures=default_captures
     )
-    for label in ("train_step.apply", "eval_step", "serving.decode",
-                  "serving.decode_paged", "mpmd.stage0.fwd"):
+    for label in ("train_step.apply", "eval_step", "serving.decode_multi",
+                  "serving.decode_multi_paged", "mpmd.stage0.fwd"):
         assert label in estimates, sorted(estimates)
         assert estimates[label]["peak_bytes"] > 0, label
         assert estimates[label]["peak_bytes"] < DEFAULT_CHIP_BUDGET_BYTES, label

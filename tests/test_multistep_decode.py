@@ -1,9 +1,10 @@
-"""Device-resident multi-step decode (docs/multistep_decode.md): bitwise parity
-with the classic one-token engine.
+"""Device-resident multi-step decode (docs/multistep_decode.md): the same tokens
+at every scan length.
 
-The contract under test: ``decode_steps = N > 1`` NEVER changes emitted tokens —
+The contract under test: ``decode_steps = N`` NEVER changes emitted tokens —
 greedy and sampled (temperature/top-k/top-p, fixed PRNG) decode are token-for-
-token identical to ``decode_steps = 1``, dense and paged, across staggered
+token identical to ``decode_steps = 1`` (the same scan at N = 1, itself pinned
+against ``llama.generate`` by tests/test_serving.py), dense and paged, across staggered
 admission, EOS mid-super-step, budgets that are not a multiple of N, cancel/
 evict between super-steps, prefix-cache reuse, handoff-adopted lanes, and
 chaos-injected super-step faults (survivors bitwise via replay recovery). The
@@ -65,15 +66,36 @@ def run_workload(engine, prompts, budgets=None, gens=None, rngs=None,
     return reqs
 
 
+_GENERATED: dict = {}
+
+
+def generated(workload, params, prompts, gens, rngs=None):
+    """What ``llama.generate`` gives each request alone, prompt left-padded to the
+    engine's bucket: the reference outside the engine that every scan length,
+    N = 1 included, is held to (made once per ``workload``, its cases share it)."""
+    if workload not in _GENERATED:
+        out = []
+        for i, (p, g) in enumerate(zip(prompts, gens)):
+            row = np.zeros((1, 16), np.int32)
+            row[0, 16 - len(p):] = p
+            out.append(np.asarray(llama.generate(
+                params, jnp.asarray(row), CFG, g, prompt_mask=jnp.asarray(row != 0),
+                rng=None if rngs is None else rngs[i]))[0].tolist())
+        _GENERATED[workload] = out
+    return _GENERATED[workload]
+
+
 # --------------------------------------------------------------------- parity
-@pytest.mark.parametrize("n_steps", [2, 4, 8])
+@pytest.mark.parametrize("n_steps", [1, 2, 4, 8])
 def test_greedy_parity_dense(setup, n_steps):
     """Staggered admission (more requests than lanes), varied budgets
-    including ones that are NOT a multiple of N: bitwise the N=1 output."""
+    including ones that are NOT a multiple of N: ``generate()``'s output at
+    every N."""
     params, prompts = setup
     budgets = [6, 11, 8, 3, 5, 7]
-    want = [r.tokens for r in
-            run_workload(make_engine(params), prompts, budgets=budgets)]
+    want = generated("greedy", params, prompts,
+                     [GenerationConfig(max_new_tokens=b, temperature=0.0)
+                      for b in budgets])
     reqs = run_workload(make_engine(params, decode_steps=n_steps),
                         prompts, budgets=budgets)
     for r, w, b in zip(reqs, want, budgets):
@@ -81,11 +103,11 @@ def test_greedy_parity_dense(setup, n_steps):
         assert r.tokens == w, r.uid
 
 
-@pytest.mark.parametrize("n_steps", [2, 4])
+@pytest.mark.parametrize("n_steps", [1, 2, 4])
 def test_sampled_parity_dense(setup, n_steps):
     """temperature/top-k/top-p lanes mixed with a greedy lane in ONE
     super-step program: the per-lane emission-indexed key schedule makes the
-    scan's draws bitwise the one-token engine's."""
+    scan's draws bitwise ``generate()``'s."""
     params, prompts = setup
     gens = [
         GenerationConfig(max_new_tokens=7, temperature=0.8, top_k=7),
@@ -95,15 +117,14 @@ def test_sampled_parity_dense(setup, n_steps):
     ]
     rngs = [jax.random.PRNGKey(100 + i) if g.temperature > 0 else None
             for i, g in enumerate(gens)]
-    want = [r.tokens for r in run_workload(
-        make_engine(params), prompts[:4], gens=gens, rngs=rngs)]
+    want = generated("sampled", params, prompts[:4], gens, rngs)
     reqs = run_workload(make_engine(params, decode_steps=n_steps),
                         prompts[:4], gens=gens, rngs=rngs)
     for r, w in zip(reqs, want):
         assert r.tokens == w, (r.uid, r.tokens, w)
 
 
-@pytest.mark.parametrize("n_steps", [2, 4])
+@pytest.mark.parametrize("n_steps", [1, 2, 4])
 def test_parity_paged(setup, n_steps):
     """Paged KV engine: the super-step writes through the device-resident
     block table (one table upload per dispatch) and stays bitwise."""
@@ -114,8 +135,7 @@ def test_parity_paged(setup, n_steps):
         GenerationConfig(max_new_tokens=10, temperature=0.9, top_k=9),
     ]
     rngs = [None, jax.random.PRNGKey(7), jax.random.PRNGKey(8)]
-    want = [r.tokens for r in run_workload(
-        make_engine(params, page_size=8), prompts[:3], gens=gens, rngs=rngs)]
+    want = generated("paged", params, prompts[:3], gens, rngs)
     eng = make_engine(params, decode_steps=n_steps, page_size=8)
     reqs = run_workload(eng, prompts[:3], gens=gens, rngs=rngs)
     for r, w in zip(reqs, want):
@@ -340,6 +360,82 @@ def test_superstep_trace_spans_account_n_tokens(setup):
     # 6-token budgets: prefill emits token 0, decode super-steps the other 5
     # per lane (N=4 then a budget-clamped 1)
     assert sum(s["tokens"] for s in spans) == 10
+
+
+# ------------------------------------------- one loop: N = 1 is the scan too
+@pytest.mark.parametrize("page_size", [0, 8], ids=["dense", "paged"])
+def test_default_engine_dispatches_the_scan_and_warms_what_it_runs(
+        setup, monkeypatch, page_size):
+    """``decode_steps=1`` (the default) has no loop of its own: it dispatches
+    ``serving.decode_multi[_paged]`` at N = 1, and ``warm_programs()`` lists the
+    scan's two ``sample`` variants and no one-token program — at any N an
+    engine warms nothing it cannot dispatch."""
+    import contextlib
+
+    from accelerate_tpu import serving
+    from accelerate_tpu.analysis.program import LowerOnlyCache
+
+    params, prompts = setup
+    scan = "serving.decode_multi" + ("_paged" if page_size else "")
+    seen = []
+
+    @contextlib.contextmanager
+    def recording(label):
+        seen.append(label)
+        yield
+
+    monkeypatch.setattr(serving, "compile_label", recording)
+    eng = make_engine(params, page_size=page_size)
+    assert eng.multi_step == 1
+    run_workload(eng, prompts[:3], budgets=[4, 3, 5])
+    assert seen and set(seen) == {scan}, seen
+
+    for n_steps in (1, 4):
+        warm = make_engine(params, decode_steps=n_steps, page_size=page_size,
+                           compile_cache=LowerOnlyCache())
+        labels = [e["label"] for e in warm.warm_programs(max_new_tokens=4)]
+        assert [l for l in labels if "decode" in l] == [scan, scan], labels
+
+
+def test_one_step_of_the_default_engine_shows_the_four_decode_phases(
+        setup, monkeypatch):
+    """The readers built on the engine's phases (docs/telemetry.md) see the default
+    engine like any other: one ``step()`` is ``decode.prepare`` → ``dispatch`` →
+    ``fetch`` → ``drain`` under an ``engine.decode`` with ``n_steps == 1``.
+    Recorded without a profiler session, by standing in for ``phase``."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.telemetry import tracing
+
+    class Recorded:
+        log = []
+
+        def __init__(self, name, **attrs):
+            self.name, self.attrs = name, attrs
+
+        def __enter__(self):
+            self.log.append(self)
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+        def set_metadata(self, **attrs):
+            self.attrs.update(attrs)
+
+    monkeypatch.setattr(serving, "phase", Recorded)
+    monkeypatch.setattr(tracing, "phase", Recorded)   # EnginePhase's annotation
+    params, prompts = setup
+    eng = make_engine(params)
+    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts[:2]]
+    eng.step()
+    names = [r.name for r in Recorded.log]
+    inside = names[names.index("engine.decode") + 1:]
+    assert inside == ["engine.decode.prepare", "engine.decode.dispatch",
+                      "engine.decode.fetch", "engine.decode.drain"], names
+    by = {r.name: r.attrs for r in Recorded.log}
+    assert by["engine.decode"] == {"lanes": 2, "n_steps": 1}
+    assert by["engine.decode.drain"]["tokens"] == 2
+    assert [len(r.tokens) for r in reqs] == [2, 2]   # the prefill's token + one
 
 
 def test_sampling_core_dyn_k_matches_static():
