@@ -31,6 +31,7 @@ __all__ = [
     "fused_ce_allowed", "fused_ce_single_shard",
     "resolve_loss_chunk", "chunked_ce", "ce_sum", "ce_sum_dispatch",
     "sp_active", "sp_manual", "resolve_sp_pipeline", "attention_dispatch",
+    "cached_prefill_attention",
 ]
 
 
@@ -478,6 +479,9 @@ def cached_decode_family(cfg):
 
 
 # ------------------------------------------------------------- attention dispatch (shared)
+_SP_MODES = ("ring", "ulysses", "ulysses_ppermute", "allgather")
+
+
 def sp_active(mesh) -> bool:
     """Does this mesh (concrete or abstract; may be None) engage the sp axis? The ONE
     copy of the sequence-parallel activation predicate — shared by the family attention
@@ -517,7 +521,7 @@ def resolve_sp_pipeline(cfg, mesh, schedule: str, virtual_stages: int):
     cannot drift when the wall moves."""
     import dataclasses
 
-    if cfg.attn_impl not in ("ring", "ulysses", "ulysses_ppermute", "allgather"):
+    if cfg.attn_impl not in _SP_MODES:
         return False, cfg
     if not (sp_active(mesh) or sp_active(current_abstract_mesh())):
         return False, cfg
@@ -526,16 +530,32 @@ def resolve_sp_pipeline(cfg, mesh, schedule: str, virtual_stages: int):
     return True, cfg
 
 
-def _flash_sharded(q, k, v, segment_ids, **kw):
-    """``flash_attention`` under a multi-device mesh. A Mosaic custom call has no
-    partitioning rule (jax refuses to lower one GSPMD would have to partition), so when
-    the ambient mesh has more than one device the call runs under ``shard_map``, each
-    device on its own rows and heads (``ops._common.attention_shard_spec``), as
-    ``loss_impl="fused_dp"`` does for the loss. Inside an already-manual region
-    (pipeline stages, sp) the caller owns the layout."""
+def _mosaic_sharded(local, q, k, v, rows=(), scalars=()):
+    """``local(q, k, v, *rows, *scalars)`` — an attention kernel over q [B,S,H,hd] and
+    k/v [B,T,K,hd] — under a multi-device mesh. A Mosaic custom call has no partitioning
+    rule (jax refuses to lower one GSPMD would have to partition), so when the ambient
+    mesh has more than one device the call runs under ``shard_map``, each device on its
+    own rows and heads (``ops._common.attention_shard_spec``), as ``loss_impl="fused_dp"``
+    does for the loss; ``rows`` are per-row arrays [B, ...] that follow the batch,
+    ``scalars`` are replicated. Inside an already-manual region (pipeline stages, sp) the
+    caller owns the layout."""
     from jax.sharding import PartitionSpec as P
 
     from ..ops._common import attention_shard_spec
+
+    mesh = current_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return local(q, k, v, *rows, *scalars)
+    qkv = attention_shard_spec(mesh, q, k)
+    return _shard_map(
+        local, mesh=mesh,
+        in_specs=(qkv, qkv, qkv) + (P(qkv[0]),) * len(rows) + (P(),) * len(scalars),
+        out_specs=qkv, check_vma=False,  # pallas_call outputs carry no vma info
+    )(q, k, v, *rows, *scalars)
+
+
+def _flash_sharded(q, k, v, segment_ids, **kw):
+    """``flash_attention`` (causal self-attention) through :func:`_mosaic_sharded`."""
     from ..ops.flash_attention import flash_attention
 
     def local(q, k, v, *seg):
@@ -543,15 +563,70 @@ def _flash_sharded(q, k, v, segment_ids, **kw):
             q, k, v, causal=True, segment_ids=seg[0] if seg else None, **kw
         )
 
-    seg = () if segment_ids is None else (segment_ids,)
-    mesh = current_abstract_mesh()
-    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
-        return local(q, k, v, *seg)
-    qkv = attention_shard_spec(mesh, q, k)
-    return _shard_map(
-        local, mesh=mesh, in_specs=(qkv, qkv, qkv) + (P(qkv[0]),) * len(seg),
-        out_specs=qkv, check_vma=False,  # pallas_call outputs carry no vma info
-    )(q, k, v, *seg)
+    return _mosaic_sharded(
+        local, q, k, v, rows=() if segment_ids is None else (segment_ids,)
+    )
+
+
+def _local_attn_impl(impl: str) -> str:
+    """The single-device form of ``impl``: ``auto`` — and an sp mode where no sp axis is
+    live, or in a cached forward, which has no sp form — is ``flash`` on a TPU backend
+    and ``xla`` elsewhere (trace-time, like :func:`paged_read_impl`). The ONE copy of the
+    rule, for the self-attention and the cached-prefill dispatch alike."""
+    if impl == "auto" or impl in _SP_MODES:
+        from ..utils.imports import is_tpu_available
+
+        return "flash" if is_tpu_available() else "xla"
+    return impl
+
+
+def cached_prefill_attention(q, ck, cv, index, valid, *, impl: str, sm_scale: float,
+                             window: int = 0, softcap: float = 0.0, xla_attention):
+    """Family-shared attention of a cached forward's queries q [B,T,H,hd] against the row
+    cache ck/cv [B,C,K,hd] they were just written into at slots ``index .. index+T-1``
+    (slot j IS position j; ``valid`` [B,C] marks the live slots).
+
+    A PREFILL chunk takes the flash forward kernel, positioned by the cache index; every
+    other call keeps ``xla_attention()``, the family's masked-softmax math over the whole
+    row. What makes a call a prefill is read off its arguments, never set:
+
+    - ``index`` is a scalar: every row writes at the same slot (``forward_cached``). The
+      engine's per-lane decode and the speculative verify pass a vector.
+    - ``T`` is a multiple of 128: whole kernel tiles (the engine's prompt buckets are;
+      ``T = 1`` decode is an HBM-bandwidth gather and ``T = spec_k`` a few rows, XLA's
+      shapes both).
+    - ``impl`` resolves to ``flash`` as in :func:`attention_dispatch` (``auto`` = a TPU
+      backend; the sp modes have no cached form and count as ``auto``; ``flash`` forces
+      the kernel, interpreted off-TPU; ``xla`` never takes it).
+
+    The kernel sees only the BAND of the row a chunk can attend — the last ``window + T``
+    slots up to the chunk's end (the whole row without a window) — so the relayout into
+    its [B,K,T,hd] form and its key grid cover 4 608 of an 8 192-slot Mistral row, and a
+    key's global position reaches it as ``kv_offset``. ``valid`` rides as the segment
+    pair ``(ones, valid)``: the kernel's ``sq == sk ∧ sk ≠ 0`` is the validity mask, its
+    ``col ≤ row`` / ``col > row − window`` on global positions the causal band. Equal to
+    ``xla_attention()`` on every query row with a live key; a row with none (a left-pad
+    position, which no live query attends) reads zeros where XLA reads a mean. A kernel
+    the compiler refuses is an error at the call, never a quiet switch."""
+    T, C = q.shape[1], ck.shape[1]
+    if jnp.ndim(index) or T % 128 or _local_attn_impl(impl) != "flash":
+        return xla_attention()
+    from ..ops.flash_attention import _flash_bhsd_offset
+
+    span = min(C, window + T) if window else C
+    start = jnp.clip(index + T - span, 0, C - span).astype(jnp.int32)
+    ck, cv, valid = (
+        jax.lax.dynamic_slice_in_dim(a, start, span, axis=1) for a in (ck, cv, valid)
+    )
+
+    def local(q, k, v, valid, index, start):
+        return _flash_bhsd_offset(
+            q, k, v, q_offset=index, kv_offset=start, causal=True, sm_scale=sm_scale,
+            window=window, softcap=softcap,
+            segments=(jnp.ones(q.shape[:2], jnp.int32), valid.astype(jnp.int32)),
+        )
+
+    return _mosaic_sharded(local, q, ck, cv, rows=(valid,), scalars=(index, start))
 
 
 def attention_dispatch(q, k, v, mask, *, impl: str, sm_scale: float, window: int = 0,
@@ -569,7 +644,7 @@ def attention_dispatch(q, k, v, mask, *, impl: str, sm_scale: float, window: int
       error at the call, never a quiet switch to this path."""
     from ..utils.constants import SEQUENCE_AXIS
 
-    if impl in ("ring", "ulysses", "ulysses_ppermute", "allgather"):
+    if impl in _SP_MODES:
         mesh = current_abstract_mesh()
         if sp_active(mesh):
             if sp_manual(mesh):
@@ -587,11 +662,7 @@ def attention_dispatch(q, k, v, mask, *, impl: str, sm_scale: float, window: int
                 window=window, softcap=softcap, sm_scale=sm_scale,
             )
             return attn(q, k, v, segment_ids=segment_ids)
-        impl = "auto"
-    if impl == "auto":
-        from ..utils.imports import is_tpu_available
-
-        impl = "flash" if is_tpu_available() else "xla"
+    impl = _local_attn_impl(impl)
     if impl == "flash":
         # Packed rows stay on the flash path: the kernels take segment ids directly.
         return _flash_sharded(

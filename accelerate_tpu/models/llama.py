@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..utils.constants import BATCH_AXES, SEQUENCE_AXIS, TENSOR_AXIS
+from .common import cached_prefill_attention as _cached_prefill_attention
 from .common import kv_planes as _kv_planes
 from .common import paged_attention_dispatch as _paged_attention
 from .common import paged_kv_planes as _paged_kv_planes
@@ -1256,8 +1257,14 @@ def _attention_cached(q, ck, cv, q_positions, valid, cfg: LlamaConfig):
     """q [B,T,H,hd] against the full cache ck/cv [B,C,K,hd]; ``valid`` [B,C] marks live keys.
 
     Causality: key slot j may be seen by the query at absolute slot p iff ``j <= p``.
-    Single-token decode (T=1) is a pure HBM-bandwidth gather — the XLA path is the right
-    kernel; flash only pays off for the (uncached) training/prefill shapes.
+    Scores every slot of the row in plain XLA, whatever the row holds. That is the right
+    form for the shapes that keep it: single-token decode (T=1, a pure HBM-bandwidth
+    gather), the speculative verify (T=k, a few rows), the gather fallback of the paged
+    read, and every cached call off-TPU. A PREFILL chunk (scalar write index, T a
+    multiple of 128) on a TPU goes through the flash forward kernel instead
+    (``common.cached_prefill_attention``: 1.2 ms → 0.2–0.5 ms a layer for 512 queries
+    against an 8 192-slot row, PERF.md PR 31); this function is then its reference, equal
+    on every query row that has a live key.
     """
     B, T, H, hd = q.shape
     C = ck.shape[1]
@@ -1338,9 +1345,13 @@ def _block_cached(x, layer, kv, index, positions, valid, cfg: LlamaConfig,
             # The dense read takes its layer's planes out of a carried stack: one layer's
             # bytes, what the attention reads anyway.
             own = new_kv if at_layer is None else {n: p[at_layer] for n, p in new_kv.items()}
-            attn = _attention_cached(
-                q, _read_cache(own, "k", cfg.dtype), _read_cache(own, "v", cfg.dtype),
-                positions, valid, cfg,
+            ck, cv = _read_cache(own, "k", cfg.dtype), _read_cache(own, "v", cfg.dtype)
+            # A prefill chunk (scalar index, T a multiple of 128) takes the flash kernel
+            # on a TPU; decode, verify and the CPU keep ``_attention_cached``.
+            attn = _cached_prefill_attention(
+                q, ck, cv, index, valid, impl=cfg.attn_impl, sm_scale=_sm_scale(cfg),
+                window=cfg.sliding_window, softcap=cfg.attn_softcap,
+                xla_attention=lambda: _attention_cached(q, ck, cv, positions, valid, cfg),
             )
         attn_out = _proj_l(attn.reshape(B, T, cfg.n_heads * cfg.head_dim), layer, "wo", cfg)
         if cfg.post_norm:

@@ -16,7 +16,11 @@ sliced off by the wrapper.
 the global position of the local block — this is what lets ``ops/ring_attention.py`` reuse
 these exact kernels per ring step with correct cross-device causal masking. The raw ``_fwd`` /
 ``_bwd_dq`` / ``_bwd_dkv`` entry points (returning/consuming lse and delta) are the building
-blocks for the ring; ``flash_attention`` is the single-device public API.
+blocks for the ring; ``flash_attention`` is the single-device public API. The serving
+prefill is the offsets' second caller (``models/common.py::cached_prefill_attention`` through
+``_flash_bhsd_offset``): a chunk of queries at ``q_offset`` = the cache's write index against
+the band of its row cache at ``kv_offset``, S != T, the cache's valid mask as the
+``(q_seg, kv_seg)`` pair — the forward kernel alone, named ``flash_fwd`` in the prefill programs.
 
 TPU-specific structure (the same three choices the official jax flash kernel makes):
 

@@ -372,6 +372,41 @@ def kernel_flash(cfg, sizes: Sizes) -> None:
     close(dv[:, :, kvh], dv_ref[:, :, kvh], TOL_BF16, "flash dv")
 
 
+def kernel_flash_prefill(cfg, sizes: Sizes) -> None:
+    """the flash forward as the serving prefill calls it (a chunk of ``prompt_bucket``
+    queries at a traced cache index against the band of a ``max_len`` row, the valid mask
+    as a segment pair, S != T) vs llama's ``_attention_cached`` on the same arrays: a
+    prompt's first chunk, left-padded, and a chunk past the window."""
+    from accelerate_tpu.models import llama
+    from accelerate_tpu.models.common import cached_prefill_attention
+
+    T, C, H, K, hd = sizes.prompt_bucket, sizes.max_len, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(SEED + 11), 3)
+    q = jax.random.normal(kq, (1, T, H, hd), jnp.bfloat16)
+    ck = jax.random.normal(kk, (1, C, K, hd), jnp.bfloat16)   # noise above the fill too
+    cv = jax.random.normal(kv, (1, C, K, hd), jnp.bfloat16)
+    pad, slots = T // 5, jnp.arange(C)
+
+    def kernel(q, ck, cv, index, valid):
+        return cached_prefill_attention(
+            q, ck, cv, index, valid, impl="flash", sm_scale=llama._sm_scale(cfg),
+            window=cfg.sliding_window, softcap=cfg.attn_softcap, xla_attention=None)
+
+    def reference(q, ck, cv, index, valid):
+        positions = index + jnp.arange(T, dtype=jnp.int32)[None]
+        with jax.default_matmul_precision("highest"):
+            return llama._attention_cached(q, ck, cv, positions, valid, cfg)
+
+    kernel, reference = jax.jit(kernel), jax.jit(reference)
+    for index in (0, C - 2 * T):
+        valid = ((slots >= pad) & (slots < index + T))[None]
+        has_key = (index + jnp.arange(T) >= pad)[None, :, None, None]   # pad rows: zeros
+        got = twice(f"flash prefill at {index}", kernel, q, ck, cv, jnp.int32(index), valid)
+        want = reference(q, ck, cv, jnp.int32(index), valid)
+        close(jnp.where(has_key, got, 0), jnp.where(has_key, want, 0), TOL_BF16,
+              f"flash prefill chunk at index {index}")
+
+
 def kernel_flash_packed(cfg, sizes: Sizes) -> None:
     """the flash kernels' packed-rows variant (segment ids in-kernel: what sample
     packing trains through) at [2, seq/4, H, hd] vs llama's XLA path + segment_mask."""
@@ -629,6 +664,7 @@ def kernels(cfg, sizes: Sizes, compiles: Compiles, dry: bool) -> None:
         say("[kernels] skipped: interpret=False needs the chip")
         return
     kernel_flash(cfg, sizes)
+    kernel_flash_prefill(cfg, sizes)
     kernel_flash_packed(cfg, sizes)
     kernel_paged(cfg, sizes, quantized=False)
     kernel_paged(cfg, sizes, quantized=True)

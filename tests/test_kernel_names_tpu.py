@@ -2,7 +2,8 @@
 ``paged_attention`` and ``mla_paged_attention`` compiled for a DESCRIBED v5e chip (none is
 attached) at the benchmark cells' widths, and the ``tpu_custom_call`` instructions carry
 the names the trace readers look for. So does the serving engine's whole decode program,
-whose compiled form must write the KV pool in place (ISSUE 29). These are compiles, not
+whose compiled form must write the KV pool in place (ISSUE 29), and its chunk-append
+prefill program, whose attention is the flash forward (ISSUE 31). These are compiles, not
 runs: nothing here is a measurement.
 
 The topology is described inside a module-scoped fixture and only there (never at
@@ -157,6 +158,22 @@ def test_paged_attention_reads_its_layer_of_the_stacked_pool(one_chip, pool_dtyp
     assert pool_shaped(compiled) == []
 
 
+def serve_cfg(**over):
+    """The Mistral serve cell's model as the engine's programs see it (depth 16)."""
+    from accelerate_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=32000, d_model=4096, n_layers=LAYERS, n_heads=H, n_kv_heads=K,
+        head_dim_override=HD, d_ff=14336, max_seq=32768, sliding_window=WINDOW,
+        tie_embeddings=False, scan_layers=True, **over)
+
+
+def on_chip(make, sharding, dtype=None):
+    """The shapes of ``make()``'s tree, placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda x: shape(x.shape, dtype or x.dtype, sharding), jax.eval_shape(make))
+
+
 def test_decode_program_writes_the_pool_in_place(one_chip, monkeypatch):
     """``serving._decode_multi_step_paged`` at the Mistral serve cell's shapes (depth 16,
     4 steps a dispatch): in the compiled program the only instructions of the pool's
@@ -169,15 +186,9 @@ def test_decode_program_writes_the_pool_in_place(one_chip, monkeypatch):
 
     monkeypatch.setenv("ACCEL_PAGED_ATTN", "kernel")       # no chip here to choose it
     monkeypatch.setattr(paged_mod, "_interpret_default", lambda: False)
-    cfg = llama.LlamaConfig(
-        vocab_size=32000, d_model=4096, n_layers=LAYERS, n_heads=H, n_kv_heads=K,
-        head_dim_override=HD, d_ff=14336, max_seq=32768, sliding_window=WINDOW,
-        tie_embeddings=False, scan_layers=True)
-    on_chip = lambda tree, dtype=None: jax.tree_util.tree_map(  # noqa: E731
-        lambda x: shape(x.shape, dtype or x.dtype, one_chip), tree)
-    params = on_chip(jax.eval_shape(lambda: llama.init_params(cfg)), jnp.bfloat16)
-    cache = on_chip(jax.eval_shape(
-        lambda: llama.init_paged_cache(cfg, LANES, MAX_LEN, PAGES, PAGE)))
+    cfg = serve_cfg()
+    params = on_chip(lambda: llama.init_params(cfg), one_chip, jnp.bfloat16)
+    cache = on_chip(lambda: llama.init_paged_cache(cfg, LANES, MAX_LEN, PAGES, PAGE), one_chip)
     lanes = lambda dtype, *more: shape((LANES, *more), dtype, one_chip)  # noqa: E731
     compiled = serving._decode_multi_step_paged.lower(
         params, cache, lanes(jnp.int32, MAX_LEN // PAGE), lanes(jnp.int32),
@@ -188,6 +199,26 @@ def test_decode_program_writes_the_pool_in_place(one_chip, monkeypatch):
     assert pool_shaped(compiled) == ["fusion", "fusion", "scatter", "scatter"]
     plane = LAYERS * PAGES * PAGE * K * HD * 2
     assert compiled.memory_analysis().temp_size_in_bytes < plane // 2
+
+
+def test_prefill_chunk_program_takes_the_flash_forward(one_chip, monkeypatch):
+    """``serving._prefill_chunk_jit`` at the Mistral serve cell's shapes (depth 16, a
+    512-token chunk against the 8 192-slot row, window 4096; ISSUE 31): Mosaic accepts the
+    combination the prefill brings (a segment pair, traced offsets, a window, S != T), the
+    program holds ONE kernel named ``flash_fwd`` (the layer scan's), and no instruction has
+    the shape of ``_attention_cached``'s score tensor."""
+    from accelerate_tpu import serving
+    from accelerate_tpu.models import llama
+
+    monkeypatch.setattr(flash_mod, "_interpret_default", lambda: False)
+    cfg = serve_cfg(attn_impl="flash")                     # no chip here for auto to find
+    params = on_chip(lambda: llama.init_params(cfg), one_chip, jnp.bfloat16)
+    cache = on_chip(lambda: llama.init_cache(cfg, 1, MAX_LEN), one_chip)
+    compiled = serving._prefill_chunk_jit.lower(
+        params, shape((1, 512), jnp.int32, one_chip), shape((1, 512), jnp.bool_, one_chip),
+        cache, cfg=cfg).compile()
+    assert kernels(compiled) == ["flash_fwd"]
+    assert not re.search(rf"\[1,{K},{H // K},512,{MAX_LEN}\]", compiled.as_text())
 
 
 def test_mla_paged_attention_is_named(one_chip):
