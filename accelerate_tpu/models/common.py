@@ -235,6 +235,28 @@ def write_latent_paged(kv: dict, val: jax.Array, pages: jax.Array,
     return {"latent": pool.at[pages, offs].set(row)}
 
 
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a lane's ring for a sliding layer of ``window`` keys: the most pages a
+    window can touch (it need not start on a page boundary), and one to spare."""
+    return -(-(window + page_size - 1) // page_size) + 1
+
+
+def ring_tables(positions: jax.Array, max_pages: int, page_size: int,
+                window: int) -> jax.Array:
+    """The COMPUTED block tables [B, max_pages] of a sliding layer's ring pool ``[B ·
+    R, page_size, W]``: logical page ``j`` of lane ``b`` lies at ``b · R + j mod R`` while
+    it holds a key of the window that ends at ``positions[b]``, and is the sentinel (``B
+    · R``) otherwise — so :func:`paged_write_coords`, :func:`write_latent_paged` and
+    the paged kernels' walks serve a ring as they serve allocated pages, and no
+    allocator knows of it. A page that leaves the window is overwritten ``R`` pages on."""
+    B, R = positions.shape[0], ring_pages(window, page_size)
+    j = jnp.arange(max_pages, dtype=jnp.int32)[None, :]
+    first = jnp.maximum(positions - (window - 1), 0) // page_size
+    inside = (j >= first[:, None]) & (j <= (positions // page_size)[:, None])
+    lane = jnp.arange(B, dtype=jnp.int32)[:, None]
+    return jnp.where(inside, lane * R + j % R, jnp.int32(B * R))
+
+
 def paged_read_impl() -> str:
     """``"kernel"`` or ``"gather"``: which paged-attention read a decoder family takes
     — the Pallas kernel on a TPU backend (or when forced), else the gather through the

@@ -512,23 +512,36 @@ def _insert_row_paged(cache, row_cache, write_ids, slot, page_size: int,
     and the scatter drops them — a lane can never write a page it doesn't own. One
     compiled program serves every slot and row width (``slot`` is a traced scalar —
     unlike the dense ``_insert_row``'s per-slot static scatter, the paged layout
-    makes the lane index data)."""
+    makes the lane index data). A leaf named ``ring`` is the second kind of cache
+    state: a per-lane ring of pages outside the block tables, which takes the row's
+    last pages (the prompt's last window) whatever ``write_ids`` says."""
     MP = write_ids.shape[0]
+    lanes = cache["valid"].shape[0]
 
-    def put(pool, row):
+    def pages_of(r):                                             # [C, ...] → [MP, ps, ...]
+        pad = MP * page_size - r.shape[0]
+        r = jnp.pad(r, ((0, pad),) + ((0, 0),) * (r.ndim - 1))
+        return r.reshape(MP, page_size, *r.shape[1:])
+
+    def put(path, pool, row):
         if scan_layers:
             r = row[:, 0]                                        # [L, C, ...]
             pad = MP * page_size - r.shape[1]
             r = jnp.pad(r, ((0, 0), (0, pad)) + ((0, 0),) * (r.ndim - 2))
             r = r.reshape(r.shape[0], MP, page_size, *r.shape[2:])
             return pool.at[:, write_ids].set(r.astype(pool.dtype))
-        r = row[0]                                               # [C, ...]
-        pad = MP * page_size - r.shape[0]
-        r = jnp.pad(r, ((0, pad),) + ((0, 0),) * (r.ndim - 1))
-        r = r.reshape(MP, page_size, *r.shape[1:])
-        return pool.at[write_ids].set(r.astype(pool.dtype))
+        if getattr(path[-1], "key", None) == "ring":
+            # A sliding layer's per-lane ring [lanes * R, ps, ...] (models.common.
+            # ring_tables): land the row's last R logical pages, page j at slot * R +
+            # j mod R; pages before the row's start drop through the sentinel.
+            R = pool.shape[0] // lanes
+            j = (row_cache["index"] - 1) // page_size - (R - 1) + jnp.arange(R)
+            dst = jnp.where(j >= 0, slot * R + j % R, pool.shape[0])
+            return pool.at[dst].set(
+                pages_of(row[0])[jnp.clip(j, 0, MP - 1)].astype(pool.dtype))
+        return pool.at[write_ids].set(pages_of(row[0]).astype(pool.dtype))
 
-    layers = jax.tree_util.tree_map(put, cache["layers"], row_cache["layers"])
+    layers = jax.tree_util.tree_map_with_path(put, cache["layers"], row_cache["layers"])
     valid = jax.lax.dynamic_update_slice(
         cache["valid"], row_cache["valid"], (slot, 0)
     )
@@ -695,6 +708,15 @@ class ContinuousBatcher:
                     raise NotImplementedError(
                         f"{self.model.__name__} has no {fn}: the engine's {path} path "
                         f"calls it, so this model cannot be served that way")
+        # Cache state a lane keeps OUTSIDE the block tables (a sliding layer's ring of
+        # pages): a page list does not describe such a lane's cache.
+        lane_state = getattr(self.model, "lane_state_in_cache", None)
+        if role != "mixed" and lane_state is not None and lane_state(cfg):
+            raise NotImplementedError(
+                f"{self.model.__name__} keeps cache state per lane, outside the block "
+                f"tables (lane_state_in_cache): the engine's prefill/decode hand-off path "
+                f"(role={role!r}) moves a cache as a list of pages, so this model cannot "
+                "be served that way")
         self.max_slots = max_slots
         self.max_len = max_len
         self.prompt_bucket = prompt_bucket

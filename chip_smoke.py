@@ -562,6 +562,86 @@ def kernel_mla() -> None:
     check(not np.asarray(got[0], np.float32).any(), "mla paged attention: an empty lane emits zeros")
 
 
+def kernel_dsa_index() -> None:
+    """dsa_index_scores at dots3-note-prev's published widths (64 index heads of 128 over
+    an index-key pool) on 32 lanes of 0 to 25 000 keys of a 32 768-slot row, vs
+    dsa_index_scores_reference: the same scores, the same -inf outside each live range."""
+    from accelerate_tpu.models import dots3
+    from accelerate_tpu.ops.sparse_attention import (
+        dsa_index_scores, dsa_index_scores_reference)
+
+    cfg = dots3.Dots3Config()
+    Hi, Di, ps = cfg.index_heads, cfg.index_dim, PAGE_SIZE
+    B, C = 32, 32768
+    MP = C // ps
+    rng = np.random.default_rng(SEED + 9)
+    lens = np.concatenate([[0, 1, ps, 1023, 1024, 1025], rng.integers(8192, 25001, B - 6)])
+    P = int(sum(-(-int(n) // ps) for n in lens)) + 1
+    tables, valid = lane_tables(rng, lens, [min(b, int(n)) for b, n in enumerate(lens)],
+                                P, MP, ps)
+    kp, kq, kw_ = jax.random.split(jax.random.PRNGKey(SEED + 9), 3)
+    pool = jax.random.normal(kp, (P, ps, Di), jnp.bfloat16)
+    q = jax.random.normal(kq, (B, Hi, Di), jnp.bfloat16)
+    w = jax.random.normal(kw_, (B, Hi), jnp.float32) * (Hi * Di) ** -0.5
+    args = (q, w, pool, jnp.asarray(tables),
+            jnp.asarray(np.maximum(lens - 1, 0).astype(np.int32)), jnp.asarray(valid))
+    got = np.asarray(twice("dsa index", jax.jit(
+        lambda *a: dsa_index_scores(*a, page_size=ps, interpret=False)), *args))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(
+            lambda *a: dsa_index_scores_reference(*a, page_size=ps))(*args))
+    live = np.isfinite(want)
+    check(bool((np.isfinite(got) == live).all()) and not np.isnan(got).any(),
+          f"dsa index scores: -inf outside the live ranges ({int(live.sum())} live keys)")
+    check(not live[0].any() and not live[1].any(), "dsa index scores: an empty lane scores nothing")
+    close(np.where(live, got, 0.0), np.where(live, want, 0.0), TOL_BF16, "dsa index scores")
+
+
+def kernel_mla_window_and_gathered() -> None:
+    """mla_paged_attention in the two call shapes dots3-note-prev adds: a sliding layer's
+    widths (64 heads over latent rows of 1024 + 64) through a ring's computed table, and
+    a full layer's widths over a gathered buffer of 2048 rows a lane under an identity
+    table with lanes that hold fewer; vs mla_paged_attention_reference."""
+    from accelerate_tpu.models import deepseek, dots3
+    from accelerate_tpu.models.common import latent_width, ring_pages, ring_tables
+    from accelerate_tpu.ops.mla_attention import (
+        mla_paged_attention, mla_paged_attention_reference)
+
+    cfg = dots3.Dots3Config()
+    ps, B, C = PAGE_SIZE, 32, 32768
+    rng = np.random.default_rng(SEED + 10)
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 10), 6)
+
+    def both(name, q_lat, q_rope, pool, tables, positions, valid, spec):
+        kw = dict(page_size=ps, sm_scale=deepseek.sm_scale(spec))
+        args = (q_lat, q_rope, pool, tables, positions, valid)
+        got = twice(name, jax.jit(
+            lambda *a: mla_paged_attention(*a, interpret=False, **kw)), *args)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda *a: mla_paged_attention_reference(*a, **kw))(*args)
+        close(got, want, TOL_BF16, name)
+
+    sw = cfg.attn_spec(2)                               # a sliding layer
+    ring = ring_pages(sw.window, ps)
+    pos = jnp.asarray(np.concatenate([[0, 5, 512, 513, 8191], rng.integers(8192, C, B - 5)]),
+                      jnp.int32)
+    valid = jnp.arange(C)[None, :] <= pos[:, None]
+    seen = valid & (jnp.arange(C)[None, :] > pos[:, None] - sw.window)
+    both("mla over a ring", jax.random.normal(keys[0], (B, sw.n_heads, sw.kv_lora_rank), jnp.bfloat16),
+         jax.random.normal(keys[1], (B, sw.n_heads, sw.qk_rope_dim), jnp.bfloat16),
+         jax.random.normal(keys[2], (B * ring, ps, latent_width(sw.latent_dim)), jnp.bfloat16),
+         ring_tables(pos, C // ps, ps, sw.window), pos, seen, sw)
+
+    fl, K = cfg.attn_spec(0), cfg.index_topk            # a full layer's selected rows
+    held = jnp.asarray(np.concatenate([[0, 1, ps, 2047], np.full(B - 4, K)]), jnp.int32)
+    both("mla over gathered rows",
+         jax.random.normal(keys[3], (B, fl.n_heads, fl.kv_lora_rank), jnp.bfloat16),
+         jax.random.normal(keys[4], (B, fl.n_heads, fl.qk_rope_dim), jnp.bfloat16),
+         jax.random.normal(keys[5], (B * K // ps, ps, latent_width(fl.latent_dim)), jnp.bfloat16),
+         jnp.arange(B * K // ps, dtype=jnp.int32).reshape(B, -1),
+         jnp.full((B,), K - 1, jnp.int32), jnp.arange(K)[None, :] < held[:, None], fl)
+
+
 def kernel_xent(cfg) -> None:
     """fused_xent fwd / dx / dw at [2048, d_model] x [d_model, vocab] vs chunked_ce."""
     from accelerate_tpu.models.common import chunked_ce
@@ -670,6 +750,8 @@ def kernels(cfg, sizes: Sizes, compiles: Compiles, dry: bool) -> None:
     kernel_paged(cfg, sizes, quantized=True)
     kernel_paged_stacked()
     kernel_mla()
+    kernel_dsa_index()
+    kernel_mla_window_and_gathered()
     kernel_xent(cfg)
     kernel_adamw(cfg)
     kernel_int8_matmul(cfg)

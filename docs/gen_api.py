@@ -58,6 +58,7 @@ MODULES = [
     ("accelerate_tpu.ops.flash_attention", "Flash attention"),
     ("accelerate_tpu.ops.paged_attention", "Paged attention"),
     ("accelerate_tpu.ops.mla_attention", "Latent (MLA) paged attention"),
+    ("accelerate_tpu.ops.sparse_attention", "Sparse-attention indexer over paged index keys"),
     ("accelerate_tpu.ops.ring_attention", "Ring attention"),
     ("accelerate_tpu.ops.moe", "Mixture of experts"),
     ("accelerate_tpu.ops.fp8", "FP8"),
@@ -115,6 +116,7 @@ MODULES = [
     ("accelerate_tpu.commands.chaos_train", "Elastic training chaos bench (chaos-train)"),
     ("accelerate_tpu.models.llama", "Llama family"),
     ("accelerate_tpu.models.deepseek", "DeepSeek family (latent attention, routed experts)"),
+    ("accelerate_tpu.models.dots3", "dots3 family (full and sliding latent layers, sparse selection)"),
     ("accelerate_tpu.models.lora", "LoRA fine-tuning"),
     ("accelerate_tpu.models.gpt", "GPT family"),
     ("accelerate_tpu.models.t5", "T5 family"),
