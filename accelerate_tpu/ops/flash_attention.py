@@ -12,8 +12,8 @@ its whole query group in VMEM — repeated K/V never exist in HBM. Sequence leng
 to block multiples; padded keys are masked via global column indices, padded query rows
 sliced off by the wrapper.
 
-**Position offsets**: the kernels take traced ``q_offset``/``kv_offset`` scalars (SMEM) giving
-the global position of the local block — this is what lets ``ops/ring_attention.py`` reuse
+**Position offsets**: the kernels take traced ``q_offset``/``kv_offset`` scalars giving the
+global position of the local block — this is what lets ``ops/ring_attention.py`` reuse
 these exact kernels per ring step with correct cross-device causal masking. The raw ``_fwd`` /
 ``_bwd_dq`` / ``_bwd_dkv`` entry points (returning/consuming lse and delta) are the building
 blocks for the ring; ``flash_attention`` is the single-device public API. The serving
@@ -21,6 +21,25 @@ prefill is the offsets' second caller (``models/common.py::cached_prefill_attent
 ``_flash_bhsd_offset``): a chunk of queries at ``q_offset`` = the cache's write index against
 the band of its row cache at ``kv_offset``, S != T, the cache's valid mask as the
 ``(q_seg, kv_seg)`` pair — the forward kernel alone, named ``flash_fwd`` in the prefill programs.
+
+**The grids walk the band, not the rectangle** (``_BandWalk``). Under ``causal`` AND a
+``window`` an outer tile needs at most ⌈(window + block − 2) / block⌉ + 1 inner tiles
+(10 of 16 at 8192 tokens under a 4096 window with tiles of 512), and that — static — is
+the inner grid dimension's extent: the kv tiles of a q tile in ``flash_fwd`` and
+``flash_bwd_dq``, ``reps ×`` the q tiles of a kv tile in ``flash_bwd_dkv``. The two offsets
+are ONE scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index
+maps place each walk on the band: step ``t`` stands for tile
+``last(outer, offsets) − (extent − 1) + t``, and the kernel derives the same tile index
+for its masks. Tiles outside the band are not visited. A step that still falls outside
+it (a causal triangle's first rows, the band's ragged ends, a ring step whose whole kv
+block lies in the future or behind the window; with one bound only the extent stays the
+rectangle's) comes BEFORE the walk's needed steps and asks for the first block they
+need: the pipeline holds it when they come to it, so such a step fetches nothing and
+computes nothing, and every walk's last step computes — the next walk's first blocks
+arrive behind it (on a TPU v5e a walk that ended on idle steps stalled on them: 3.5 ms
+of a 25.8 ms dq call at [4, 32, 8192, 128]). Init and finalize stay on the walk's first
+and last step, so an outer tile with NO needed step still writes zeros (``lse`` =
+``_NEG_INF``) — the ring merges those.
 
 TPU-specific structure (the same three choices the official jax flash kernel makes):
 
@@ -32,11 +51,12 @@ TPU-specific structure (the same three choices the official jax flash kernel mak
 - **Mask-free interior tiles.** For causal attention only the tiles the diagonal actually
   crosses need the iota row/col mask; tiles entirely below the diagonal (the majority at
   long S) skip mask construction, the select, and the zero-fill entirely — splash-attention
-  style tile classing, decided per grid step from the SMEM offsets.
+  style tile classing, decided per grid step from the prefetched offsets.
 - **Grid semantics + cost estimate.** (batch, head, q-block) grid dimensions are declared
   PARALLEL (only the kv dimension carries scratch state and stays ARBITRARY), and each
-  ``pallas_call`` carries a ``pl.CostEstimate`` so XLA's scheduler sees the real arithmetic
-  intensity. ``ACCEL_FLASH_DIMSEM=0`` disables the semantics for A/B measurement.
+  ``pallas_call`` carries a ``pl.CostEstimate`` that counts the band's tiles, so XLA's
+  scheduler sees the real arithmetic intensity. ``ACCEL_FLASH_DIMSEM=0`` disables the
+  semantics for A/B measurement.
 
 Runs in interpreter mode on CPU (tests) and compiled on TPU. Block sizes default to 512×512
 (see ``_DEFAULT_BLOCK_Q/K``); hd should be a multiple of 128 for peak efficiency (llama3:
@@ -52,6 +72,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -104,12 +125,69 @@ def _cost(flops: float, bytes_accessed: float, transcendentals: float):
     )
 
 
-def _scalar(x) -> jax.Array:
-    return jnp.asarray(x, dtype=jnp.int32).reshape(1, 1)
+def _offsets(q_offset, kv_offset) -> jax.Array:
+    """The two traced positions as ONE int32[2] scalar-prefetch operand: the index maps read
+    them (to place each walk on the band) before the kernel body does."""
+    return jnp.stack([jnp.asarray(q_offset, jnp.int32).reshape(()),
+                      jnp.asarray(kv_offset, jnp.int32).reshape(())])
 
 
-def _smem_scalar_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
+class _BandWalk:
+    """How an inner grid dimension walks the band. An outer tile at positions
+    p .. p + block_outer - 1 pairs with the inner positions p - back ..
+    p + block_outer - 1 + ahead (``None``: no bound on that side). For the kv tiles of a q
+    tile ``back`` is the window's reach and ``ahead`` the causal 0; for the q tiles of a kv
+    tile it is the other way round.
+
+    ``extent`` — static — is the most inner tiles one outer tile can touch: the inner grid
+    dimension's size (``n_inner`` unless both sides are bounded). The walk ENDS on the last
+    tile the band touches, so the steps it has to spare come first and its last step
+    computes: the pipeline fetches the next walk's first block behind that."""
+
+    def __init__(self, back, ahead, block_outer, block_inner, n_inner):
+        self.back, self.ahead = back, ahead
+        self.block_outer, self.block_inner, self.n_inner = block_outer, block_inner, n_inner
+        self.extent = n_inner
+        if back is not None and ahead is not None:
+            self.extent = min(n_inner, -(-(back + ahead + block_outer - 1) // block_inner) + 1)
+
+    def _span(self, p, xp):
+        """First and last inner tile the band of the outer tile at ``p`` touches, each
+        clamped into the array (``xp=np`` counts statically)."""
+        def tile(position):
+            return xp.minimum(xp.maximum(position, 0) // self.block_inner, self.n_inner - 1)
+        first = 0 if self.back is None else tile(p - self.back)
+        last = self.n_inner - 1 if self.ahead is None else tile(
+            p + self.block_outer - 1 + self.ahead)
+        return first, last
+
+    def step(self, p, t):
+        """``(tile, fetch)`` of step ``t`` for an outer tile whose first position is ``p`` in
+        the inner array's own coordinates (traced: the offsets are). ``tile`` is what the
+        step stands for; before the band's first (or negative) it is not needed, and the
+        index maps ask for ``fetch`` — the first tile the walk does need, which the
+        pipeline then already holds when it comes to it: such a step copies nothing."""
+        first, last = self._span(p, jnp)
+        tile = last - (self.extent - 1) + t
+        return tile, jnp.maximum(tile, first)
+
+    def fetched(self, n_outer, shift):
+        """(outer, inner) tile pairs the walks fetch when outer position 0 sits at inner
+        position ``shift`` — the cost estimates' count; the real offsets are traced."""
+        first, last = self._span(np.arange(n_outer) * self.block_outer + shift, np)
+        return int(np.broadcast_to(np.maximum(last - first + 1, 1), (n_outer,)).sum())
+
+
+def _kv_walk(causal, window, block_q, block_k, nk):
+    """The kv tiles one q tile needs (forward, dq): behind it by the window, ahead by none."""
+    return _BandWalk(window - 1 if window else None, 0 if causal else None,
+                     block_q, block_k, nk)
+
+
+def _q_walk(causal, window, block_q, block_k, nq):
+    """The q tiles one kv tile needs (dk/dv): none behind it, the window ahead."""
+    return _BandWalk(0 if causal else None, window - 1 if window else None,
+                     block_k, block_q, nq)
 
 
 def _tile_mask(*, causal, window, has_segments, kv_pad, block_q, block_k,
@@ -141,8 +219,9 @@ def _tile_mask(*, causal, window, has_segments, kv_pad, block_q, block_k,
 
 # ------------------------------------------------------------------------------ forward
 def _fwd_kernel(
-    q_off_ref, kv_off_ref, *refs,
+    offs_ref, *refs,
     sm_scale, causal, block_q, block_k, kv_len, kv_pad, has_segments, window, softcap,
+    walk,
 ):
     if has_segments:
         (q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,
@@ -151,29 +230,30 @@ def _fwd_kernel(
         q_seg_ref = kv_seg_ref = None
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     i = pl.program_id(2)  # q block
-    j = pl.program_id(3)  # kv block
-    nk = pl.num_programs(3)
+    t = pl.program_id(3)  # step of the walk over this q block's band of kv blocks
+    nt = pl.num_programs(3)
 
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q_start = i * block_q
+    q_off = offs_ref[0]
+    kv_off = offs_ref[1]
+    j, _ = walk.step(q_off + q_start - kv_off, t)   # kv block, by the index maps' arithmetic
     k_start = j * block_k
-    q_off = q_off_ref[0, 0]
-    kv_off = kv_off_ref[0, 0]
     q_global = q_off + q_start        # global position of this tile's first row
     k_global = kv_off + k_start       # global position of this tile's first col
-    # Causal: skip kv tiles strictly above the diagonal band (in global positions).
-    needed = jnp.logical_or(
-        jnp.asarray(not causal), k_global <= q_global + block_q - 1
+    # The walk ends on the band's last kv tile, but its extent is static and the band is
+    # not: its first steps may stand before the array or BELOW the band (col <= row -
+    # window for every pair in the tile), and a kv array wholly in the future leaves steps
+    # above the diagonal (causal, in global positions). They fetch and compute nothing.
+    needed = jnp.logical_and(
+        j >= 0, jnp.logical_or(jnp.asarray(not causal), k_global <= q_global + block_q - 1)
     )
     if window:
-        # Sliding window: also skip kv tiles entirely BELOW the band (col <= row - window
-        # for every pair in the tile) — long-context Mistral-style attention never touches
-        # those tiles at all.
         needed = jnp.logical_and(needed, k_global + block_k - 1 > q_global - window)
 
     # Tile classing: interior tiles (diagonal doesn't cross, window band doesn't clip,
@@ -232,7 +312,7 @@ def _fwd_kernel(
         )
         _accumulate(_scores(), mask)
 
-    @pl.when(j == nk - 1)
+    @pl.when(t == nt - 1)
     def _finalize():
         l = l_ref[:]                                        # [bq, LANES] replicated
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -262,6 +342,30 @@ def _seg_blocks(segments, Sp, Tp):
     return q_seg[:, :, None], kv_seg[:, None, :]
 
 
+def _q_major_maps(walk, reps, block_q, block_k, segments, Sp, Tp):
+    """Index maps of the kernels whose grid is (b, q head, q block, step of the kv walk) —
+    forward and dq: the q side's, the kv side's (GQA resolved here; a step outside the
+    band asks for the walk's ``fetch``), and the segment ids' specs and arrays riding the
+    same maps."""
+
+    def q_map(b, h, i, t, offs):
+        return (b, h, i, 0)
+
+    def kv_tile(i, t, offs):
+        return walk.step(offs[0] + i * block_q - offs[1], t)[1]
+
+    def kv_map(b, h, i, t, offs):
+        return (b, h // reps, kv_tile(i, t, offs), 0)
+
+    if segments is None:
+        return q_map, kv_map, [], []
+    seg_specs = [
+        pl.BlockSpec((None, block_q, 1), lambda b, h, i, t, offs: (b, i, 0)),
+        pl.BlockSpec((None, 1, block_k), lambda b, h, i, t, offs: (b, 0, kv_tile(i, t, offs))),
+    ]
+    return q_map, kv_map, seg_specs, list(_seg_blocks(segments, Sp, Tp))
+
+
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_offset=0,
          segments=None, window=0, softcap=0.0):
     """Raw forward: q [B,H,S,hd], k/v [B,K,T,hd] (K divides H — GQA resolved IN the BlockSpec
@@ -278,63 +382,61 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_
     k = _pad_seq(k, Tp)
     v = _pad_seq(v, Tp)
     has_segments = segments is not None
+    walk = _kv_walk(causal, window, block_q, block_k, nk)
 
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k, kv_len=T,
         kv_pad=(Tp != T), has_segments=has_segments, window=window, softcap=softcap,
+        walk=walk,
     )
-    seg_specs, seg_args = [], []
-    if has_segments:
-        q_seg, kv_seg = _seg_blocks(segments, Sp, Tp)
-        seg_specs = [
-            pl.BlockSpec((None, block_q, 1), lambda b, h, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, block_k), lambda b, h, i, j: (b, 0, j)),
-        ]
-        seg_args = [q_seg, kv_seg]
-    # fwd cost: qk^T + pv dots (causal ≈ half the tiles), exp over the score tiles.
-    dot_flops = 4 * B * H * Sp * Tp * hd * (0.5 if causal else 1.0)
+    q_map, kv_map, seg_specs, seg_args = _q_major_maps(
+        walk, reps, block_q, block_k, segments, Sp, Tp)
+    # fwd cost: the qk^T + pv dots and the exp of the band's tiles; K and V once a tile.
+    tiles = B * H * walk.fetched(nq, T - S)
     o, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",
-        grid=(B, H, nq, nk),
-        in_specs=[
-            _smem_scalar_spec(),
-            _smem_scalar_spec(),
-            *seg_specs,
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h // reps, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h // reps, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES), lambda b, h, i, j: (b, h, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, nq, walk.extent),
+            in_specs=[
+                *seg_specs,
+                pl.BlockSpec((1, 1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_q, _LANES), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, hd), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sp, hd), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sp, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
         compiler_params=_dim_semantics(3, 1),
         cost_estimate=_cost(
-            dot_flops,
-            q.size * q.dtype.itemsize + (k.size + v.size) * k.dtype.itemsize * reps
-            + B * H * Sp * hd * q.dtype.itemsize,
-            B * H * Sp * Tp * (0.5 if causal else 1.0),
+            4 * tiles * block_q * block_k * hd,
+            2 * q.size * q.dtype.itemsize + B * H * Sp * _LANES * 4
+            + 2 * tiles * block_k * hd * k.dtype.itemsize,
+            tiles * block_q * block_k,
         ),
         interpret=interpret,
-    )(_scalar(q_offset), _scalar(kv_offset), *seg_args, q, k, v)
+    )(_offsets(q_offset, kv_offset), *seg_args, q, k, v)
     return o[:, :, :S], lse[:, :, :S, 0]
 
 
 # ------------------------------------------------------------------------------ backward
 def _bwd_dq_kernel(
-    q_off_ref, kv_off_ref, *refs,
+    offs_ref, *refs,
     sm_scale, causal, block_q, block_k, kv_len, kv_pad, has_segments, window, softcap,
+    walk,
 ):
     if has_segments:
         (q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -343,21 +445,22 @@ def _bwd_dq_kernel(
         q_seg_ref = kv_seg_ref = None
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
     i = pl.program_id(2)
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
+    t = pl.program_id(3)  # step of the walk over this q block's band, as in the forward
+    nt = pl.num_programs(3)
 
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     q_start = i * block_q
+    q_off = offs_ref[0]
+    kv_off = offs_ref[1]
+    j, _ = walk.step(q_off + q_start - kv_off, t)
     k_start = j * block_k
-    q_off = q_off_ref[0, 0]
-    kv_off = kv_off_ref[0, 0]
     q_global = q_off + q_start
     k_global = kv_off + k_start
-    needed = jnp.logical_or(
-        jnp.asarray(not causal), k_global <= q_global + block_q - 1
+    needed = jnp.logical_and(
+        j >= 0, jnp.logical_or(jnp.asarray(not causal), k_global <= q_global + block_q - 1)
     )
     if window:
         needed = jnp.logical_and(needed, k_global + block_k - 1 > q_global - window)
@@ -406,14 +509,14 @@ def _bwd_dq_kernel(
             k_local=k_start, kv_len=kv_len, q_seg_ref=q_seg_ref, kv_seg_ref=kv_seg_ref,
         ))
 
-    @pl.when(j == nk - 1)
+    @pl.when(t == nt - 1)
     def _finalize():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
-    q_off_ref, kv_off_ref, *refs,
-    sm_scale, causal, block_q, block_k, kv_len, kv_pad, q_len, q_pad, nq,
+    offs_ref, *refs,
+    sm_scale, causal, block_q, block_k, kv_len, kv_pad, q_len, q_pad, walk,
     has_segments, window, softcap,
 ):
     if has_segments:
@@ -424,25 +527,26 @@ def _bwd_dkv_kernel(
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
     j = pl.program_id(2)  # kv block (outer)
-    # Inner dim walks (GQA group rep, q block) pairs: g = r*nq + i. dk/dv for one kv head
-    # accumulate over every q head in its group, entirely in VMEM scratch.
+    # Inner dim walks (GQA group rep, step of the walk over this kv block's band of q
+    # blocks) pairs: g = r*extent + t. dk/dv for one kv head accumulate over every q head in
+    # its group, entirely in VMEM scratch.
     g = pl.program_id(3)
-    ni = pl.num_programs(3)
-    i = jax.lax.rem(g, nq)
+    ng = pl.num_programs(3)
 
     @pl.when(g == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = i * block_q
     k_start = j * block_k
-    q_off = q_off_ref[0, 0]
-    kv_off = kv_off_ref[0, 0]
+    q_off = offs_ref[0]
+    kv_off = offs_ref[1]
+    i, _ = walk.step(kv_off + k_start - q_off, jax.lax.rem(g, walk.extent))   # q block
+    q_start = i * block_q
     q_global = q_off + q_start
     k_global = kv_off + k_start
-    needed = jnp.logical_or(
-        jnp.asarray(not causal), q_global + block_q - 1 >= k_global
+    needed = jnp.logical_and(
+        i >= 0, jnp.logical_or(jnp.asarray(not causal), q_global + block_q - 1 >= k_global)
     )
     if window:
         needed = jnp.logical_and(needed, k_global + block_k - 1 > q_global - window)
@@ -507,7 +611,7 @@ def _bwd_dkv_kernel(
     def _compute_masked():
         _compute(_mask_with_qpad())
 
-    @pl.when(g == ni - 1)
+    @pl.when(g == ng - 1)
     def _finalize():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -535,48 +639,44 @@ def _bwd_dq(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interpr
     lsep = _rep_lanes(lse, Sp)
     deltap = _rep_lanes(delta, Sp)
     has_segments = segments is not None
-    seg_specs, seg_args = [], []
-    if has_segments:
-        q_seg, kv_seg = _seg_blocks(segments, Sp, Tp)
-        seg_specs = [
-            pl.BlockSpec((None, block_q, 1), lambda b, h, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, block_k), lambda b, h, i, j: (b, 0, j)),
-        ]
-        seg_args = [q_seg, kv_seg]
+    walk = _kv_walk(causal, window, block_q, block_k, nk)
+    q_map, kv_map, seg_specs, seg_args = _q_major_maps(
+        walk, reps, block_q, block_k, segments, Sp, Tp)
     kernel = functools.partial(
         _bwd_dq_kernel,
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k, kv_len=T,
         kv_pad=(Tp != T), has_segments=has_segments, window=window, softcap=softcap,
+        walk=walk,
     )
-    dot_flops = 8 * B * H * Sp * Tp * hd * (0.5 if causal else 1.0)
+    tiles = B * H * walk.fetched(nq, T - S)
     dq = pl.pallas_call(
         kernel,
         name="flash_bwd_dq",
-        grid=(B, H, nq, nk),
-        in_specs=[
-            _smem_scalar_spec(),
-            _smem_scalar_spec(),
-            *seg_specs,
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h // reps, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h // reps, j, 0)),
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES), lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, nq, walk.extent),
+            in_specs=[
+                *seg_specs,
+                pl.BlockSpec((1, 1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_q, _LANES), q_map),
+                pl.BlockSpec((1, 1, block_q, _LANES), q_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, hd), q_map),
+            scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, H, Sp, hd), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         compiler_params=_dim_semantics(3, 1),
         cost_estimate=_cost(
-            dot_flops,
-            (qp.size + dop.size) * q.dtype.itemsize
-            + (kp.size + vp.size) * k.dtype.itemsize * reps
-            + B * H * Sp * hd * 4,
-            B * H * Sp * Tp * (0.5 if causal else 1.0),
+            6 * tiles * block_q * block_k * hd,
+            (qp.size + dop.size) * q.dtype.itemsize + (lsep.size + deltap.size) * 4
+            + B * H * Sp * hd * 4 + 2 * tiles * block_k * hd * k.dtype.itemsize,
+            tiles * block_q * block_k,
         ),
         interpret=interpret,
-    )(_scalar(q_offset), _scalar(kv_offset), *seg_args, qp, kp, vp, dop, lsep, deltap)
+    )(_offsets(q_offset, kv_offset), *seg_args, qp, kp, vp, dop, lsep, deltap)
     return dq[:, :, :S]
 
 
@@ -584,9 +684,10 @@ def _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interp
              q_offset=0, kv_offset=0, segments=None, window=0, softcap=0.0):
     """(dk, dv) [B,K,T,hd] for one kv block against local q (ring building block).
 
-    GQA: the inner grid dim runs ``reps * nq`` steps — every (q head in the kv head's
-    group, q block) pair — so each kv head's gradient accumulates over its whole group in
-    VMEM scratch, without materializing per-q-head dk/dv."""
+    GQA: the inner grid dim runs ``reps ×`` the q walk's extent (``nq`` without both a
+    window and ``causal``) — every (q head in the kv head's group, step over the q blocks
+    this kv block's band touches) pair — so each kv head's gradient accumulates over its
+    whole group in VMEM scratch, without materializing per-q-head dk/dv."""
     B, H, S, hd = q.shape
     K = k.shape[1]
     reps = H // K
@@ -599,59 +700,71 @@ def _bwd_dkv(q, k, v, do, lse, delta, causal, sm_scale, block_q, block_k, interp
     lsep = _rep_lanes(lse, Sp)
     deltap = _rep_lanes(delta, Sp)
     has_segments = segments is not None
+    walk = _q_walk(causal, window, block_q, block_k, nq)
+    nt = walk.extent
+
+    # Grid order here is (b, kh, j, g): kv block outer, (group rep, step of the q walk)
+    # inner. A step outside the band asks for the walk's ``fetch``, as in ``_q_major_maps``.
+    def q_tile(j, g, offs):
+        return walk.step(offs[1] + j * block_k - offs[0], g % nt)[1]
+
+    def q_map(b, kh, j, g, offs):
+        return (b, kh * reps + g // nt, q_tile(j, g, offs), 0)
+
+    def kv_map(b, kh, j, g, offs):
+        return (b, kh, j, 0)
+
     seg_specs, seg_args = [], []
     if has_segments:
-        q_seg, kv_seg = _seg_blocks(segments, Sp, Tp)
-        # Grid order here is (b, kh, j, g): kv block outer, (group rep, q block) inner.
         seg_specs = [
-            pl.BlockSpec((None, block_q, 1), lambda b, kh, j, g: (b, g % nq, 0)),
-            pl.BlockSpec((None, 1, block_k), lambda b, kh, j, g: (b, 0, j)),
+            pl.BlockSpec((None, block_q, 1), lambda b, kh, j, g, offs: (b, q_tile(j, g, offs), 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, kh, j, g, offs: (b, 0, j)),
         ]
-        seg_args = [q_seg, kv_seg]
+        seg_args = list(_seg_blocks(segments, Sp, Tp))
     kernel = functools.partial(
         _bwd_dkv_kernel,
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
-        kv_len=T, kv_pad=(Tp != T), q_len=S, q_pad=(Sp != S), nq=nq,
+        kv_len=T, kv_pad=(Tp != T), q_len=S, q_pad=(Sp != S), walk=walk,
         has_segments=has_segments, window=window, softcap=softcap,
     )
-    dot_flops = 10 * B * H * Sp * Tp * hd * (0.5 if causal else 1.0)
+    tiles = B * H * walk.fetched(nk, S - T)
     dk, dv = pl.pallas_call(
         kernel,
         name="flash_bwd_dkv",
-        grid=(B, K, nk, reps * nq),
-        in_specs=[
-            _smem_scalar_spec(),
-            _smem_scalar_spec(),
-            *seg_specs,
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, kh, j, g: (b, kh * reps + g // nq, g % nq, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, kh, j, g: (b, kh, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, kh, j, g: (b, kh, j, 0)),
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, kh, j, g: (b, kh * reps + g // nq, g % nq, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES), lambda b, kh, j, g: (b, kh * reps + g // nq, g % nq, 0)),
-            pl.BlockSpec((1, 1, block_q, _LANES), lambda b, kh, j, g: (b, kh * reps + g // nq, g % nq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, kh, j, g: (b, kh, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, kh, j, g: (b, kh, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, K, nk, reps * nt),
+            in_specs=[
+                *seg_specs,
+                pl.BlockSpec((1, 1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_q, _LANES), q_map),
+                pl.BlockSpec((1, 1, block_q, _LANES), q_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_k, hd), kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, hd), jnp.float32),
+                pltpu.VMEM((block_k, hd), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B, K, Tp, hd), jnp.float32),
             jax.ShapeDtypeStruct((B, K, Tp, hd), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, hd), jnp.float32),
-            pltpu.VMEM((block_k, hd), jnp.float32),
-        ],
         compiler_params=_dim_semantics(3, 1),
         cost_estimate=_cost(
-            dot_flops,
-            (qp.size + dop.size) * q.dtype.itemsize
-            + (kp.size + vp.size) * k.dtype.itemsize
-            + 2 * B * K * Tp * hd * 4,
-            B * H * Sp * Tp * (0.5 if causal else 1.0),
+            8 * tiles * block_q * block_k * hd,
+            (kp.size + vp.size) * k.dtype.itemsize + 2 * B * K * Tp * hd * 4
+            + 2 * tiles * block_q * (hd * q.dtype.itemsize + _LANES * 4),
+            tiles * block_q * block_k,
         ),
         interpret=interpret,
-    )(_scalar(q_offset), _scalar(kv_offset), *seg_args, qp, kp, vp, dop, lsep, deltap)
+    )(_offsets(q_offset, kv_offset), *seg_args, qp, kp, vp, dop, lsep, deltap)
     return dk[:, :, :T], dv[:, :, :T]
 
 
@@ -779,8 +892,9 @@ def flash_attention(
     Requires self-attention shapes (T == S).
 
     ``window`` > 0 adds Mistral-style sliding-window masking (position i attends
-    (i-window, i]): kv tiles entirely outside the band are SKIPPED, not just masked, so
-    long-context compute scales with S·window instead of S².
+    (i-window, i]): kv tiles entirely outside the band are NOT VISITED — the kernels' grids
+    walk the band's tiles only, and a grid step left outside it fetches nothing — so
+    long-context compute and traffic scale with S·window instead of S².
 
     ``softcap`` > 0 applies Gemma-style score capping cap·tanh(s/cap) in-kernel, with the
     exact chain rule (1 − tanh²) in both backward kernels — Gemma-2 trains on the flash

@@ -1,9 +1,10 @@
 """Sliding-window (Mistral-style) attention: kernel band masking, model wiring, decode.
 
-The flash kernels SKIP kv tiles outside the (i-window, i] band — these tests pin the
-numerics against an explicitly-masked XLA reference, including gradients (the skipped
-tiles must contribute exactly zero), the model forward (flash vs xla impl parity), and
-the KV-cache decode path (windowed cached logits == windowed uncached logits).
+The flash kernels' grids do not VISIT kv tiles outside the (i-window, i] band — these
+tests pin the numerics against an explicitly-masked XLA reference, including gradients
+(the tiles left out must contribute exactly zero), the grids' extents at the train cell's
+shapes, the model forward (flash vs xla impl parity), and the KV-cache decode path
+(windowed cached logits == windowed uncached logits).
 """
 
 import dataclasses
@@ -38,13 +39,18 @@ def _ref_attention(q, k, v, mask):
     return jnp.einsum("bhst,bthd->bshd", p, v)
 
 
-@pytest.mark.parametrize("S,window", [(96, 24), (128, 64), (64, 200)])
-def test_flash_window_matches_masked_reference(S, window):
+@pytest.mark.parametrize("S,window,block", [
+    (96, 24, None), (128, 64, None), (64, 200, None),
+    # several tiles a row, so the kv walk is shorter than the row of tiles: the window a
+    # multiple of the tile, not a multiple with a ragged S, and narrower than one tile
+    (1024, 256, 128), (640, 130, 128), (700, 50, 64),
+])
+def test_flash_window_matches_masked_reference(S, window, block):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(2, S, 4, 32)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, S, 2, 32)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(2, S, 2, 32)), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, window=window)
+    out = flash_attention(q, k, v, causal=True, window=window, block_q=block, block_k=block)
     ref = _ref_attention(q, k, v, _band_mask(S, window))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
@@ -70,6 +76,44 @@ def test_flash_window_gradients_match():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-3, err_msg=f"d{name}"
         )
+
+
+def _pallas_grids(jaxpr, found=None):
+    """{kernel name: grid} of every ``pallas_call`` in a jaxpr, nested ones included."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_grids(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("causal,window,fwd_dq,dkv", [
+    (True, 4096, 10, 4 * 10),     # the band: 9 tiles, + 1 because the offsets are traced
+    (True, 0, 16, 4 * 16),        # a triangle's widest row is the whole rectangle
+    (False, 4096, 16, 4 * 16),    # a window alone bounds one side only
+    (False, 0, 16, 4 * 16),
+], ids=["causal_window", "causal", "window", "neither"])
+def test_grids_walk_the_band_at_the_train_cell_shapes(causal, window, fwd_dq, dkv):
+    """The counter that says the mechanism engages, read where it is static: the inner
+    extent of each kernel's grid at [4, 8192] x 32 q / 8 kv heads, tiles of 512 (tracing
+    only: nothing runs). The rectangle had 16 and 4 x 16 whatever the band."""
+    B, S, H, K, hd = 4, 8192, 32, 8, 128
+    q = jax.ShapeDtypeStruct((B, S, H, hd), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, K, hd), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=512, block_k=512).astype(jnp.float32).sum()
+
+    grids = _pallas_grids(
+        jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr)
+    assert grids == {
+        "flash_fwd": (B, H, 16, fwd_dq),
+        "flash_bwd_dq": (B, H, 16, fwd_dq),
+        "flash_bwd_dkv": (B, K, 16, dkv),
+    }
 
 
 def test_model_forward_flash_equals_xla():
