@@ -603,7 +603,8 @@ def _local_attn_impl(impl: str) -> str:
 
 
 def cached_prefill_attention(q, ck, cv, index, valid, *, impl: str, sm_scale: float,
-                             window: int = 0, softcap: float = 0.0, xla_attention):
+                             window: int = 0, softcap: float = 0.0, xla_attention,
+                             select=None):
     """Family-shared attention of a cached forward's queries q [B,T,H,hd] against the row
     cache ck/cv [B,C,K,hd] they were just written into at slots ``index .. index+T-1``
     (slot j IS position j; ``valid`` [B,C] marks the live slots).
@@ -629,7 +630,12 @@ def cached_prefill_attention(q, ck, cv, index, valid, *, impl: str, sm_scale: fl
     ``col ≤ row`` / ``col > row − window`` on global positions the causal band. Equal to
     ``xla_attention()`` on every query row with a live key; a row with none (a left-pad
     position, which no live query attends) reads zeros where XLA reads a mean. A kernel
-    the compiler refuses is an error at the call, never a quiet switch."""
+    the compiler refuses is an error at the call, never a quiet switch.
+
+    ``select`` [B,T,C] bool (a learned sparse selection: the slots each query keeps, the
+    same for every head) rides into the kernel as its per-pair mask — the kernel's
+    ``flash_fwd_masked`` specialisation; a call without it builds the kernel it built
+    before. ``xla_attention()`` has to apply the same selection itself."""
     T, C = q.shape[1], ck.shape[1]
     if jnp.ndim(index) or T % 128 or _local_attn_impl(impl) != "flash":
         return xla_attention()
@@ -640,15 +646,20 @@ def cached_prefill_attention(q, ck, cv, index, valid, *, impl: str, sm_scale: fl
     ck, cv, valid = (
         jax.lax.dynamic_slice_in_dim(a, start, span, axis=1) for a in (ck, cv, valid)
     )
+    rows = (valid,)
+    if select is not None:
+        rows += (jax.lax.dynamic_slice_in_dim(select, start, span, axis=2).astype(jnp.int8),)
 
-    def local(q, k, v, valid, index, start):
+    def local(q, k, v, valid, *rest):
+        *pair, index, start = rest
         return _flash_bhsd_offset(
             q, k, v, q_offset=index, kv_offset=start, causal=True, sm_scale=sm_scale,
             window=window, softcap=softcap,
             segments=(jnp.ones(q.shape[:2], jnp.int32), valid.astype(jnp.int32)),
+            mask=pair[0] if pair else None,
         )
 
-    return _mosaic_sharded(local, q, ck, cv, rows=(valid,), scalars=(index, start))
+    return _mosaic_sharded(local, q, ck, cv, rows=rows, scalars=(index, start))
 
 
 def attention_dispatch(q, k, v, mask, *, impl: str, sm_scale: float, window: int = 0,

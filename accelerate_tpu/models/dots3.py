@@ -87,6 +87,7 @@ class Dots3Config:
 
     scan_layers: ClassVar[bool] = False
     counts_attention: ClassVar[bool] = True
+    router: ClassVar[str] = "sigmoid_grouped"
     n_group: ClassVar[int] = 1        # the router has no groups
     topk_group: ClassVar[int] = 1
 

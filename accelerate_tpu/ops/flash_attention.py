@@ -22,6 +22,15 @@ prefill is the offsets' second caller (``models/common.py::cached_prefill_attent
 the band of its row cache at ``kv_offset``, S != T, the cache's valid mask as the
 ``(q_seg, kv_seg)`` pair — the forward kernel alone, named ``flash_fwd`` in the prefill programs.
 
+**A per-pair mask** (``_fwd(..., mask=)``, through ``_flash_bhsd_offset``): an int8 [B, S, T]
+operand, non-zero where query ``s`` may attend key ``t``, shared by every head — a learned
+sparse selection's per-query key set in a serving prefill chunk (``models/deepseek.py``, the
+grouped-query kind). It is a STATIC specialisation of the forward kernel builder, as
+``window`` and ``has_segments`` are: a call without it builds the kernel it built before,
+under the name it had; a call with it builds ``flash_fwd_masked`` — one more [block_q,
+block_k] operand on the kv walk's index map, ANDed into the tile mask, and no tile is
+interior. Forward only (no VJP): prefill does not differentiate.
+
 **The grids walk the band, not the rectangle** (``_BandWalk``). Under ``causal`` AND a
 ``window`` an outer tile needs at most ⌈(window + block − 2) / block⌉ + 1 inner tiles
 (10 of 16 at 8192 tokens under a 4096 window with tiles of 512), and that — static — is
@@ -221,14 +230,12 @@ def _tile_mask(*, causal, window, has_segments, kv_pad, block_q, block_k,
 def _fwd_kernel(
     offs_ref, *refs,
     sm_scale, causal, block_q, block_k, kv_len, kv_pad, has_segments, window, softcap,
-    walk,
+    walk, has_mask=False,
 ):
-    if has_segments:
-        (q_seg_ref, kv_seg_ref, q_ref, k_ref, v_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        q_seg_ref = kv_seg_ref = None
-        q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    refs = list(refs)
+    q_seg_ref, kv_seg_ref = (refs.pop(0), refs.pop(0)) if has_segments else (None, None)
+    pair_ref = refs.pop(0) if has_mask else None
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     i = pl.program_id(2)  # q block
     t = pl.program_id(3)  # step of the walk over this q block's band of kv blocks
     nt = pl.num_programs(3)
@@ -258,7 +265,7 @@ def _fwd_kernel(
 
     # Tile classing: interior tiles (diagonal doesn't cross, window band doesn't clip,
     # no kv padding, no segment ids) take the mask-free fast path.
-    interior = jnp.asarray(not (has_segments or kv_pad))
+    interior = jnp.asarray(not (has_segments or kv_pad or has_mask))
     if causal:
         interior = jnp.logical_and(interior, k_global + block_k - 1 <= q_global)
     if window:
@@ -310,6 +317,9 @@ def _fwd_kernel(
             block_q=block_q, block_k=block_k, q_global=q_global, k_global=k_global,
             k_local=k_start, kv_len=kv_len, q_seg_ref=q_seg_ref, kv_seg_ref=kv_seg_ref,
         )
+        if has_mask:          # the caller's per-pair mask: one byte a (query, key) pair
+            pair = pair_ref[...].astype(jnp.int32) != 0
+            mask = pair if mask is None else jnp.logical_and(mask, pair)
         _accumulate(_scores(), mask)
 
     @pl.when(t == nt - 1)
@@ -367,10 +377,11 @@ def _q_major_maps(walk, reps, block_q, block_k, segments, Sp, Tp):
 
 
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_offset=0,
-         segments=None, window=0, softcap=0.0):
+         segments=None, window=0, softcap=0.0, mask=None):
     """Raw forward: q [B,H,S,hd], k/v [B,K,T,hd] (K divides H — GQA resolved IN the BlockSpec
     index maps, never via a materialized head repeat) → (o [B,H,S,hd], lse [B,H,S] fp32).
-    Differentiation-free."""
+    Differentiation-free. ``mask`` [B,S,T] (non-zero: the pair may attend; every head
+    shares it) builds the kernel's masked specialisation, ``flash_fwd_masked``."""
     B, H, S, hd = q.shape
     K = k.shape[1]
     reps = H // K
@@ -382,21 +393,28 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_
     k = _pad_seq(k, Tp)
     v = _pad_seq(v, Tp)
     has_segments = segments is not None
+    has_mask = mask is not None
     walk = _kv_walk(causal, window, block_q, block_k, nk)
 
     kernel = functools.partial(
         _fwd_kernel,
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k, kv_len=T,
         kv_pad=(Tp != T), has_segments=has_segments, window=window, softcap=softcap,
-        walk=walk,
+        walk=walk, has_mask=has_mask,
     )
     q_map, kv_map, seg_specs, seg_args = _q_major_maps(
         walk, reps, block_q, block_k, segments, Sp, Tp)
+    if has_mask:        # rides behind the segment ids, on the q tile and the kv walk's tile
+        seg_specs = seg_specs + [pl.BlockSpec(
+            (None, block_q, block_k),
+            lambda b, h, i, t, offs: (b, i, kv_map(b, h, i, t, offs)[2]))]
+        seg_args = seg_args + [jnp.pad(mask.astype(jnp.int8),
+                                       ((0, 0), (0, Sp - S), (0, Tp - T)))]
     # fwd cost: the qk^T + pv dots and the exp of the band's tiles; K and V once a tile.
     tiles = B * H * walk.fetched(nq, T - S)
     o, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name="flash_fwd_masked" if has_mask else "flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nq, walk.extent),
@@ -424,7 +442,8 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, q_offset=0, kv_
         cost_estimate=_cost(
             4 * tiles * block_q * block_k * hd,
             2 * q.size * q.dtype.itemsize + B * H * Sp * _LANES * 4
-            + 2 * tiles * block_k * hd * k.dtype.itemsize,
+            + 2 * tiles * block_k * hd * k.dtype.itemsize
+            + has_mask * tiles * block_q * block_k,
             tiles * block_q * block_k,
         ),
         interpret=interpret,
@@ -845,11 +864,13 @@ _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 def _flash_bhsd_offset(q, k, v, q_offset=0, kv_offset=0, causal=True, sm_scale=None,
                        block_q=None, block_k=None, interpret=None, window=0, softcap=0.0,
-                       segments=None):
+                       segments=None, mask=None):
     """Offset-aware flash attention over user layout [B, S, H, hd] (shard_map helper).
 
     ``segments``: None, a shared [B,S] array, or a ``(q_seg [B,S], kv_seg [B,T])`` pair —
-    the pair form is how the SP modes keep packing exact when kv spans other shards."""
+    the pair form is how the SP modes keep packing exact when kv spans other shards.
+    ``mask`` [B,S,T] (non-zero: the pair may attend): the forward kernel's masked
+    specialisation, forward only."""
     B, S, H, hd = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
@@ -860,6 +881,11 @@ def _flash_bhsd_offset(q, k, v, q_offset=0, kv_offset=0, causal=True, sm_scale=N
     vT = v.transpose(0, 2, 1, 3)
     bq = _fit_block(block_q or _DEFAULT_BLOCK_Q, S)
     bk = _fit_block(block_k or _DEFAULT_BLOCK_K, k.shape[1])
+    if mask is not None:
+        o, _ = _fwd(qT, kT, vT, causal, sm_scale, bq, bk, interpret, q_offset=q_offset,
+                    kv_offset=kv_offset, segments=segments, window=int(window),
+                    softcap=float(softcap), mask=mask)
+        return o.transpose(0, 2, 1, 3)
     seg_f32, has_segments = _seg_pair_f32(segments)
     o = _flash_bhsd(qT, kT, vT,
                     jnp.asarray(q_offset, jnp.float32), jnp.asarray(kv_offset, jnp.float32),

@@ -13,15 +13,19 @@ Components: top-k softmax router with capacity dropping, Switch/Mixtral-style lo
 auxiliary loss, batched expert FFN (SwiGLU, matching the dense MLP).
 
 ``moe_mlp_grouped`` is the serving-side layer of a model whose experts outnumber the
-chip (DeepSeek-V3: 256 routed experts, 8 a token, one shared): a sigmoid router over ALL
-the published experts with a selection bias and group-limited top-k
-(``router_sigmoid_grouped``), and ONE dropless grouped product a projection over the
-experts this chip HOLDS (``expert_offset .. expert_offset + E_held``); what the absent
-experts would add is left out — the exchange between expert-parallel chips is not here.
+chip (DeepSeek-V3: 256 routed experts, 8 a token, one shared): a router over ALL the
+published experts — a sigmoid one with a selection bias and group-limited top-k
+(``router_sigmoid_grouped``), or a softmax one that keeps its ``top_k`` largest and
+renormalises them (``router_softmax_topk``: no bias, no groups; 128 small experts whole on
+one chip) — and ONE dropless grouped product a projection over the experts this chip
+HOLDS (``expert_offset .. expert_offset + E_held``), beside a shared expert if the layer
+has one; what the absent experts would add is left out — the exchange between
+expert-parallel chips is not here.
 """
 
 from __future__ import annotations
 
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +34,8 @@ from jax.sharding import PartitionSpec as P
 from ..utils.constants import EXPERT_AXIS
 
 __all__ = ["router_topk", "load_balancing_loss", "moe_mlp", "moe_mlp_dense",
-           "router_sigmoid_grouped", "moe_mlp_grouped", "expert_partition_specs"]
+           "router_sigmoid_grouped", "router_softmax_topk", "moe_mlp_grouped",
+           "expert_partition_specs"]
 
 
 def router_topk(
@@ -199,6 +204,21 @@ def router_sigmoid_grouped(x: jax.Array, w_router: jax.Array, bias: jax.Array, *
         return gates * scale, idx.astype(jnp.int32)
 
 
+def router_softmax_topk(x: jax.Array, w_router: jax.Array, *, top_k: int,
+                        norm_topk: bool = True, scale: float = 1.0):
+    """The softmax router of the small-expert models: x [T, D], w_router [D, E] → (gates
+    [T, k] fp32, idx [T, k] int32). ``p = softmax(x W)`` over ALL experts in fp32, the
+    ``top_k`` largest chosen (a tie to the lower index), their ``p`` divided by their sum
+    (``norm_topk``), times ``scale``. No bias, no groups."""
+    with jax.named_scope("router"):
+        p = jax.nn.softmax(jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                                   precision=jax.lax.Precision.HIGHEST), axis=-1)
+        gates, idx = jax.lax.top_k(p, top_k)
+        if norm_topk:
+            gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+        return gates * scale, idx.astype(jnp.int32)
+
+
 def _swiglu(x, w: dict, dtype):
     gate = jax.nn.silu(x @ w["w_gate"].astype(dtype))
     return (gate * (x @ w["w_up"].astype(dtype))) @ w["w_down"].astype(dtype)
@@ -226,16 +246,20 @@ def _grouped_dot(rows: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
     return out[:m]
 
 
-def moe_mlp_grouped(x: jax.Array, moe: dict, *, top_k: int, n_group: int,
-                    topk_group: int, scale: float, norm_topk: bool = True,
-                    expert_offset: int = 0, compute_dtype=jnp.bfloat16):
+def moe_mlp_grouped(x: jax.Array, moe: dict, *, top_k: int, n_group: int = 1,
+                    topk_group: int = 1, scale: float = 1.0, norm_topk: bool = True,
+                    expert_offset: int = 0, compute_dtype=jnp.bfloat16,
+                    router: str = "sigmoid_grouped"):
     """Dropless MoE SwiGLU over the experts HELD here, beside a shared expert.
 
     x [T, D]; ``moe`` = ``{"router" [D, E_published], "router_bias" [E_published],
     "shared" {w_gate/w_up [D, Fs], w_down [Fs, D]}, "experts" {w_gate/w_up
     [E_held, D, F], w_down [E_held, F, D]}}``: the router keeps its published width and
     the held experts are the published ones ``expert_offset .. expert_offset +
-    E_held``. The (token, chosen expert) pairs whose expert is held are sorted by
+    E_held``. ``router`` names the rule in front of the product: ``"sigmoid_grouped"``
+    (:func:`router_sigmoid_grouped`, reads ``router_bias``) or ``"softmax"``
+    (:func:`router_softmax_topk`: no bias, no groups); a layer without ``"shared"`` has
+    no shared expert. The (token, chosen expert) pairs whose expert is held are sorted by
     expert, each projection is ONE grouped product over the sorted rows
     (:func:`_grouped_dot`: a row meets only its own expert's weights, nothing is
     dropped whatever the load), the rows are un-sorted, weighted by their gates and
@@ -246,9 +270,15 @@ def moe_mlp_grouped(x: jax.Array, moe: dict, *, top_k: int, n_group: int,
     entered, the largest number of pairs on one expert."""
     T, D = x.shape
     E = moe["experts"]["w_gate"].shape[0]
-    gates, idx = router_sigmoid_grouped(
-        x, moe["router"], moe["router_bias"], top_k=top_k, n_group=n_group,
-        topk_group=topk_group, scale=scale, norm_topk=norm_topk)
+    if router == "softmax":
+        gates, idx = router_softmax_topk(x, moe["router"], top_k=top_k,
+                                         norm_topk=norm_topk, scale=scale)
+    elif router == "sigmoid_grouped":
+        gates, idx = router_sigmoid_grouped(
+            x, moe["router"], moe["router_bias"], top_k=top_k, n_group=n_group,
+            topk_group=topk_group, scale=scale, norm_topk=norm_topk)
+    else:
+        raise ValueError(f"router={router!r}: expected 'sigmoid_grouped' or 'softmax'")
     xc = x.astype(compute_dtype)
     with jax.named_scope("experts"):
         local = idx.reshape(-1) - expert_offset                          # [T*k]
@@ -266,10 +296,11 @@ def moe_mlp_grouped(x: jax.Array, moe: dict, *, top_k: int, n_group: int,
         out = jnp.where(live, out.astype(jnp.float32), 0.0) * gates.reshape(-1)[order][:, None]
         routed = jnp.zeros((T * top_k, D), jnp.float32).at[order].set(
             out, unique_indices=True).reshape(T, top_k, D).sum(1)
-    with jax.named_scope("shared"):
-        shared = _swiglu(xc, moe["shared"], compute_dtype)
+    if "shared" in moe:
+        with jax.named_scope("shared"):
+            routed = routed + _swiglu(xc, moe["shared"], compute_dtype).astype(jnp.float32)
     counts = jnp.stack([n_pairs, jnp.int32(T), sizes.max()]).astype(jnp.int32)
-    return (routed + shared.astype(jnp.float32)).astype(x.dtype), counts
+    return routed.astype(x.dtype), counts
 
 
 def expert_partition_specs() -> dict:

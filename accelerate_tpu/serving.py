@@ -539,7 +539,10 @@ def _insert_row_paged(cache, row_cache, write_ids, slot, page_size: int,
             dst = jnp.where(j >= 0, slot * R + j % R, pool.shape[0])
             return pool.at[dst].set(
                 pages_of(row[0])[jnp.clip(j, 0, MP - 1)].astype(pool.dtype))
-        return pool.at[write_ids].set(pages_of(row[0]).astype(pool.dtype))
+        # a pool page is the row-major view of the row's page (an index-key plane lays two
+        # 64-value keys in one 128-lane row: ops.sparse_attention.index_pool_shape)
+        return pool.at[write_ids].set(
+            pages_of(row[0]).astype(pool.dtype).reshape(MP, *pool.shape[1:]))
 
     layers = jax.tree_util.tree_map_with_path(put, cache["layers"], row_cache["layers"])
     valid = jax.lax.dynamic_update_slice(

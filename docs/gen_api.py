@@ -117,6 +117,7 @@ MODULES = [
     ("accelerate_tpu.models.llama", "Llama family"),
     ("accelerate_tpu.models.deepseek", "DeepSeek family (latent attention, routed experts)"),
     ("accelerate_tpu.models.dots3", "dots3 family (full and sliding latent layers, sparse selection)"),
+    ("accelerate_tpu.models.keye", "Keye-VL-2.0 language model (grouped-query layers under a sparse selection, 128 experts)"),
     ("accelerate_tpu.models.lora", "LoRA fine-tuning"),
     ("accelerate_tpu.models.gpt", "GPT family"),
     ("accelerate_tpu.models.t5", "T5 family"),

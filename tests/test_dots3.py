@@ -239,7 +239,9 @@ def test_window_layers_cache_does_not_grow_with_max_len():
     assert rings == [(4 * common.ring_pages(5, 8), 8, 128)] * 3       # O(window) a lane
     full = [l for l in small if "ring" not in l]
     assert [sorted(l) for l in full] == [["index_k", "latent"]] * 2
-    assert full[0]["latent"].shape == (64, 8, 128) and full[0]["index_k"].shape == (64, 8, 16)
+    # the toy's 16-value index keys lie 8 to a 128-lane row: a page of 8 keys is one row
+    assert full[0]["latent"].shape == (64, 8, 128) and full[0]["index_k"].shape == (64, 1, 128)
+    assert sa.index_pool_shape(64, 16, 128) == (64, 16, 128)         # dots3's: a key a row
 
 
 # ------------------------------------------------------------------ the chip's share

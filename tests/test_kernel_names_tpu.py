@@ -1,7 +1,7 @@
 """Kernel names survive the TPU compiler: ``flash_attention`` forward and backward,
-``paged_attention`` and ``mla_paged_attention`` compiled for a DESCRIBED v5e chip (none is
-attached) at the benchmark cells' widths, and the ``tpu_custom_call`` instructions carry
-the names the trace readers look for. So does the serving engine's whole decode program,
+``paged_attention``, ``mla_paged_attention`` and ``dsa_index_scores`` compiled for a DESCRIBED
+v5e chip (none is attached) at the benchmark cells' widths, and the ``tpu_custom_call``
+instructions carry the names the trace readers look for. So does the serving engine's whole decode program,
 whose compiled form must write the KV pool in place (ISSUE 29), and its chunk-append
 prefill program, whose attention is the flash forward (ISSUE 31). These are compiles, not
 runs: nothing here is a measurement.
@@ -57,7 +57,8 @@ def shape(dims, dtype, sharding):
     return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
 
-KERNEL = re.compile(r"flash_fwd|flash_bwd_dq|flash_bwd_dkv|mla_paged_attention|paged_attention")
+KERNEL = re.compile(r"flash_fwd_masked|flash_fwd|flash_bwd_dq|flash_bwd_dkv|mla_paged_attention"
+                    r"|paged_attention|dsa_index_scores|gmm")
 
 
 def kernels(compiled) -> list:
@@ -245,3 +246,95 @@ def test_a_name_changes_nothing_but_the_name(one_chip, monkeypatch, module, fn, 
     for field in ("argument_size_in_bytes", "output_size_in_bytes", "temp_size_in_bytes",
                   "generated_code_size_in_bytes"):
         assert getattr(bare.memory_analysis(), field) == getattr(named.memory_analysis(), field)
+
+
+# --------------------------------------------------------------------------- Keye-VL-2.0
+# 32 q / 4 kv heads x 128, 16 index heads x 64 (two keys a 128-lane pool row), 2048 keys
+# kept; serve cell 16 lanes, pages of 16, max_len 32768, 24576 pages, depth 5.
+KEYE_LANES, KEYE_K, KEYE_LEN, KEYE_PAGES, KEYE_TOPK = 16, 4, 32768, 24576, 2048
+
+
+@pytest.mark.parametrize("index_dim,heads", [(64, 16), (128, 64)], ids=["keye_64", "dots3_128"])
+def test_index_kernel_takes_keys_of_half_a_lane_tile(one_chip, index_dim, heads):
+    """``dsa_index_scores`` over the pool ``index_pool_shape`` gives: Mosaic accepts 64-value
+    keys laid two a row (it refuses a page cut out of a plane declared 64 wide), dots3's 128
+    still compile, and both are the one kernel of that name."""
+    from accelerate_tpu.ops import sparse_attention as sa
+
+    def scores(q, w, pool, tables, positions, valid):
+        return sa.dsa_index_scores(q, w, pool, tables, positions, valid, page_size=PAGE,
+                                   interpret=False)
+
+    s = one_chip
+    pool = sa.index_pool_shape(KEYE_PAGES, PAGE, index_dim)
+    assert pool == (KEYE_PAGES, PAGE * index_dim // 128, 128)
+    compiled = jax.jit(scores).lower(
+        shape((KEYE_LANES, heads, index_dim), jnp.bfloat16, s),
+        shape((KEYE_LANES, heads), jnp.float32, s), shape(pool, jnp.bfloat16, s),
+        shape((KEYE_LANES, KEYE_LEN // PAGE), jnp.int32, s), shape((KEYE_LANES,), jnp.int32, s),
+        shape((KEYE_LANES, KEYE_LEN), jnp.bool_, s)).compile()
+    assert kernels(compiled) == ["dsa_index_scores"]
+
+
+def test_flash_forward_under_a_pair_mask_is_its_own_kernel(one_chip):
+    """The prefill chunk of a sparse grouped-query layer: 512 queries against the 32768-slot
+    row, 32 / 4 heads, the valid mask as the segment pair and the selection as an int8
+    per-pair mask — Mosaic accepts the (512, 512) int8 block, and the kernel is named apart
+    from the unmasked ``flash_fwd`` the other cells compile."""
+    def masked(q, k, v, valid, pair, index):
+        return flash_mod._flash_bhsd_offset(
+            q, k, v, q_offset=index, kv_offset=0, causal=True, interpret=False,
+            segments=(jnp.ones(q.shape[:2], jnp.int32), valid.astype(jnp.int32)), mask=pair)
+
+    s = one_chip
+    kv = shape((1, KEYE_LEN, KEYE_K, HD), jnp.bfloat16, s)
+    compiled = jax.jit(masked).lower(
+        shape((1, 512, H, HD), jnp.bfloat16, s), kv, kv, shape((1, KEYE_LEN), jnp.bool_, s),
+        shape((1, 512, KEYE_LEN), jnp.int8, s), shape((), jnp.int32, s)).compile()
+    assert kernels(compiled) == ["flash_fwd_masked"]
+
+
+def keye_programs(one_chip, monkeypatch):
+    """Keye-VL-2.0's serve cell as the engine's programs see it (depth 5), every trace-time
+    probe answering as on the chip."""
+    import accelerate_tpu.ops._common as ops_common
+    import accelerate_tpu.utils.imports as imports
+    from accelerate_tpu.models import keye
+
+    monkeypatch.setattr(imports, "is_tpu_available", lambda: True)
+    monkeypatch.setattr(ops_common, "is_tpu_available", lambda: True)
+    cfg = keye.KeyeConfig(n_layers=5)
+    params = on_chip(lambda: keye.init_params(cfg, jax.random.PRNGKey(0)), one_chip)
+    return keye, cfg, params
+
+
+def test_keye_decode_program_holds_the_index_and_the_attention_kernel(one_chip, monkeypatch):
+    """``serving._decode_multi_step_paged`` at the Keye cell's shapes (7.5 GB of weights
+    beside a 4.28 GB pool of K, V and index-key planes): the index kernel and
+    ``paged_attention`` over the gathered rows once a layer, the experts' grouped
+    products, and temporaries of a fraction of a GB."""
+    from accelerate_tpu import serving
+
+    keye, cfg, params = keye_programs(one_chip, monkeypatch)
+    cache = on_chip(lambda: keye.init_paged_cache(cfg, KEYE_LANES, KEYE_LEN, KEYE_PAGES, PAGE),
+                    one_chip)
+    lanes = lambda dtype, *more: shape((KEYE_LANES, *more), dtype, one_chip)  # noqa: E731
+    compiled = serving._decode_multi_step_paged.lower(
+        params, cache, lanes(jnp.int32, KEYE_LEN // PAGE), lanes(jnp.int32),
+        lanes(jnp.int32), lanes(jnp.bool_), lanes(jnp.int32), lanes(jnp.int32),
+        lanes(jnp.uint32, 4, 2), lanes(jnp.float32), lanes(jnp.float32), lanes(jnp.int32),
+        cfg=cfg, n_steps=4, sample=False, page_size=PAGE).compile()
+    assert kernels(compiled) == ["dsa_index_scores"] * 5 + ["gmm"] * 15 + ["paged_attention"] * 5
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+
+
+def test_keye_prefill_chunk_program_takes_the_masked_flash_forward(one_chip, monkeypatch):
+    from accelerate_tpu import serving
+
+    keye, cfg, params = keye_programs(one_chip, monkeypatch)
+    cache = on_chip(lambda: keye.init_cache(cfg, 1, KEYE_LEN), one_chip)
+    compiled = serving._prefill_chunk_jit.lower(
+        params, shape((1, 512), jnp.int32, one_chip), shape((1, 512), jnp.bool_, one_chip),
+        cache, cfg=cfg).compile()
+    assert kernels(compiled) == ["flash_fwd_masked"] * 5 + ["gmm"] * 15
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
