@@ -17,6 +17,7 @@ materializes in HBM).
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -727,45 +728,118 @@ def resolve_loss_chunk(loss_chunk: int, S: int, vocab_size: int) -> int:
     return min(512, S)
 
 
+def _chunk_logits(xc, hd, bias, softcap: float):
+    """One chunk's fp32 logits [B, c, V] (head product, + fp32 bias, softcap)."""
+    logits = (xc @ hd).astype(jnp.float32)
+    if bias is not None:
+        logits = logits + bias
+    return _softcap(logits, softcap)
+
+
+def _chunk_nll(logits, tc, mc):
+    """(logsumexp [B, c], one-hot of the targets [B, c, V], masked sum of -log p(target))
+    of one chunk's logits. The target's logit is a one-hot reduction, not a gather: it
+    fuses into the ``exp`` sum's pass over the logits (a gather made the TPU write the
+    chunk's logits in float32 beside the bfloat16 the passes read) and partitions over a
+    sharded vocabulary as a sum does."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    onehot = jnp.arange(logits.shape[-1], dtype=tc.dtype) == tc[..., None]
+    tgt = jnp.where(onehot, logits, 0.0).sum(axis=-1)
+    return lse, onehot, -((tgt - lse) * mc).sum()
+
+
+def _chunks(a, chunk: int):
+    """[B, S, ...] -> [S // chunk, B, chunk, ...]: the chunk scan's ``xs`` layout."""
+    B, S = a.shape[:2]
+    return a.reshape(B, S // chunk, chunk, *a.shape[2:]).swapaxes(0, 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _chunked_ce_sum(x, hd, bias, targets, mask, chunk: int, softcap: float):
+    """The primal: the chunk scan alone (evaluation, a loss read without ``grad``).
+    ``hd`` is the head in the compute dtype, ``bias`` fp32 or None, S a chunk multiple."""
+
+    def body(total, xtm):
+        xc, tc, mc = xtm
+        _, _, nll = _chunk_nll(_chunk_logits(xc, hd, bias, softcap), tc, mc)
+        return total + nll, None
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32), [_chunks(a, chunk) for a in (x, targets, mask)])
+    return total
+
+
+def _chunked_ce_sum_fwd(x, hd, bias, targets, mask, chunk, softcap):
+    """Loss AND its gradients in one scan: a chunk's logits are formed once and give the
+    loss term, ``dlogits`` and from it ``dx`` (the scan's ``ys``) and ``dW`` / ``dbias``
+    (the carry). All three are per unit of the loss's cotangent."""
+
+    def body(carry, xtm):
+        total, dw, db = carry
+        xc, tc, mc = xtm
+        logits = _chunk_logits(xc, hd, bias, softcap)
+        lse, onehot, nll = _chunk_nll(logits, tc, mc)
+        dl = (jnp.exp(logits - lse[..., None]) - onehot) * mc[..., None].astype(jnp.float32)
+        if softcap:
+            dl = dl * (1.0 - jnp.square(logits / softcap))       # d cap·tanh(z/cap) / dz
+        if db is not None:
+            db = db + dl.sum(axis=(0, 1))
+        dl = dl.astype(hd.dtype)                                 # [B, c, V]
+        dw = dw + jnp.einsum("bcd,bcv->dv", xc, dl).astype(hd.dtype)
+        return (total + nll, dw, db), (dl @ hd.T).astype(xc.dtype)
+
+    (total, dw, db), dxs = jax.lax.scan(
+        body,
+        (jnp.zeros((), jnp.float32), jnp.zeros_like(hd),
+         None if bias is None else jnp.zeros_like(bias)),
+        [_chunks(a, chunk) for a in (x, targets, mask)],
+    )
+    return total, (dxs.swapaxes(0, 1).reshape(x.shape), dw, db)
+
+
+def _chunked_ce_sum_bwd(chunk, softcap, res, g):
+    # ``targets`` and ``mask`` take no gradient: None is the zero cotangent.
+    return (*((None if r is None else (g * r).astype(r.dtype)) for r in res), None, None)
+
+
+_chunked_ce_sum.defvjp(_chunked_ce_sum_fwd, _chunked_ce_sum_bwd)
+
+
 def chunked_ce(x, head, targets, mask, chunk: int, dtype, final_softcap: float = 0.0,
                bias=None):
-    """Memory-efficient cross-entropy: per-chunk head matmul + logsumexp under remat.
+    """Memory-efficient cross-entropy: per-chunk head matmul + logsumexp, differentiated
+    in the same pass.
 
     ``x`` [B,S,D] (post-final-norm hidden), ``head`` [D,V]; returns the sum of
     -log p(target) over unmasked positions. The fp32 [B,S,V] logits are never
-    materialized — each scan step computes one [B,chunk,V] block and the backward pass
-    recomputes it (``jax.checkpoint``), so peak memory drops from O(S·V) to O(chunk·V).
-    S is padded up to a chunk multiple with masked positions, so any chunk works for any
-    sequence length. ``bias`` [V] (gpt-j's lm_head bias) is added pre-softmax.
+    materialized — each scan step computes one [B,chunk,V] block, so peak memory drops
+    from O(S·V) to O(chunk·V). S is padded up to a chunk multiple with masked positions,
+    so any chunk works for any sequence length. ``bias`` [V] (gpt-j's lm_head bias) is
+    added pre-softmax.
+
+    A ``jax.custom_vjp`` over ``(x, head, bias)``. The loss is a scalar, so its cotangent
+    is one number ``g`` and ``dlogits = g · mask · (softmax − onehot)`` is known up to
+    ``g`` the moment a chunk's logits exist: under differentiation ONE scan forms the
+    logits once and from them the loss term, ``dx`` and ``dW`` (``dbias``) — three
+    head-sized products where a backward that recomputes the chunk's logits spends four,
+    and no second scan. The residuals are those gradients per unit ``g`` —
+    ``dx`` [B,S,D] in ``x``'s dtype, ``dW`` [D,V] accumulated in ``dtype``, ``dbias`` [V]
+    in fp32 — and the backward only scales them by ``g``. ``targets`` and ``mask`` take no
+    gradient (a float ``mask`` gets zeros, not ``-log p``), and there is no second-order
+    or forward-mode derivative through the loss (a ``custom_vjp`` has none). Called
+    without differentiation it runs the plain scan.
     """
-    B, S, D = x.shape
+    S = x.shape[1]
     if S % chunk:
         pad = chunk - S % chunk
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         targets = jnp.pad(targets, ((0, 0), (0, pad)))
         mask = jnp.pad(mask, ((0, 0), (0, pad)))
-        S += pad
-    n = S // chunk
-    xs = x.reshape(B, n, chunk, D).swapaxes(0, 1)            # [n, B, c, D]
-    ts = targets.reshape(B, n, chunk).swapaxes(0, 1)         # [n, B, c]
-    ms = mask.reshape(B, n, chunk).swapaxes(0, 1)            # [n, B, c]
-
-    @jax.checkpoint
-    def chunk_loss(xc, tc, mc):
-        logits = (xc @ head.astype(dtype)).astype(jnp.float32)   # [B, c, V]
-        if bias is not None:
-            logits = logits + bias.astype(jnp.float32)
-        logits = _softcap(logits, final_softcap)
-        lse = jax.nn.logsumexp(logits, axis=-1)                  # [B, c]
-        tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1).squeeze(-1)
-        return -((tgt - lse) * mc).sum()
-
-    def body(carry, xtm):
-        xc, tc, mc = xtm
-        return carry + chunk_loss(xc, tc, mc), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts, ms))
-    return total
+    # The casts stay outside the custom_vjp, so each gradient returns in its operand's dtype.
+    return _chunked_ce_sum(
+        x, head.astype(dtype), None if bias is None else bias.astype(jnp.float32),
+        targets, mask, chunk, final_softcap,
+    )
 
 
 def ce_sum(x, head, targets, mask, *, dtype, chunk: int = 0, softcap: float = 0.0,

@@ -100,9 +100,10 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # Cross-entropy chunking (memory): compute logits+logsumexp per sequence chunk of this
-    # many tokens under remat instead of materializing fp32 [B,S,V] logits. 0 = auto
-    # (chunk only when S*V is large enough to matter), -1 = never chunk.
+    # Cross-entropy chunking (memory): compute logits+logsumexp — and, under grad, the
+    # chunk's dx and dW in the same pass — per sequence chunk of this many tokens instead
+    # of materializing fp32 [B,S,V] logits. 0 = auto (chunk only when S*V is large enough
+    # to matter), -1 = never chunk.
     loss_chunk: int = 0
     # "auto": loss_chunk logic above. "fused": ops/fused_xent Pallas kernel — the score
     # tiles never leave VMEM (no [tokens, V] logits in HBM at all, fwd or bwd);

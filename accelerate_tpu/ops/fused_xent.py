@@ -2,8 +2,9 @@
 
 The reference computes ``lm_head`` logits then ``torch.nn.CrossEntropyLoss`` — at
 V=32k, S=2048, B=4 that is a ~1 GB fp32 tensor materialized twice per step (forward
-and backward). ``models/llama._chunked_ce`` already bounds this by chunking over the
-sequence, but each [B, chunk, V] block still round-trips HBM. This kernel goes the rest
+and backward). ``models/common.chunked_ce`` already bounds this by chunking over the
+sequence and forms each chunk's gradients from the logits it holds (one scan, no
+recompute), but each [B, chunk, V] block still round-trips HBM. This kernel goes the rest
 of the way (the CCE / Liger-kernel idea, TPU-style): the score tile ``x_tile @ w_tile``
 lives only in VMEM, reduced on the fly into an online logsumexp (exactly the
 FlashAttention recurrence with the kv axis replaced by the vocab axis), and the

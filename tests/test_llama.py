@@ -203,6 +203,155 @@ def test_chunked_ce_matches_full():
     )
 
 
+def _ce_case(S=32, mask="zeros", dtype=jnp.float32, seed=0, B=2, D=16, V=64):
+    """x [B,S,D], head [D,V], bias [V], targets, mask for the chunked-CE cases below."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(k[0], (B, S, D), dtype)
+    head = (0.3 * jax.random.normal(k[1], (D, V))).astype(dtype)
+    bias = jax.random.normal(k[2], (V,), dtype)
+    targets = jax.random.randint(k[3], (B, S), 0, V)
+    m = jax.random.uniform(k[4], (B, S)) > 0.3 if mask == "zeros" else jnp.ones((B, S), bool)
+    return x, head, bias, targets, m.astype(jnp.float32)
+
+
+def _count_primitives(jaxpr, name: str) -> int:
+    """Equations named ``name`` in a jaxpr and every jaxpr nested in it (scan bodies...)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_primitives(sub, name)
+    return n
+
+
+def _assert_close(got, want, rel):
+    """Every leaf within ``rel`` of the reference's largest element."""
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=rel * max(np.abs(b).max(), 1e-6), rtol=0)
+
+
+@pytest.mark.parametrize("mask", ["zeros", "ones"])
+@pytest.mark.parametrize("S", [32, 30])                       # a chunk multiple / padded
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("softcap", [0.0, 30.0])
+def test_chunked_ce_one_pass_matches_dense(softcap, with_bias, S, mask):
+    """Value and gradients wrt x, head (and bias) of the one-pass chunked loss equal the
+    dense ``ce_sum(chunk=0)``'s, under jit, in float32."""
+    from accelerate_tpu.models.common import ce_sum
+
+    x, head, bias, targets, m = _ce_case(S, mask)
+    args = (x, head, bias) if with_bias else (x, head)
+
+    def loss(chunk):
+        def f(x, head, bias=None):
+            return ce_sum(x, head, targets, m, dtype=jnp.float32, chunk=chunk,
+                          softcap=softcap, bias=bias)
+        return jax.jit(jax.value_and_grad(f, argnums=tuple(range(len(args)))))
+
+    (l_chunk, g_chunk), (l_full, g_full) = loss(8)(*args), loss(0)(*args)
+    np.testing.assert_allclose(float(l_chunk), float(l_full), rtol=1e-6)
+    _assert_close(g_chunk, g_full, 2e-6)
+    assert all(g.dtype == a.dtype and g.shape == a.shape for g, a in zip(g_chunk, args))
+
+
+@pytest.mark.parametrize("scale", ["over_denom", "times_3"])
+def test_chunked_ce_scales_by_the_cotangent(scale):
+    """The residuals are gradients per unit cotangent; the backward multiplies by ``g``."""
+    from accelerate_tpu.models.common import ce_sum
+
+    x, head, bias, targets, m = _ce_case(30)
+    post = (lambda l: l / jnp.maximum(m.sum(), 1.0)) if scale == "over_denom" else (lambda l: 3.0 * l)
+
+    def grads(chunk):
+        return jax.grad(lambda x, h, b: post(ce_sum(
+            x, h, targets, m, dtype=jnp.float32, chunk=chunk, bias=b)), argnums=(0, 1, 2))(x, head, bias)
+
+    _assert_close(grads(8), grads(0), 2e-6)
+
+
+def test_chunked_ce_gradient_reaches_tied_embedding_twice():
+    """Tied embeddings: ``embed`` is the lookup table AND (transposed) the head."""
+    from accelerate_tpu.models.common import ce_sum
+
+    _, head, _, targets, m = _ce_case(32)
+    tokens = (targets + 1) % head.shape[1]
+
+    def grad(chunk):
+        return jax.grad(lambda e: ce_sum(e[tokens], e.T, targets, m, dtype=jnp.float32, chunk=chunk))(head.T)
+
+    _assert_close(grad(8), grad(0), 2e-6)
+
+
+def test_chunked_ce_bfloat16_matches_dense():
+    from accelerate_tpu.models.common import ce_sum
+
+    x, head, bias, targets, m = _ce_case(30, dtype=jnp.bfloat16)
+
+    def vg(chunk):
+        return jax.jit(jax.value_and_grad(lambda x, h, b: ce_sum(
+            x, h, targets, m, dtype=jnp.bfloat16, chunk=chunk, bias=b) / 32.0, argnums=(0, 1, 2)))(x, head, bias)
+
+    (l_chunk, g_chunk), (l_full, g_full) = vg(8), vg(0)
+    np.testing.assert_allclose(float(l_chunk), float(l_full), rtol=1e-5)   # fp32 from bf16 logits
+    assert [g.dtype for g in g_chunk] == [jnp.bfloat16] * 3
+    _assert_close(g_chunk, g_full, 2e-2)
+
+
+def test_chunked_ce_primal_equals_differentiated_value():
+    """Without ``grad`` the plain scan runs; its value is the differentiated call's."""
+    from accelerate_tpu.models.common import chunked_ce
+
+    x, head, bias, targets, m = _ce_case(30)
+    f = lambda x: chunked_ce(x, head, targets, m, 8, jnp.float32, final_softcap=30.0, bias=bias)  # noqa: E731
+    assert float(f(x)) == float(jax.value_and_grad(f)(x)[0])
+    assert _count_primitives(jax.make_jaxpr(f)(x).jaxpr, "dot_general") == 1
+
+
+def test_chunked_ce_differentiates_in_one_scan_of_three_products():
+    """The gradient program holds ONE scan with THREE head-sized products (a recomputing
+    backward holds two scans and four), and no remat."""
+    from accelerate_tpu.models.common import chunked_ce
+
+    x, head, _, targets, m = _ce_case(32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x, h: chunked_ce(x, h, targets, m, 8, jnp.float32), argnums=(0, 1)))(x, head).jaxpr
+    assert _count_primitives(jaxpr, "scan") == 1
+    assert _count_primitives(jaxpr, "dot_general") == 3
+    assert _count_primitives(jaxpr, "checkpoint") == 0
+    assert _count_primitives(jaxpr, "gather") == 0      # the target's logit is a one-hot sum
+
+
+def test_chunked_ce_mask_and_targets_take_no_gradient():
+    """A float mask's cotangent is zeros (plain autodiff would hand it -log p, which no
+    caller reads)."""
+    from accelerate_tpu.models.common import chunked_ce
+
+    x, head, _, targets, m = _ce_case(32)
+    g = jax.grad(lambda m: chunked_ce(x, head, targets, m, 8, jnp.float32))(m)
+    assert g.shape == m.shape and not np.asarray(g).any()
+
+
+def test_chunked_ce_vocab_sharded_head_matches_unsharded(mesh8):
+    """GSPMD: a head sharded over the vocabulary (and x over the batch) gives the
+    unsharded call's value and gradients."""
+    from jax.sharding import Mesh, NamedSharding
+
+    from accelerate_tpu.models.common import chunked_ce
+
+    x, head, bias, targets, m = _ce_case(30, B=4)
+    f = jax.jit(jax.value_and_grad(lambda x, h, b: chunked_ce(
+        x, h, targets, m, 8, jnp.float32, bias=b), argnums=(0, 1, 2)))
+    mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4), ("data", "vocab"))
+    put = lambda a, *spec: jax.device_put(a, NamedSharding(mesh, P(*spec)))  # noqa: E731
+    l_sh, g_sh = f(put(x, "data"), put(head, None, "vocab"), put(bias, "vocab"))
+    l_one, g_one = f(x, head, bias)
+    np.testing.assert_allclose(float(l_sh), float(l_one), rtol=1e-6)
+    _assert_close(g_sh, g_one, 2e-6)
+    assert g_sh[1].sharding.spec == P(None, "vocab")
+
+
 def test_chunked_ce_tied_embeddings():
     cfg = dataclasses.replace(CFG, tie_embeddings=True, loss_chunk=8)
     params = llama.init_params(cfg)
