@@ -87,7 +87,7 @@ from .telemetry.schemas import (
     SERVING_THROUGHPUT_SCHEMA,
 )
 from .telemetry.slo import latency_summary
-from .telemetry.tracing import EnginePhase, phase
+from .telemetry.tracing import PHASES, EnginePhase, phase
 from .utils.dataclasses import CompileCacheConfig
 
 __all__ = ["ContinuousBatcher", "KVBudgetError", "KVHandoff", "Request",
@@ -963,6 +963,7 @@ class ContinuousBatcher:
         # (bounded window; ``queue_wait_s`` keeps the oldest-queued age).
         self.queue_waits: deque[float] = deque(maxlen=1024)
         self.admitted = 0   # requests that entered a slot (prefill ran)
+        self.prefill_chunks = 0  # prefill programs dispatched (chunked: one a chunk)
         self.evicted = 0    # slot frees: finished (EOS/max_new_tokens) requests
         self.evicted_external = 0  # slot frees forced by evict() (deadline/cancel/preempt)
         # Decode-throughput accounting: tokens emitted per decode dispatch is THE
@@ -1033,7 +1034,17 @@ class ContinuousBatcher:
         (``kv_shared_pages``) and alloc/free/COW/adopt/defer counters — the same
         fields the ``serving.kv/v1`` telemetry record carries per step. Prefix-cache
         eviction is observable in both layouts: ``prefix_evictions`` plus the
-        capacity-vs-key miss split."""
+        capacity-vs-key miss split.
+
+        ``phases`` is what the process's phase ledger
+        (``telemetry.tracing.PHASES``) has counted under ``engine.*`` since the
+        process began, with or without a profiler: per phase of docs/telemetry.md's
+        table its ``count``, ``total_ns``, ``self_ns``, longest (``max_ns``,
+        ``max_t0_ns``, ``max_attrs``) and ``sums`` of every numeric attribute
+        (``tokens``, ``chunks``, ``pages_live``, the model's ``DECODE_COUNTERS``…).
+        The ledger is the process's, so the engines of one process share it; the
+        table is a copy as of the call (``PhaseLedger.totals``: a few microseconds
+        for the engine's dozen names)."""
         active = sum(r is not None for r in self.slot_req)
         queue_wait_s = 0.0
         if self.queue:
@@ -1075,6 +1086,7 @@ class ContinuousBatcher:
             "max_slots": self.max_slots,
             "slot_occupancy": active / self.max_slots,
             "admitted": self.admitted,
+            "prefill_chunks": self.prefill_chunks,
             "evicted": self.evicted,
             "evicted_external": self.evicted_external,
             "prefix_entries": len(self._prefix_reg),
@@ -1105,6 +1117,7 @@ class ContinuousBatcher:
             "watchdog_timeouts": (
                 self._watchdog.timeouts if self._watchdog is not None else 0
             ),
+            "phases": PHASES.totals("engine."),
         }
 
     def _emit_telemetry(self, extra: Optional[dict] = None) -> None:
@@ -1120,6 +1133,7 @@ class ContinuousBatcher:
             "telemetry_rev": TELEMETRY_REV,
             **self.stats(),
         }
+        del record["phases"]    # the program's own memory, not a stream of records
         if self.compile_cache is not None:
             record["compile_cache"] = self.compile_cache.stats()
         if extra:
@@ -1942,9 +1956,9 @@ class ContinuousBatcher:
         with phase("engine.decode.prepare"):
             sampled, lane_args = self._lane_args(active, N)
             tables = self._tables()
+            walk = self._paged_walk(active) if self.paged else None
         t_guard, (tok_buf, counts, self.cache, *model_counts) = self._dispatch(
-            "decode_multi", active, tables, lane_args,
-            self._paged_walk(active) if self.paged else None,
+            "decode_multi", active, tables, lane_args, walk,
             n_steps=N, sample=sampled)
         with phase("engine.decode.fetch"):
             tok_host = np.asarray(tok_buf).tolist()     # [N, B]
@@ -2419,8 +2433,8 @@ class ContinuousBatcher:
                 with EnginePhase(self.tracer, "engine.prefill", uid=req.uid,
                                  prompt_len=len(ctx), width=int(width)) as ph:
                     tracing = ph.tracer is not None
+                    hits0, chunks0 = self.prefix_hits, self.prefill_chunks
                     if tracing:
-                        hits0 = self.prefix_hits
                         cow0 = self.block_mgr.cow_count if self.paged else 0
                         adopt0 = self.block_mgr.adopt_count if self.paged else 0
                     try:
@@ -2429,6 +2443,13 @@ class ContinuousBatcher:
                     except Exception as e:
                         return self._prefill_failed(req, e, finished)
                     self.queue.popleft()
+                    hit = self.prefix_hits > hits0
+                    # Without a plan the path actually run is a prefix-snapshot
+                    # resume only when the registry hit — a cold prompt ran the
+                    # right-aligned chunked prefill.
+                    mode = plan[0] if plan is not None else (
+                        "prefix" if hit else "chunk")
+                    ph.set_metadata(chunks=self.prefill_chunks - chunks0, mode=mode)
                     if req._recover_ctx is None:
                         self.queue_waits.append(
                             max(0.0, time.monotonic() - req.enqueued_at)
@@ -2466,12 +2487,6 @@ class ContinuousBatcher:
                         # waits on, so queue.dur + prefill.dur reconstructs TTFT.
                         tracer = ph.tracer
                         handle = tracer.handle_for(req.uid)
-                        hit = self.prefix_hits > hits0
-                        # Without a plan the path actually run is a prefix-snapshot
-                        # resume only when the registry hit — a cold prompt ran the
-                        # right-aligned chunked prefill.
-                        mode = plan[0] if plan is not None else (
-                            "prefix" if hit else "chunk")
                         tracer.event(
                             handle, "admit", t=ph.t0, lane=slot,
                             kv_defer_retries=handle.kv_defers if handle else 0,
@@ -2704,6 +2719,7 @@ class ContinuousBatcher:
                 page_size=self.page_size, scan_layers=self.cfg.scan_layers,
             )
         logits = None
+        self.prefill_chunks += n_chunks - start
         for c in range(start, n_chunks):
             sl = slice(c * bucket, (c + 1) * bucket)
             if cache is None:
@@ -2781,6 +2797,7 @@ class ContinuousBatcher:
             else:
                 self.bucket_misses += 1
                 self._buckets_seen.add(total)
+            self.prefill_chunks += 1
             greedy, logits, cache = self._prefill_fn(
                 self.params, jnp.asarray(row), jnp.asarray(mask),
                 cfg=self.cfg, max_len=self.max_len,
@@ -2788,6 +2805,7 @@ class ContinuousBatcher:
             return cache, greedy, logits, total
         bucket = self.prompt_bucket
         n_chunks = total // bucket
+        self.prefill_chunks += n_chunks
         greedy, logits, cache = self._prefill_fn(
             self.params, jnp.asarray(row[:, :bucket]), jnp.asarray(mask[:, :bucket]),
             cfg=self.cfg, max_len=self.max_len,
@@ -2834,6 +2852,7 @@ class ContinuousBatcher:
             self._classify_prefix_miss(prompt, full_chunks)
 
         logits = None
+        self.prefill_chunks += n_chunks - start
         for c in range(start, n_chunks):
             sl = slice(c * bucket, (c + 1) * bucket)
             if cache is None:
@@ -2855,6 +2874,8 @@ class ContinuousBatcher:
             sl = slice((start - 1) * bucket, start * bucket)
             prev_key = prompt[: (start - 1) * bucket].tobytes() if start > 1 else None
             prev = self._prefix_reg.get(prev_key) if prev_key else None
+            if prev is not None or start == 1:      # else _recompute_all counts its own
+                self.prefill_chunks += 1
             if prev is not None:
                 logits, cache = self._prefill_chunk_keep_fn(
                     self.params, jnp.asarray(row[:, sl]), jnp.asarray(mask[:, sl]),
@@ -2873,6 +2894,7 @@ class ContinuousBatcher:
 
     def _recompute_all(self, row, mask, n_chunks):
         bucket = self.prompt_bucket
+        self.prefill_chunks += n_chunks
         logits, cache = self._prefill_full_logits_fn(
             self.params, jnp.asarray(row[:, :bucket]), jnp.asarray(mask[:, :bucket]),
             cfg=self.cfg, max_len=self.max_len,

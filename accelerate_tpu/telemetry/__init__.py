@@ -65,7 +65,7 @@ from .slo import (
 )
 from .steady import SteadyStateDetector, TELEMETRY_REV
 from .timing import StepTimer, StepTiming, fence
-from .tracing import EnginePhase, Tracer, TraceHandle, phase, step_phase
+from .tracing import PHASES, EnginePhase, Tracer, TraceHandle, phase, step_phase
 
 __all__ = [
     "AlertEngine",
@@ -125,6 +125,7 @@ __all__ = [
     "Tracer",
     "TraceHandle",
     "EnginePhase",
+    "PHASES",
     "phase",
     "step_phase",
 ]

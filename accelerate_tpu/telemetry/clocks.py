@@ -30,12 +30,19 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional
 
-__all__ = ["WALL_CLOCK", "WALL_SLEEP", "resolve_clock", "resolve_sleep"]
+__all__ = ["WALL_CLOCK", "WALL_SLEEP", "PHASE_CLOCK_NS", "resolve_clock",
+           "resolve_sleep"]
 
 #: The sanctioned wall clock: monotonic, so backoff schedules and deadline
 #: arithmetic survive NTP steps. Components fall back to this — they never
 #: spell ``time.monotonic`` themselves.
 WALL_CLOCK: Callable[[], float] = time.monotonic
+
+#: The phase ledger's clock (``telemetry.tracing.PHASES``): integer nanoseconds of
+#: ``time.perf_counter``, the clock a caller that times the program from outside
+#: reads (the benchmark's windows do), so a ledger record is cut at such a caller's
+#: marks with no conversion. Not injectable: the ledger times the real program.
+PHASE_CLOCK_NS: Callable[[], int] = time.perf_counter_ns
 
 #: The sanctioned wall sleep, paired with :data:`WALL_CLOCK` (a component
 #: that waits must wait in the same domain it measures).

@@ -58,74 +58,210 @@ fully traced while the happy path pays ring entries alone — and a promoted
 trace reconstructs TTFT to the digit, because the span records ARE the ones
 full tracing would have written.
 
-**Program phases on the profiler's clock.** :func:`phase` opens a
-``jax.profiler.TraceAnnotation`` named ``atpu.<name>``: it records only while
-a ``jax.profiler`` session is live (``start_trace`` is the switch — there is
-no other) and lands in the session's trace beside the device's operations,
-nested by time on the calling thread's line (:func:`step_phase` is the same
-for a train step). :class:`EnginePhase` is the one
-helper the serving engine's boundaries go through: the phase, plus — only
-while the request-scoped :class:`Tracer` is enabled — the tracer-clock reads
-its span records are stamped with (docs/telemetry.md lists the spans).
+**Program phases: one span, two sinks.** :func:`phase` names a piece of the
+program's own work ``atpu.<name>`` (:func:`step_phase` is the same for a train
+step). Every phase feeds the process-wide :data:`PHASES` ledger — always on, in
+bounded memory, on ``clocks.PHASE_CLOCK_NS`` — and is a
+``jax.profiler.TraceAnnotation`` besides, which reaches a TRACE only while a
+``jax.profiler`` session is live (``start_trace`` is that switch — there is no
+other) and lands there beside the device's operations, nested by time on the
+calling thread's line. :class:`EnginePhase` is the helper the serving engine's
+boundaries go through where they also emit :class:`Tracer` records: the phase,
+plus — only while the request-scoped :class:`Tracer` is enabled — the
+tracer-clock reads its span records are stamped with (docs/telemetry.md lists
+the spans).
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
 import random
-from typing import Callable, Dict, Optional
+import threading
+from typing import Callable, Dict, NamedTuple, Optional
 
 import jax
 
-from .clocks import resolve_clock
+from .clocks import PHASE_CLOCK_NS, resolve_clock
 from .schemas import TRACE_SPAN_SCHEMA
 
 __all__ = ["Tracer", "TraceHandle", "TRACE_SPAN_SCHEMA", "phase", "step_phase",
-           "EnginePhase"]
+           "Phase", "EnginePhase", "PhaseRecord", "PhaseLedger", "PHASES"]
 
 #: Every program span's name starts with this, so a trace reader finds them all.
 PHASE_PREFIX = "atpu."
 
 
-def phase(name: str, **attrs) -> jax.profiler.TraceAnnotation:
-    """Context manager: the span ``atpu.<name>`` on the profiler's clock, with
-    ``attrs`` as the event's stats. Outside a ``jax.profiler`` session it
-    records nothing and costs a flag test. ``set_metadata(**attrs)`` on the
-    entered object adds values known only at the span's end."""
-    return jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **attrs)
+class PhaseRecord(NamedTuple):
+    """One closed phase. Times are ``clocks.PHASE_CLOCK_NS`` readings; ``self_ns``
+    is the duration less the phases opened inside it on the same thread;
+    ``depth`` counts the phases open around it (0: none); ``attrs`` is the dict
+    the profiler's span got, ``set_metadata`` additions included."""
+
+    name: str
+    t0_ns: int
+    t1_ns: int
+    self_ns: int
+    depth: int
+    attrs: dict
+    thread: int
 
 
-def step_phase(name: str, step_num: int) -> jax.profiler.StepTraceAnnotation:
-    """:func:`phase` for one step of a training loop: profiler tools group the
-    device's work by the ``step_num`` of the step span that dispatched it."""
-    return jax.profiler.StepTraceAnnotation(PHASE_PREFIX + name, step_num=step_num)
+class _OpenPhases(threading.local):
+    """A thread's stack of open phases (built on the thread's first phase)."""
+
+    def __init__(self):
+        self.stack: list = []
+        self.thread = threading.get_ident()
 
 
-class EnginePhase:
-    """One engine boundary stamped on both clocks from one place: the
-    :func:`phase` span always, and ``t0`` on the :class:`Tracer`'s clock only
-    while that tracer is enabled. ``tracer`` is None otherwise, so a boundary
-    with tracing off reads two attributes and no clock (the overhead contract
-    above); callers emit their span records through :meth:`span`."""
+class PhaseLedger:
+    """What the program remembers of its own phases, profiler or no profiler: a
+    ring of the last :attr:`RING` closed phases (``dropped`` counts those that
+    fell out of it) and, per name, totals that never fall out. One per process
+    (:data:`PHASES`); every thread has its own stack of open phases, so nesting
+    and self time are a thread's own."""
 
-    __slots__ = ("tracer", "t0", "_annotation")
+    RING = 65536
 
-    def __init__(self, tracer: Optional["Tracer"], name: str, **attrs):
-        self.tracer = tracer if tracer is not None and tracer.enabled else None
-        self.t0 = 0.0
-        self._annotation = phase(name, **attrs)
+    def __init__(self):
+        # Plain tuples in PhaseRecord's order (records() wraps them): cheaper to
+        # build, and the collector can stop tracking a tuple of numbers, a string
+        # and a dict of such, where it would walk 65 536 instances of a subclass.
+        self._ring: collections.deque = collections.deque(maxlen=self.RING)
+        # name -> [count, total_ns, self_ns, max_ns, max_t0_ns, max_attrs, sums]
+        self._totals: Dict[str, list] = {}
+        self._open = _OpenPhases()
+        self._lock = threading.Lock()
+        self.dropped = 0
 
-    def __enter__(self) -> "EnginePhase":
+    def _close(self, ph: "Phase", t1: int) -> None:
+        """Pop ``ph`` off its thread's stack and book it: one record, its name's
+        totals, and its duration against the phase around it."""
+        open_ = self._open
+        stack = open_.stack
+        stack.pop()
+        t0, attrs = ph._t0, ph.attrs
+        dur = t1 - t0
+        self_ns = dur - ph._inner_ns
+        if stack:
+            stack[-1]._inner_ns += dur
+        with self._lock:
+            ring = self._ring
+            if len(ring) == self.RING:
+                self.dropped += 1
+            ring.append((ph.name, t0, t1, self_ns, len(stack), attrs, open_.thread))
+            tot = self._totals.get(ph.name)
+            if tot is None:
+                tot = self._totals[ph.name] = [0, 0, 0, -1, 0, attrs, {}]
+            tot[0] += 1
+            tot[1] += dur
+            tot[2] += self_ns
+            if dur > tot[3]:
+                tot[3], tot[4], tot[5] = dur, t0, attrs
+            if attrs:
+                sums = tot[6]
+                for k, v in attrs.items():
+                    if isinstance(v, (int, float)):
+                        sums[k] = sums.get(k, 0) + v
+
+    def records(self, since_ns: Optional[int] = None,
+                until_ns: Optional[int] = None) -> list:
+        """The ring's :class:`PhaseRecord` s that overlap ``[since_ns, until_ns]``
+        (None: open on that side), in the order they closed — a phase after the
+        phases inside it. A record that straddles an edge is kept whole: the
+        caller clips."""
+        with self._lock:
+            out = list(self._ring)
+        return [PhaseRecord._make(r) for r in out
+                if (since_ns is None or r[2] >= since_ns)
+                and (until_ns is None or r[1] <= until_ns)]
+
+    def totals(self, prefix: str = "") -> Dict[str, dict]:
+        """Per phase name (those that start with ``prefix``): ``count``,
+        ``total_ns``, ``self_ns``, ``max_ns`` with the ``max_t0_ns`` and
+        ``max_attrs`` of that longest phase, and ``sums`` — the running sum of
+        every numeric attribute, i.e. the engine's per-dispatch counts as
+        counters. A COPY as of the call (``sums`` too), a few microseconds for
+        the engine's dozen names; ``max_attrs`` is the record's own dict."""
+        with self._lock:
+            return {
+                name: {"count": t[0], "total_ns": t[1], "self_ns": t[2], "max_ns": t[3],
+                       "max_t0_ns": t[4], "max_attrs": t[5], "sums": dict(t[6])}
+                for name, t in self._totals.items() if name.startswith(prefix)}
+
+
+#: The process's phase ledger. Always on: there is no switch.
+PHASES = PhaseLedger()
+
+
+class Phase:
+    """Context manager behind :func:`phase`: the profiler's span and the
+    :data:`PHASES` record, opened and closed by the same two statements, so the
+    two have the same edges up to a constant offset between their clocks. It
+    reads the ledger's clock exactly twice; an exception inside it still closes
+    both."""
+
+    __slots__ = ("name", "attrs", "_annotation", "_t0", "_inner_ns")
+
+    def __init__(self, name: str, attrs: dict, annotation=None):
+        self.name, self.attrs = name, attrs
+        self._annotation = annotation if annotation is not None else (
+            jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **attrs))
+        self._inner_ns = 0
+
+    def __enter__(self) -> "Phase":
         self._annotation.__enter__()
-        if self.tracer is not None:
-            self.t0 = self.tracer._clock()
+        self._t0 = PHASE_CLOCK_NS()
+        PHASES._open.stack.append(self)
         return self
 
     def __exit__(self, *exc) -> None:
+        t1 = PHASE_CLOCK_NS()
         self._annotation.__exit__(*exc)
+        PHASES._close(self, t1)
 
     def set_metadata(self, **attrs) -> None:
+        """Attributes known only at the span's end: onto the profiler's span and
+        into the record (and its name's sums)."""
         self._annotation.set_metadata(**attrs)
+        self.attrs.update(attrs)
+
+
+def phase(name: str, **attrs) -> Phase:
+    """Context manager: the phase ``atpu.<name>`` with ``attrs``, into the
+    :data:`PHASES` ledger always and into a ``jax.profiler`` session's trace
+    while one is live. ``set_metadata(**attrs)`` on the entered object adds
+    values known only at the phase's end."""
+    return Phase(name, attrs)
+
+
+def step_phase(name: str, step_num: int) -> Phase:
+    """:func:`phase` for one step of a training loop: profiler tools group the
+    device's work by the ``step_num`` of the step span that dispatched it."""
+    return Phase(name, {"step_num": step_num}, jax.profiler.StepTraceAnnotation(
+        PHASE_PREFIX + name, step_num=step_num))
+
+
+class EnginePhase(Phase):
+    """:func:`phase` for an engine boundary that also emits :class:`Tracer`
+    records: ``t0`` on the tracer's clock, read only while that tracer is
+    enabled. ``tracer`` is None otherwise, so a boundary with tracing off reads
+    two attributes and no tracer clock (the overhead contract above); callers
+    emit their span records through :meth:`span`."""
+
+    __slots__ = ("tracer", "t0")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, **attrs):
+        super().__init__(name, attrs)
+        self.tracer = tracer if tracer is not None and tracer.enabled else None
+        self.t0 = 0.0
+
+    def __enter__(self) -> "EnginePhase":
+        super().__enter__()
+        if self.tracer is not None:
+            self.t0 = self.tracer._clock()
+        return self
 
     def now(self) -> float:
         """The tracer's clock (only while ``tracer`` is not None)."""

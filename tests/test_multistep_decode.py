@@ -397,42 +397,24 @@ def test_default_engine_dispatches_the_scan_and_warms_what_it_runs(
         assert [l for l in labels if "decode" in l] == [scan, scan], labels
 
 
-def test_one_step_of_the_default_engine_shows_the_four_decode_phases(
-        setup, monkeypatch):
+def test_one_step_of_the_default_engine_shows_the_four_decode_phases(setup):
     """The readers built on the engine's phases (docs/telemetry.md) see the default
     engine like any other: one ``step()`` is ``decode.prepare`` → ``dispatch`` →
     ``fetch`` → ``drain`` under an ``engine.decode`` with ``n_steps == 1``.
-    Recorded without a profiler session, by standing in for ``phase``."""
-    from accelerate_tpu import serving
-    from accelerate_tpu.telemetry import tracing
+    Read from the phase ledger: no profiler session, nothing stood in."""
+    from accelerate_tpu.telemetry.tracing import PHASES, PHASE_CLOCK_NS
 
-    class Recorded:
-        log = []
-
-        def __init__(self, name, **attrs):
-            self.name, self.attrs = name, attrs
-
-        def __enter__(self):
-            self.log.append(self)
-            return self
-
-        def __exit__(self, *exc):
-            return None
-
-        def set_metadata(self, **attrs):
-            self.attrs.update(attrs)
-
-    monkeypatch.setattr(serving, "phase", Recorded)
-    monkeypatch.setattr(tracing, "phase", Recorded)   # EnginePhase's annotation
     params, prompts = setup
     eng = make_engine(params)
     reqs = [eng.submit(p, max_new_tokens=5) for p in prompts[:2]]
+    since = PHASE_CLOCK_NS()
     eng.step()
-    names = [r.name for r in Recorded.log]
+    records = sorted(PHASES.records(since_ns=since), key=lambda r: r.t0_ns)
+    names = [r.name for r in records]
     inside = names[names.index("engine.decode") + 1:]
     assert inside == ["engine.decode.prepare", "engine.decode.dispatch",
                       "engine.decode.fetch", "engine.decode.drain"], names
-    by = {r.name: r.attrs for r in Recorded.log}
+    by = {r.name: r.attrs for r in records}
     assert by["engine.decode"] == {"lanes": 2, "n_steps": 1}
     assert by["engine.decode.drain"]["tokens"] == 2
     assert [len(r.tokens) for r in reqs] == [2, 2]   # the prefill's token + one
