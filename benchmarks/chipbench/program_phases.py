@@ -9,10 +9,6 @@ this module sees all of them. The records come back as ``program_spans.Span`` ob
 thread by thread, so a reader uses the same ``children`` / ``self_ns`` / ``descendants`` on
 either source. A program without a ledger — the parent of the PR that added it — gives an
 empty list, and every reader built on this returns ``None``.
-
-The readers' ten ``per_layer`` entries are ``testdata/window_entries.json`` until a
-``benchmark`` PR appends them to ``BENCHMARK.json`` (PERF.md 36.5); ``run.py --root <dir>``
-with such a file in ``<dir>`` reports them today.
 """
 
 from __future__ import annotations

@@ -395,8 +395,8 @@ def test_windows_and_readers_name_no_model():
 
 
 def test_a_model_type_without_a_family_names_the_file_it_looked_for(tmp_path):
-    with pytest.raises(FileNotFoundError, match=r"families/deepseek_v3\.py"):
-        run.load_family("deepseek_v3")
+    with pytest.raises(FileNotFoundError, match=r"families/no_such_model_type\.py"):
+        run.load_family("no_such_model_type")
     with pytest.raises(FileNotFoundError, match=str(tmp_path)):
         run.load_family("mistral", str(tmp_path))
 
@@ -535,12 +535,16 @@ def test_config_files_hold_published_widths_and_list_what_was_cut(entry):
     assert schema.entry_problems(BENCH, entry, ROOT) == []
 
 
+PINNED_ENTRY = next(e for e in BENCH["configs"] if e["source"] in schema.PINNED)
+
+
 def accepted_tree(tmp_path):
-    """A copy of the accepted configurations, their family and BENCHMARK.json in ``tmp_path``."""
+    """A copy of the accepted configurations, their family and BENCHMARK.json in ``tmp_path``;
+    → the file of the first configuration whose source's sizes ``schema.PINNED`` holds."""
     for sub in ("configs", "families"):
         shutil.copytree(os.path.join(run.HERE, sub), tmp_path / BENCH["paths"][0] / sub)
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(BENCH))
-    return tmp_path / BENCH["configs"][0]["file"]
+    return tmp_path / PINNED_ENTRY["file"]
 
 
 @pytest.mark.parametrize("key,value,problem", [
@@ -562,7 +566,7 @@ def test_schema_rules_bite_on_a_copy_of_the_accepted_tree(tmp_path, key, value, 
         assert problems == []
     else:
         assert len(problems) >= 1 and any(problem in p for p in problems), problems
-        assert all(p.startswith(BENCH["configs"][0]["name"] + ": ") for p in problems)
+        assert all(p.startswith(PINNED_ENTRY["name"] + ": ") for p in problems)
 
 
 # The catalog's DeepSeek-V3 row (model-configs guide; source below), cut as ISSUE 27 sizes it

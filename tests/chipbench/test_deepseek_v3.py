@@ -65,13 +65,10 @@ def test_the_configuration_is_the_source_outside_reduced_and_states_its_cut():
 
 
 def test_the_cell_is_entries_and_files_the_benchmark_did_not_have():
-    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (NAME, TRAFFIC, 1)
-    reported = {m["name"] for m in BENCH["end_to_end"] if run.applies(m, cell)}
-    assert reported == {"serve_tokens_per_s", "setup_s"}
-    own = {m["name"] for m in BENCH["per_layer"] if m.get("workloads") == [CELL]}
-    assert own == {"mla_attn_roofline", "mla_attn_ms_per_dispatch", "moe_pairs_per_token",
-                   "moe_max_over_mean_load"}
+    reported = {"serve_tokens_per_s", "setup_s"}
+    own = {"mla_attn_roofline", "mla_attn_ms_per_dispatch", "moe_pairs_per_token",
+           "moe_max_over_mean_load"}
+    assert schema.cell_problems(BENCH, CELL, NAME, TRAFFIC, 1, own, reported) == []
     assert not hasattr(FAMILY, "loss")        # serving rows only: no training cell on it
 
 
@@ -141,8 +138,15 @@ def test_dry_run_is_correct_and_its_float8_control_is_not(capsys):
     assert gap["value"] <= gap["limit"] < line["readings"]["control_fp8.served_logit_gap"]
 
 
-def test_dry_run_traced_reports_the_expert_counters(capsys):
-    line = dry(capsys, "--trace", "1", seconds="4")
+def test_dry_run_traced_reports_the_expert_counters(capsys, monkeypatch, tmp_path):
+    # Every traced run writes <ROOT>/.cb_trace, and the tests of other files trace too, on
+    # other workers: this one keeps its trace in a directory of its own.
+    from benchmarks.chipbench import program_spans
+
+    root = run.ROOT
+    monkeypatch.setattr(run, "ROOT", str(tmp_path))
+    monkeypatch.setattr(program_spans, "ROOT", str(tmp_path))
+    line = dry(capsys, "--trace", "1", "--root", root, seconds="4")
     assert {"moe_pairs_per_token", "moe_max_over_mean_load",
             "decode_occupancy_mean.throughput"} <= set(line["metrics"])
     # the dry run holds 8 of 16 experts at 4 a token: 2 pairs a token in expectation

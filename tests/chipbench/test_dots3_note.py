@@ -75,14 +75,12 @@ def test_the_configuration_is_the_source_outside_reduced_and_states_its_cut():
 
 
 def test_the_cell_is_entries_and_files_the_benchmark_did_not_have():
+    reported = {"serve_tokens_per_s", "setup_s"}
+    own = {"dsa_index_roofline", "dsa_index_ms_per_dispatch", "dsa_selected_share",
+           "mla_attn_roofline.sparse16k", "mla_attn_ms_per_dispatch.sparse16k",
+           "moe_pairs_per_token.sparse16k", "moe_max_over_mean_load.sparse16k"}
+    assert schema.cell_problems(BENCH, CELL, NAME, TRAFFIC, 1, own, reported) == []
     cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (NAME, TRAFFIC, 1)
-    reported = {m["name"] for m in BENCH["end_to_end"] if run.applies(m, cell)}
-    assert reported == {"serve_tokens_per_s", "setup_s"}
-    own = {m["name"] for m in BENCH["per_layer"] if m.get("workloads") == [CELL]}
-    assert own == {"dsa_index_roofline", "dsa_index_ms_per_dispatch", "dsa_selected_share",
-                   "mla_attn_roofline.sparse16k", "mla_attn_ms_per_dispatch.sparse16k",
-                   "moe_pairs_per_token.sparse16k", "moe_max_over_mean_load.sparse16k"}
     shared = {m["name"] for m in BENCH["per_layer"] if run.applies(m, cell)} - own
     assert shared and all(n.endswith(".throughput") for n in shared)
     assert not hasattr(FAMILY, "loss")        # serving rows only: no training cell on it

@@ -6,26 +6,22 @@ no ledger, and clips at the window's edges; a traced dry run of a serving cell p
 new entries from more samples than its slice holds. Nothing here is a measurement.
 """
 
+import copy
 import json
-import os
 import sys
 import types
 
 import pytest
 
 from accelerate_tpu.telemetry.tracing import PhaseRecord
-from benchmarks.chipbench import program_phases, program_spans, run, schema
+from benchmarks.chipbench import program_phases, program_spans, run, schema, traffic
 
-SPARSE, CHAT = "serve_dots3_sparse16k", "serve_mistral7b_chat"
+SPARSE, KEYE = "serve_dots3_sparse16k", "serve_keyevl2_sparse16k"
 with open(f"{run.ROOT}/BENCHMARK.json") as f:
     BENCH = json.load(f)
-with open(f"{run.HERE}/testdata/window_entries.json") as f:
-    ENTRIES = json.load(f)["per_layer"]
-NEW = ["engine_admit_share_window.latency", "engine_admit_share_window.throughput",
-       "engine_host_ms_per_step_window.latency", "engine_host_ms_per_step_window.throughput",
-       "decode_gap_ms_p99.latency", "decode_gap_ms_p99.throughput",
-       "prefill_ms_per_chunk.throughput", "moe_pairs_per_token_window.throughput",
-       "moe_max_over_mean_load_window.throughput", "dsa_selected_share_window.throughput"]
+SERVING = [w["name"] for w in BENCH["workloads"]
+           if traffic.load("traffic", w["traffic"])["window"] == "serve_window"]
+NEW = list(schema.WINDOW)
 MS = 1_000_000
 
 
@@ -78,7 +74,8 @@ def test_records_become_nested_spans_with_the_ledgers_self_times():
     r = run_with(records)
     assert program_phases.window(r) == (0, 1_000 * MS)
     assert [s.attrs["queued"] for s in program_phases.whole(r, "engine.step")] == [4, 3, 2]
-    first, last = spans[0], [s for s in spans if s.name == "engine.step"][-1]
+    first = spans[0]
+    last = max((s for s in spans if s.name == "engine.step"), key=lambda s: s.t0)
     assert program_phases.clipped(r, first) == first.t1 and first.t0 < 0         # cut at t0
     assert program_phases.clipped(r, last) == 30 * MS < last.dur                 # cut at t_close
     drain = [s for s in spans if s.name == "engine.decode.drain"][2]
@@ -171,44 +168,98 @@ def test_readers_return_none_under_three_samples_and_without_a_ledger(name, monk
     assert run.read_metric(name, r) is None and r.program_phases == []
 
 
+LONGCTX, CHAT = "serve_deepseekv3_longctx", "serve_mistral7b_chat"
+
+
+def anchors_hold(bench: dict) -> bool:
+    """The accepted cells stay in the lists the accepted file gave them, whatever cells are
+    appended to those lists: a subset, never the whole list, so that no name here pins one."""
+    by = {m["name"]: set(m["workloads"]) for m in bench["per_layer"] if m["name"] in NEW}
+    return ({LONGCTX, SPARSE, KEYE} <= by["moe_pairs_per_token_window.throughput"]
+            and {SPARSE, KEYE} <= by["dsa_selected_share_window.throughput"]
+            and CHAT in by["decode_gap_ms_p99.latency"]
+            and set(SERVING) - {CHAT} <= by["prefill_ms_per_chunk.throughput"])
+
+
 def test_the_ten_entries_are_data_beside_the_benchmark_which_stays_as_it_was():
-    """``BENCHMARK.json`` may only gain entries at its end, and ``test_keye_vl2.py`` pins the
-    keye cell's own eight as its last: the ten wait in ``testdata/window_entries.json`` for a
-    ``benchmark`` PR (PERF.md §7), spelt as they are to be appended."""
-    assert [m["name"] for m in ENTRIES] == NEW
-    assert not {m["name"] for m in BENCH["per_layer"]} & set(NEW)
-    cells = [w["name"] for w in BENCH["workloads"]]
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    layers = {m["layer"] for m in BENCH["per_layer"]}
-    for m in ENTRIES:
-        assert set(m) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        assert schema.NAME.match(m["name"]) and m["better"] == "lower" and m["layer"] in layers
-        assert m["workloads"] == [c for c in cells if c in m["workloads"]]      # the file's order
-        assert all(run.applies(e2e[m["moves"]], {"name": c}) for c in m["workloads"])
-        assert m["source"] == ("program_counter" if m["name"].split("_")[0] in ("moe", "dsa")
-                               else "host_clock")
-        reader = m["name"].split(".")[0]
-        assert (reader.endswith("_window") or reader in ("decode_gap_ms_p99", "prefill_ms_per_chunk"))
-        assert os.path.exists(f"{run.HERE}/metrics/{reader}.py")
-    by = {m["name"]: m["workloads"] for m in ENTRIES}
-    assert by["moe_pairs_per_token_window.throughput"] == cells[3:]           # several cells, one entry
-    assert by["dsa_selected_share_window.throughput"] == cells[4:]
-    assert by["decode_gap_ms_p99.latency"] == [CHAT] and by["prefill_ms_per_chunk.throughput"] == cells[2:]
+    """The ten entries are ``BENCHMARK.json``'s, each under its name, and each lists the cells
+    by what their configuration and traffic hold: nothing here reads a position."""
+    assert schema.window_entry_problems(BENCH, run.ROOT) == []
+    assert anchors_hold(BENCH)
+
+
+LATER, LATER_CONFIG = "serve_later_sparse16k", "later-serve-d5"
+
+
+def accepted(cell: str) -> tuple:
+    """``schema.cell_problems``'s arguments for an accepted cell, read by name from the
+    accepted ``BENCHMARK.json``: the entries it alone was listed in are its own."""
+    w = next(w for w in BENCH["workloads"] if w["name"] == cell)
+    own = {m["name"] for m in BENCH["per_layer"] if m.get("workloads") == [cell]}
+    reports = {m["name"] for m in BENCH["end_to_end"] if run.applies(m, w)}
+    return cell, w["config"], w["traffic"], w["chips"], own, reports
+
+
+JOINED = ("mla_attn_roofline", "dsa_index_roofline")      # longctx's and sparse16k's own
+LATER_OWN = {"dsa_index_roofline.later", "sparse_kv_attn_roofline.later"}
+
+
+def appended() -> dict:
+    """The accepted benchmark as a change that adds a model leaves it: keye's cell under a
+    new name and configuration name, on one chip, appended with its configuration entry and
+    two per-layer entries of its own; its name at the end of every list of a shared metric
+    it reports (``serve_tokens_per_s``, the ``.throughput`` and ``_window`` entries) and of
+    two accepted cells' own entries whose readers it shares, as a DeepSeek-V3.2 cell would
+    join longctx's latent attention and sparse16k's indexer."""
+    bench = copy.deepcopy(BENCH)
+    keye = next(w for w in bench["workloads"] if w["name"] == KEYE)
+    shared = [m for m in bench["end_to_end"] + bench["per_layer"]
+              if (KEYE in m.get("workloads", []) and len(m["workloads"]) > 1) or m["name"] in JOINED]
+    for m in shared:
+        m["workloads"].append(LATER)
+    entry = next(c for c in bench["configs"] if c["name"] == keye["config"])
+    bench["configs"].append({**entry, "name": LATER_CONFIG})
+    bench["workloads"].append({**keye, "name": LATER, "config": LATER_CONFIG, "chips": 1})
+    bench["per_layer"] += [{**m, "name": m["name"].split(".")[0] + ".later",
+                            "workloads": [LATER]} for m in bench["per_layer"]
+                           if m["name"] in ("dsa_index_roofline.keye16k", "sparse_kv_attn_roofline")]
+    return bench
+
+
+@pytest.mark.parametrize("cell", SERVING)
+def test_a_cell_appended_at_the_end_changes_no_accepted_cells_check(cell):
+    """Appending is how the benchmark grows: on the copy every check reads as on the accepted
+    file. A copy that changes one accepted entry instead (this cell's traffic, its name gone
+    from one of its own entries, or from a window entry's list) fails the check."""
+    later = appended()
+    assert schema.cell_problems(later, *accepted(cell)) == []
+    assert schema.cell_problems(later, *accepted(KEYE)) == []
+    assert schema.cell_problems(later, LATER, LATER_CONFIG, accepted(KEYE)[2], 1,
+                                LATER_OWN | set(JOINED), accepted(KEYE)[5]) == []
+    assert schema.window_entry_problems(later, run.ROOT) == []
+    assert anchors_hold(later)
+
+    moved, orphaned, dropped = appended(), appended(), appended()
+    w = next(w for w in moved["workloads"] if w["name"] == cell)
+    w["traffic"] = next(v["traffic"] for v in BENCH["workloads"] if v["traffic"] != w["traffic"])
+    own = min(accepted(cell)[4])
+    next(m for m in orphaned["per_layer"] if m["name"] == own)["workloads"] = [LATER]
+    next(m for m in dropped["per_layer"] if m["name"] in NEW
+         and cell in m["workloads"])["workloads"].remove(cell)
+    assert any("traffic" in p for p in schema.cell_problems(moved, *accepted(cell)))
+    assert any(own in p for p in schema.cell_problems(orphaned, *accepted(cell)))
+    assert any("lists" in p for p in schema.window_entry_problems(dropped, run.ROOT))
 
 
 def test_traced_dry_run_prints_the_new_entries_from_the_whole_window(capsys, monkeypatch, tmp_path):
-    """One serving cell end to end on the CPU, under a ``BENCHMARK.json`` with the ten entries
-    appended: its seven are in the line, each read from the ledger over the whole window, and
-    the five longest steps are on stderr."""
-    root = tmp_path / "root"
-    root.mkdir()
-    (root / "BENCHMARK.json").write_text(
-        json.dumps({**BENCH, "per_layer": BENCH["per_layer"] + ENTRIES}))
-    os.symlink(f"{run.ROOT}/benchmarks", root / "benchmarks")
+    """One serving cell end to end on the CPU under the checkout's own ``BENCHMARK.json``: its
+    seven entries of the ten are in the line, each read from the ledger over the whole window,
+    and the five longest steps are on stderr."""
+    root = run.ROOT
     monkeypatch.setattr(run, "ROOT", str(tmp_path))          # the trace: a directory of its own
     monkeypatch.setattr(program_spans, "ROOT", str(tmp_path))
     rc = run.main(["--workload", SPARSE, "--seed", "3100000017", "--seconds", "4",
-                   "--trace", "1", "--cpu-dry-run", "--root", str(root)])
+                   "--trace", "1", "--cpu-dry-run", "--root", root])
     captured = capsys.readouterr()
     line = json.loads(captured.out.strip().splitlines()[-1])
     want = {n for n in NEW if n.endswith(".throughput")}
