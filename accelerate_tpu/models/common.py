@@ -18,7 +18,7 @@ materializes in HBM).
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence
+from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -346,28 +346,62 @@ def multi_step_decode(forward_one: Callable, cache, tokens: jax.Array,
     return cache, tok_buf, counts
 
 
-def spec_multi_step_decode(forward_verify: Callable, propose: Callable,
+class SpecDraft(NamedTuple):
+    """A drafter the fused speculative scan runs on the device: ``state`` rides in the
+    scan's carry; ``propose(state, cache) -> proposals [B, spec_k]`` at the start of a
+    round; ``advance(state, cache, out, ref, n_emit, write_pos) -> (state, cache)`` after
+    its accept, given what the verify handed out beside its logits (``out``: the main
+    stack's hidden states for a model that drafts with its own module, else None), the
+    round's reference tokens [B, spec_k+1], each lane's emission count and the positions
+    the round wrote at."""
+    state: Any
+    propose: Callable
+    advance: Callable
+
+
+def history_draft(propose: Callable, history: jax.Array,
+                  hist_lens: jax.Array) -> SpecDraft:
+    """The drafter over each lane's token history (prompt + emissions, packed from
+    column 0): ``propose(history, hist_lens) -> proposals`` (the resident NgramDrafter is a
+    pure gather), and a round's emissions appended in-scan (columns past the history's
+    width drop), so round r+1 drafts from a context that holds round r's tokens."""
+    S = history.shape[1]
+
+    def advance(state, cache, _out, ref, n_emit, _write_pos):
+        hist, lens = state
+        k1 = ref.shape[1]
+        wi = jnp.where(
+            jnp.arange(k1, dtype=jnp.int32)[None, :] < n_emit[:, None],
+            lens[:, None] + jnp.arange(k1, dtype=jnp.int32)[None, :],
+            jnp.int32(S),
+        )
+        hist = hist.at[jnp.arange(ref.shape[0])[:, None], wi].set(ref)
+        return (hist, lens + n_emit), cache
+
+    return SpecDraft((history, hist_lens), lambda state, _cache: propose(*state), advance)
+
+
+def spec_multi_step_decode(forward_verify: Callable, draft: SpecDraft,
                            select_ref: Callable, cache, tokens: jax.Array,
                            positions: jax.Array, active: jax.Array,
                            budgets: jax.Array, eos_ids: jax.Array,
-                           key_tab: jax.Array, history: jax.Array,
-                           hist_lens: jax.Array, n_steps: int, spec_k: int,
+                           key_tab: jax.Array, n_steps: int, spec_k: int,
                            max_len: int):
     """N speculative rounds (draft → verify → accept) as ONE ``lax.scan`` — the
-    device-resident speculative super-step both decoder families'
-    ``forward_slots_spec_multi`` wrappers share. Composes :func:`multi_step_decode`'s
+    device-resident speculative super-step every decoder family's
+    ``forward_slots_spec_multi`` wrapper shares. Composes :func:`multi_step_decode`'s
     lane-freezing carry with the serving engine's host spec round
     (``serving._spec_step``), eliminating the per-round host round-trip.
 
-    Per scan step: ``propose(history, hist_lens) -> proposals [B, spec_k]``
-    drafts on device from the carried token history (prompt + all emissions so
-    far, packed from column 0 — the resident NgramDrafter is a pure gather);
-    the carried pending ``tokens`` [B] and the proposals form the ``[B, spec_k+1]``
-    verify sequence, written+attended at ``positions`` via
-    ``forward_verify(cache, seq, write_pos) -> (logits [B, spec_k+1, V], cache)``;
+    Per scan step: ``draft.propose`` gives ``proposals [B, spec_k]`` on device
+    (:class:`SpecDraft`: the history drafter of :func:`history_draft`, or a model's own
+    prediction module); the carried pending ``tokens`` [B] and the proposals form the
+    ``[B, spec_k+1]`` verify sequence, written+attended at ``positions`` via
+    ``forward_verify(cache, seq, write_pos) -> (logits [B, spec_k+1, V], cache, out)``;
     ``select_ref(logits, keys) -> ref [B, spec_k+1]`` picks the reference tokens
     (argmax for greedy lanes, the engine's replay sampler for sampled lanes);
-    acceptance is :func:`generation.speculative_prefix_accept`.
+    acceptance is :func:`generation.speculative_prefix_accept`; then ``draft.advance``
+    sees the round's outcome.
 
     The bitwise-parity linchpin is the per-lane emission-key CURSOR: sampled
     draws consume keys indexed by EMISSION count, and acceptance makes that
@@ -387,10 +421,6 @@ def spec_multi_step_decode(forward_verify: Callable, propose: Callable,
     slot and spans ``spec_k+1 ≥`` the garbage run) overwrites it — the PR-6
     garbage-above-rewind contract, now applied per scan round.
 
-    Accepted emissions are appended to the carried ``history`` in-scan (OOB
-    columns drop), so round r+1 drafts from a context that includes round r's
-    tokens — no host involvement at any point.
-
     Returns ``(cache, tok_buf [N, B, spec_k+1], emits [N, B], counts [B],
     proposed [B], accepted [B])``: per round, ``tok_buf[r, b, :emits[r, b]]``
     are lane b's real emissions (drain round-major, lane-minor to match the
@@ -401,18 +431,17 @@ def spec_multi_step_decode(forward_verify: Callable, propose: Callable,
     from ..generation import speculative_prefix_accept
 
     B = tokens.shape[0]
-    S = history.shape[1]
     k1 = spec_k + 1
     done0 = ~active
     zeros = jnp.zeros((B,), jnp.int32)
 
     def body(carry, _):
-        cache, hist, lens, tok, pos, done, count, proposed, accepted = carry
+        cache, state, tok, pos, done, count, proposed, accepted = carry
         live = ~done
-        props = propose(hist, lens)
+        props = draft.propose(state, cache)
         seq = jnp.concatenate([tok[:, None], props], axis=1)
         write_pos = jnp.where(done, jnp.int32(max_len), pos)
-        logits, cache = forward_verify(cache, seq, write_pos)
+        logits, cache, out = forward_verify(cache, seq, write_pos)
         # Emission-key cursor: lane b's j-th key this round is its (count+j)-th
         # emission key. The clip only guards the table edge — a live lane never
         # reads past n_steps*(spec_k+1)-1, and the window itself already clamps
@@ -426,27 +455,18 @@ def spec_multi_step_decode(forward_verify: Callable, propose: Callable,
         n_emit, last, hit_eos, n_acc = speculative_prefix_accept(
             props, ref, live, budgets - count, eos_ids
         )
-        # Append this round's emissions to the drafting history (columns past
-        # n_emit route to S — out of bounds, the scatter drops them).
-        wi = jnp.where(
-            jnp.arange(k1, dtype=jnp.int32)[None, :] < n_emit[:, None],
-            lens[:, None] + jnp.arange(k1, dtype=jnp.int32)[None, :],
-            jnp.int32(S),
-        )
-        hist = hist.at[jnp.arange(B)[:, None], wi].set(ref)
-        lens = lens + n_emit
+        state, cache = draft.advance(state, cache, out, ref, n_emit, write_pos)
         tok = jnp.where(n_emit > 0, last, tok)
         count = count + n_emit
         pos = pos + n_emit
         done = done | (live & (hit_eos | (count >= budgets)))
         proposed = proposed + jnp.where(live, jnp.int32(spec_k), 0)
         accepted = accepted + n_acc
-        carry = (cache, hist, lens, tok, pos, done, count, proposed, accepted)
+        carry = (cache, state, tok, pos, done, count, proposed, accepted)
         return carry, (ref, n_emit)
 
-    carry0 = (cache, history, hist_lens, tokens, positions, done0, zeros,
-              zeros, zeros)
-    (cache, _, _, _, _, _, counts, proposed, accepted), (tok_buf, emits) = (
+    carry0 = (cache, draft.state, tokens, positions, done0, zeros, zeros, zeros)
+    (cache, _, _, _, _, counts, proposed, accepted), (tok_buf, emits) = (
         jax.lax.scan(body, carry0, None, length=n_steps)
     )
     return cache, tok_buf, emits, counts, proposed, accepted

@@ -58,12 +58,19 @@ identity table → ``ops.paged_attention`` over that. The experts' router is dat
 The engine's surface (``serving.ContinuousBatcher`` reaches a model through the module
 of its config's class): ``init_cache`` + ``forward_cached`` (chunked prefill of one dense
 latent row), ``init_paged_cache`` + ``forward_slots_paged`` / ``forward_slots_multi``
-(paged decode), ``paged_walk_shape``, ``DECODE_COUNTERS``. Not here, so the engine
-refuses them for this model: dense decode rows (``forward_slots``), speculative verify
-(``forward_slots_spec_multi``), per-position prefill logits for the prefix cache
+(paged decode), ``forward_slots_spec_multi`` (speculative rounds over the pages: a
+verify of ``k + 1`` rows a lane, run as ``(k + 1) · B`` queries of the kernels that share
+their lane's table row, each with its own length), ``paged_walk_shape``,
+``DECODE_COUNTERS``. Not here, so the engine refuses them for this model: dense decode
+rows (``forward_slots``), per-position prefill logits for the prefix cache
 (``forward_cached_logits``). Layers are a Python list (``scan_layers`` is False: the
 first layer differs, and a pool carried through a scan's ``xs``/``ys`` is copied whole —
 here every write is a scatter on the donated carry).
+
+**A prediction module** (``mtp_draft``; ``models/deepseek_v32.py`` has the equations):
+its weights under ``params["mtp"]``, its latent and index-key planes as one more layer of
+the cache (:func:`cache_specs`), its rows written by prefill one slot behind the chunk's
+and, in decode, by the speculative rounds it drafts for (:func:`_module_draft`).
 """
 
 from __future__ import annotations
@@ -79,8 +86,9 @@ import numpy as np
 from ..ops.sparse_attention import (dsa_index_scores, dsa_index_scores_reference,
                                     index_block_pages, index_pool_shape, index_scores,
                                     write_index_paged)
-from .common import (cached_prefill_attention, kv_planes, latent_planes, latent_width, multi_step_decode, paged_latent_planes,
-                     paged_attention_dispatch, paged_kv_planes, paged_read_impl,
+from .common import (SpecDraft, cached_prefill_attention, kv_planes, latent_planes,
+                     latent_width, multi_step_decode, paged_attention_dispatch,
+                     paged_kv_planes, paged_latent_planes, paged_read_impl,
                      paged_write_coords, ring_pages, ring_tables, write_kv, write_kv_paged,
                      write_latent_paged)
 from .llama import _rms_norm
@@ -139,6 +147,9 @@ class DeepseekConfig:
     q_rescale: ClassVar[float] = 1.0
     kv_rescale: ClassVar[float] = 1.0
     counts_attention: ClassVar[bool] = False
+    #: prediction modules served as the drafter (``models/deepseek_v32.py``): 0 — none
+    #: is built, and the engine does not speculate unless asked to
+    mtp_draft: ClassVar[int] = 0
 
     @property
     def latent_dim(self) -> int:
@@ -213,6 +224,18 @@ def layer_specs(cfg) -> list:
     return [cfg.attn_spec(l) for l in range(cfg.n_layers)]
 
 
+def cache_specs(cfg) -> list:
+    """The specs of the cache's layers: the decoder's, then each prediction module's
+    (a layer of the model's own kind, ``cfg.attn_spec(n_layers)``) with planes of its own."""
+    return layer_specs(cfg) + [cfg.attn_spec(cfg.n_layers) for _ in range(mtp_draft(cfg))]
+
+
+def mtp_draft(cfg) -> int:
+    """The prediction modules ``cfg`` builds and drafts with (0: none; a config of another
+    class than :class:`DeepseekConfig` — dots3's, Keye's — has none)."""
+    return getattr(cfg, "mtp_draft", 0)
+
+
 def _pool_spec(cfg):
     """The spec of the layers whose cache lies in pool pages under the block tables."""
     return next(s for s in layer_specs(cfg) if not s.window)
@@ -236,12 +259,37 @@ def init_params(cfg, key: jax.Array) -> dict:
     "layers": [per-layer dict]}``; an expert layer holds ``"moe"``, a dense one
     ``w_gate/w_up/w_down``; a gated layer ``w_g``, an indexed one ``idx_wq`` / ``idx_wk``
     / ``idx_ww`` and its key norm; a grouped-query layer ``wq`` / ``wk`` / ``wv`` / ``wo`` and
-    its two head norms. A layer's attention shapes follow ITS spec."""
+    its two head norms. A layer's attention shapes follow ITS spec. A config with a
+    prediction module (``mtp_draft``) also holds ``"mtp"``: an expert layer's dict plus
+    ``enorm`` / ``hnorm`` / ``eh_proj`` [2D, D] (the embedding's half first) / ``ln_out``."""
+    D, dt = cfg.d_model, cfg.dtype
+    layers = [_init_layer(cfg, sp, key, l, l < cfg.n_dense_layers)
+              for l, sp in enumerate(layer_specs(cfg))]
+    ke, kh = jax.random.split(jax.random.fold_in(key, 1 << 20))
+    params = {"embed": _mat(ke, cfg.vocab_size, D, fan_in=D, dtype=dt),
+              "lm_head": _mat(kh, D, cfg.vocab_size, dtype=dt), "ln_f": jnp.ones((D,), dt),
+              "layers": layers}
+    if mtp_draft(cfg):
+        l = cfg.n_layers
+        params["mtp"] = {
+            **_init_layer(cfg, cfg.attn_spec(l), key, l, False),
+            "enorm": jnp.ones((D,), dt), "hnorm": jnp.ones((D,), dt),
+            "eh_proj": _mat(jax.random.fold_in(key, 1 << 21), 2 * D, D, dtype=dt),
+            "ln_out": jnp.ones((D,), dt)}
+    return params
+
+
+def _mat(k, *shape, fan_in=None, dtype):
+    return (jax.random.normal(k, shape, jnp.float32)
+            / math.sqrt(fan_in or shape[0])).astype(dtype)
+
+
+def _init_layer(cfg, sp, key, l: int, dense: bool) -> dict:
+    """Layer ``l``'s weights from ``key`` (:func:`init_params`)."""
     D, dt = cfg.d_model, cfg.dtype
 
     def mat(k, *shape, fan_in=None):
-        return (jax.random.normal(k, shape, jnp.float32)
-                / math.sqrt(fan_in or shape[0])).astype(dt)
+        return _mat(k, *shape, fan_in=fan_in, dtype=dt)
 
     def mlp(k, width, lead=()):
         ks = jax.random.split(k, 3)
@@ -249,54 +297,48 @@ def init_params(cfg, key: jax.Array) -> dict:
                 "w_up": mat(ks[1], *lead, D, width, fan_in=D),
                 "w_down": mat(ks[2], *lead, width, D, fan_in=width)}
 
-    layers = []
-    for l, sp in enumerate(layer_specs(cfg)):
-        ks = jax.random.split(jax.random.fold_in(key, l), 12)
-        kx = jax.random.split(jax.random.fold_in(key, (1 << 10) + l), 4)
-        H = sp.n_heads
-        layer = {"ln_attn": jnp.ones((D,), dt), "ln_mlp": jnp.ones((D,), dt)}
-        if sp.kind == "gqa":
-            hd = sp.head_dim
-            layer.update(
-                wq=mat(ks[0], D, H * hd), wk=mat(ks[1], D, sp.n_kv_heads * hd),
-                wv=mat(ks[2], D, sp.n_kv_heads * hd), wo=mat(ks[5], H * hd, D),
-                q_norm=jnp.ones((hd,), dt), k_norm=jnp.ones((hd,), dt))
-            idx_from = D                      # no query latent: the index queries come from h
-        else:
-            layer.update(
-                w_qa=mat(ks[0], D, sp.q_lora_rank), q_norm=jnp.ones((sp.q_lora_rank,), dt),
-                w_qb=mat(ks[1], sp.q_lora_rank, H * (sp.qk_nope_dim + sp.qk_rope_dim)),
-                w_kva=mat(ks[2], D, sp.latent_dim),
-                kv_norm=jnp.ones((sp.kv_lora_rank,), dt),
-                w_kb=mat(ks[3], sp.kv_lora_rank, H, sp.qk_nope_dim),
-                w_vb=mat(ks[4], sp.kv_lora_rank, H, sp.v_head_dim),
-                wo=mat(ks[5], H * sp.v_head_dim, D))
-            idx_from = sp.q_lora_rank
-        if sp.attn_gate:
-            layer["w_g"] = mat(kx[0], D, H)
-        if sp.index_topk:
-            layer.update(
-                idx_wq=mat(kx[1], idx_from, sp.index_heads * sp.index_dim),
-                idx_wk=mat(kx[2], D, sp.index_dim), idx_ww=mat(kx[3], D, sp.index_heads),
-                idx_k_gain=jnp.ones((sp.index_dim,), dt),
-                idx_k_bias=jnp.zeros((sp.index_dim,), dt))
-        if l < cfg.n_dense_layers:
-            layer.update(mlp(ks[6], cfg.d_ff))
-        else:
-            layer["moe"] = {
-                "router": mat(ks[7], D, cfg.n_routed_experts).astype(jnp.float32),
-                "experts": mlp(ks[10], cfg.moe_d_ff, lead=(cfg.experts_held,)),
-            }
-            if cfg.router == "sigmoid_grouped":
-                layer["moe"]["router_bias"] = 0.01 * jax.random.normal(
-                    ks[8], (cfg.n_routed_experts,), jnp.float32)
-            if cfg.n_shared_experts:
-                layer["moe"]["shared"] = mlp(ks[9], cfg.moe_d_ff * cfg.n_shared_experts)
-        layers.append(layer)
-    ke, kh = jax.random.split(jax.random.fold_in(key, 1 << 20))
-    return {"embed": mat(ke, cfg.vocab_size, D, fan_in=D),
-            "lm_head": mat(kh, D, cfg.vocab_size), "ln_f": jnp.ones((D,), dt),
-            "layers": layers}
+    ks = jax.random.split(jax.random.fold_in(key, l), 12)
+    kx = jax.random.split(jax.random.fold_in(key, (1 << 10) + l), 4)
+    H = sp.n_heads
+    layer = {"ln_attn": jnp.ones((D,), dt), "ln_mlp": jnp.ones((D,), dt)}
+    if sp.kind == "gqa":
+        hd = sp.head_dim
+        layer.update(
+            wq=mat(ks[0], D, H * hd), wk=mat(ks[1], D, sp.n_kv_heads * hd),
+            wv=mat(ks[2], D, sp.n_kv_heads * hd), wo=mat(ks[5], H * hd, D),
+            q_norm=jnp.ones((hd,), dt), k_norm=jnp.ones((hd,), dt))
+        idx_from = D                      # no query latent: the index queries come from h
+    else:
+        layer.update(
+            w_qa=mat(ks[0], D, sp.q_lora_rank), q_norm=jnp.ones((sp.q_lora_rank,), dt),
+            w_qb=mat(ks[1], sp.q_lora_rank, H * (sp.qk_nope_dim + sp.qk_rope_dim)),
+            w_kva=mat(ks[2], D, sp.latent_dim),
+            kv_norm=jnp.ones((sp.kv_lora_rank,), dt),
+            w_kb=mat(ks[3], sp.kv_lora_rank, H, sp.qk_nope_dim),
+            w_vb=mat(ks[4], sp.kv_lora_rank, H, sp.v_head_dim),
+            wo=mat(ks[5], H * sp.v_head_dim, D))
+        idx_from = sp.q_lora_rank
+    if sp.attn_gate:
+        layer["w_g"] = mat(kx[0], D, H)
+    if sp.index_topk:
+        layer.update(
+            idx_wq=mat(kx[1], idx_from, sp.index_heads * sp.index_dim),
+            idx_wk=mat(kx[2], D, sp.index_dim), idx_ww=mat(kx[3], D, sp.index_heads),
+            idx_k_gain=jnp.ones((sp.index_dim,), dt),
+            idx_k_bias=jnp.zeros((sp.index_dim,), dt))
+    if dense:
+        layer.update(mlp(ks[6], cfg.d_ff))
+    else:
+        layer["moe"] = {
+            "router": mat(ks[7], D, cfg.n_routed_experts).astype(jnp.float32),
+            "experts": mlp(ks[10], cfg.moe_d_ff, lead=(cfg.experts_held,)),
+        }
+        if cfg.router == "sigmoid_grouped":
+            layer["moe"]["router_bias"] = 0.01 * jax.random.normal(
+                ks[8], (cfg.n_routed_experts,), jnp.float32)
+        if cfg.n_shared_experts:
+            layer["moe"]["shared"] = mlp(ks[9], cfg.moe_d_ff * cfg.n_shared_experts)
+    return layer
 
 
 # ------------------------------------------------------------------------------- rotary
@@ -586,11 +628,18 @@ def _attend_selected_rows(q_nope, q_rope, latent, sel, ok, layer, spec):
     """Prefill form of a sparse layer: each query against ITS selected latent rows
     ``latent[b, sel[b,t]]`` in the absorbed form (the rows are per query, so there is
     nothing to up-project once for all), ``_QUERY_BLOCK`` queries' rows gathered at a
-    time. The cost does not grow with the row's fill. → o [B,T,H,v_head_dim]."""
+    time — a chunk of another length (a prediction module's, one row longer) padded to
+    whole blocks, whose filler queries select nothing. The cost does not grow with the
+    row's fill. → o [B,T,H,v_head_dim]."""
     B, T, H, _ = q_nope.shape
     dt, R, r = spec.dtype, spec.kv_lora_rank, spec.qk_rope_dim
     scale = sm_scale(spec)
-    qb = _QUERY_BLOCK if T % _QUERY_BLOCK == 0 else T
+    qb = min(_QUERY_BLOCK, T)
+    pad = -T % qb
+    if pad:
+        grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))  # noqa: E731
+        q_nope, q_rope, sel, ok = grow(q_nope), grow(q_rope), grow(sel), grow(ok)
+    T_in, T = T, T + pad
     q_lat = jnp.einsum("bthd,chd->bthc", q_nope, layer["w_kb"].astype(dt))
 
     def per_row(q_lat, q_rope, latent, sel, ok):          # one batch row
@@ -612,7 +661,7 @@ def _attend_selected_rows(q_nope, q_rope, latent, sel, ok, layer, spec):
         o = jax.lax.map(block, (split(q_lat), split(q_rope), split(sel), split(ok)))
         return o.reshape(T, H, R)
 
-    o_lat = jax.vmap(per_row)(q_lat, q_rope, latent, sel, ok)
+    o_lat = jax.vmap(per_row)(q_lat, q_rope, latent, sel, ok)[:, :T_in]
     return jnp.einsum("bthc,chd->bthd", o_lat, layer["w_vb"].astype(dt))
 
 
@@ -758,10 +807,24 @@ def _mlp(x, layer, cfg):
     return y.reshape(B, T, D), counts
 
 
-def _head(x, params, cfg):
-    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+def _head(x, params, cfg, norm=None):
+    """Logits fp32 of hidden states x: the final norm (``norm``: a prediction module's
+    own, before the SHARED head), then the head."""
+    x = _rms_norm(x, params["ln_f"] if norm is None else norm, cfg.norm_eps)
     with jax.named_scope("head"):
         return (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+
+
+def _module_input(params, hidden, next_tokens, cfg):
+    """A prediction module's input rows: ``u_i = W_eh [RMSNorm_e(E t_{i+1});
+    RMSNorm_h(h_i)]`` from the main stack's last hidden states ``hidden`` [B,T,D] (before
+    its final norm) and the tokens that follow them ``next_tokens`` [B,T]."""
+    m = params["mtp"]
+    with jax.named_scope("embed"):
+        e = params["embed"][next_tokens].astype(cfg.dtype)
+    eh = jnp.concatenate([_rms_norm(e, m["enorm"], cfg.norm_eps),
+                          _rms_norm(hidden.astype(cfg.dtype), m["hnorm"], cfg.norm_eps)], -1)
+    return eh @ m["eh_proj"].astype(cfg.dtype)
 
 
 # ------------------------------------------------------------- dense latent row: prefill
@@ -785,16 +848,29 @@ def init_cache(cfg, batch_size: int, max_len: int, dtype=None) -> dict:
     [B, C] bool, "index": int32}`` — the row chunked prefill fills and the engine then
     scatters into pool pages (``_row_planes`` has a layer's other leaves)."""
     dtype = dtype or cfg.dtype
-    return {"layers": [_row_planes(sp, batch_size, max_len, dtype)
-                       for sp in layer_specs(cfg)],
-            "valid": jnp.zeros((batch_size, max_len), jnp.bool_),
-            "index": jnp.zeros((), jnp.int32)}
+    cache = {"layers": [_row_planes(sp, batch_size, max_len, dtype)
+                        for sp in cache_specs(cfg)],
+             "valid": jnp.zeros((batch_size, max_len), jnp.bool_),
+             "index": jnp.zeros((), jnp.int32)}
+    if mtp_draft(cfg):
+        # the main stack's last hidden state before this chunk (a module row needs the
+        # token AFTER its position, so a chunk's module rows start one slot back), and
+        # the draft the module made at the last position
+        cache["mtp_h"] = jnp.zeros((batch_size, cfg.d_model), dtype)
+        cache["draft"] = jnp.zeros((batch_size,), jnp.int32)
+    return cache
 
 
-def _write_row(plane, row, index):
+def _write_row(plane, row, index, slots=None):
+    """Rows ``row`` [B,T,·] into the dense row ``plane`` [B,C,W] at ``index ..`` — or, given
+    ``slots`` [B,T], at those slots, where a negative one DROPS (a prediction module's rows
+    start one slot before the chunk's)."""
     row = jnp.pad(row.astype(plane.dtype),
                   ((0, 0), (0, 0), (0, plane.shape[-1] - row.shape[-1])))
-    return jax.lax.dynamic_update_slice(plane, row, (0, index, 0))
+    if slots is None:
+        return jax.lax.dynamic_update_slice(plane, row, (0, index, 0))
+    slots = jnp.where(slots >= 0, slots, plane.shape[1])
+    return plane.at[jnp.arange(plane.shape[0])[:, None], slots].set(row, mode="drop")
 
 
 def _gqa_rows(h, layer, kv, slots, streams, valid, index, spec):
@@ -849,23 +925,49 @@ def _forward_rows(params, tokens, cache, cfg, token_mask, last_only, positions=N
         y, _ = _mlp(x, layer, cfg)
         x = x + y
         new_layers.append(new)
-    if last_only:
-        x = x[:, -1:, :]
-    return _head(x, params, cfg), {"layers": new_layers, "valid": valid, "index": index + T}
+    logits = _head(x[:, -1:, :] if last_only else x, params, cfg)
+    out = {**cache, "layers": new_layers, "valid": valid, "index": index + T}
+    if not mtp_draft(cfg):
+        return logits, out
+    # The prediction module, a row a position: row i reads h_i and the token at i + 1, so
+    # this chunk's rows are the slots index - 1 .. index + T - 1 — the first from the
+    # hidden state the previous chunk left, the last from the token the chunk's last
+    # logits put first (the engine's first token where this is the prompt's last chunk;
+    # a later chunk writes that row again with the prompt's own token).
+    first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    hidden = jnp.concatenate([cache["mtp_h"][:, None].astype(x.dtype), x], 1)
+    nxt = jnp.concatenate([tokens, first[:, None]], 1)
+    m_slots = index - 1 + jnp.broadcast_to(jnp.arange(T + 1, dtype=jnp.int32), (B, T + 1))
+    spec = cfg.attn_spec(cfg.n_layers)
+    with jax.named_scope("mtp"):
+        u = _module_input(params, hidden, nxt, cfg)
+        h = _rms_norm(u, params["mtp"]["ln_attn"], cfg.norm_eps)
+        o, new = _latent_rows(h, params["mtp"], cache["layers"][cfg.n_layers], m_slots,
+                              valid, index - 1, spec, scatter=True)
+        u = u + o.reshape(B, T + 1, -1) @ params["mtp"]["wo"].astype(cfg.dtype)
+        u = u + _mlp(u, params["mtp"], cfg)[0]
+        m_logits = _head(u[:, -1:] if last_only else u, params, cfg, params["mtp"]["ln_out"])
+    out.update(layers=new_layers + [new], mtp_h=x[:, -1].astype(cache["mtp_h"].dtype),
+               draft=jnp.argmax(m_logits[:, -1], axis=-1).astype(jnp.int32))
+    if not last_only:
+        out["mtp_logits"] = m_logits[:, 1:]       # the rows of slots index .. index + T - 1
+    return logits, out
 
 
-def _latent_rows(h, layer, kv, positions, valid, index, spec):
+def _latent_rows(h, layer, kv, positions, valid, index, spec, scatter=False):
     """A latent layer's attention over a prefill chunk h [B,T,D] at ``positions`` [B,T]
-    (= the row slots it is written at) → (o [B,T,H,v_head_dim], the layer's new planes)."""
+    (= the row slots it is written at, from ``index``; ``scatter``: a slot below 0 is
+    dropped) → (o [B,T,H,v_head_dim], the layer's new planes)."""
     T = h.shape[1]
+    slots = positions if scatter else None
     q_nope, q_rope, row, c_q = _mla_project(h, layer, positions, spec)
     name = "ring" if spec.window else "latent"
     with jax.named_scope("kv_write"):
-        new = {name: _write_row(kv[name], row, index)}
+        new = {name: _write_row(kv[name], row, index, slots)}
     if spec.index_topk:
         with jax.named_scope("dsa_select"):
             q_idx, k_idx, w_idx = _index_project(h, c_q, layer, positions, spec)
-            new["index_k"] = _write_row(kv["index_k"], k_idx, index)
+            new["index_k"] = _write_row(kv["index_k"], k_idx, index, slots)
             sel, ok = _select_rows(q_idx, w_idx, new["index_k"], positions, valid,
                                    index + T, spec)
         return _attend_selected_rows(q_nope, q_rope, new["latent"], sel, ok, layer, spec), new
@@ -892,6 +994,15 @@ def forward(params: dict, tokens: jax.Array, cfg, positions=None) -> jax.Array:
     they differ (image and video tokens); the default is text — every row the index."""
     cache = init_cache(cfg, tokens.shape[0], tokens.shape[1])
     return _forward_rows(params, tokens, cache, cfg, None, False, positions)[0]
+
+
+def forward_mtp(params: dict, tokens: jax.Array, cfg) -> tuple:
+    """(logits [B,S,V], the prediction module's logits [B,S,V]) fp32 of a whole sequence:
+    module row ``i`` reads the main stack's ``h_i`` and ``tokens[i + 1]`` and scores the
+    token at ``i + 2``; the last row reads the token the main logits put first."""
+    cache = init_cache(cfg, tokens.shape[0], tokens.shape[1])
+    logits, cache = _forward_rows(params, tokens, cache, cfg, None, False)
+    return logits, cache["mtp_logits"]
 
 
 # ------------------------------------------------------------------ latent pages: decode
@@ -922,8 +1033,11 @@ def init_paged_cache(cfg, batch_size: int, max_len: int, num_pages: int,
                 index_pool_shape(num_pages, page_size, sp.index_dim), dtype)
         return out
 
-    return {"layers": [planes(sp) for sp in layer_specs(cfg)],
-            "valid": jnp.zeros((batch_size, max_len), jnp.bool_)}
+    cache = {"layers": [planes(sp) for sp in cache_specs(cfg)],
+             "valid": jnp.zeros((batch_size, max_len), jnp.bool_)}
+    if mtp_draft(cfg):
+        cache["draft"] = jnp.zeros((batch_size,), jnp.int32)   # each lane's next draft
+    return cache
 
 
 def paged_walk_shape(cfg, page_size: int, itemsize: int, max_pages: int) -> tuple:
@@ -938,94 +1052,153 @@ def paged_walk_shape(cfg, page_size: int, itemsize: int, max_pages: int) -> tupl
     return mla_block_pages(page_size, latent_width(spec.latent_dim), itemsize, max_pages), 0
 
 
+def _read_route(tables, positions, valid, spec, T: int, page_size: int, num_pages: int):
+    """How a layer of ``spec``'s window reaches its pages for ``T`` rows a lane at
+    ``positions`` .. ``+ T - 1``: (the write coordinates [B,T], and for the reads — ONE row
+    of the kernels' a query, ``B · T`` of them sharing their lane's table row — the tables,
+    the valid rows, the positions and each row's live keys: 0 past ``max_len``)."""
+    B, max_len = valid.shape
+    grid = positions[:, None] + jnp.arange(T, dtype=jnp.int32)
+    pos = grid.reshape(B * T)
+    n_live = jnp.where(grid < max_len, grid - jnp.argmax(valid, axis=1)[:, None] + 1, 0)
+    tab, seen = tables, valid
+    if spec.window:
+        if T > 1:
+            raise NotImplementedError("a sliding layer's ring reads one query a lane")
+        tab = ring_tables(positions, tables.shape[1], page_size, spec.window)
+        seen = valid & (jnp.arange(max_len)[None, :] > positions[:, None] - spec.window)
+    coords = paged_write_coords(tab, grid, page_size, max_len, num_pages)
+    if T > 1:
+        tab, seen = jnp.repeat(tab, T, axis=0), jnp.repeat(seen, T, axis=0)
+    return coords, tab, seen, pos, n_live.reshape(B * T)
+
+
+def _decode_layer(x, layer, kv, spec, route, page_size: int, max_len: int):
+    """One layer's attention over T tokens a lane, x [B,T,D] — the rows written through
+    ``route`` (:func:`_read_route`) and each attended at its own position → (x plus the
+    attention's output, the layer's new planes, its counts [3]: live keys the indexer
+    scored, rows the selection handed on, rows a window read)."""
+    B, T, _ = x.shape
+    (pages, offs), tab, seen, pos, n_live = route
+    gqa = spec.kind == "gqa"
+    name = "k" if gqa else "ring" if spec.window else "latent"
+    rows = lambda a: a.reshape(B * T, *a.shape[2:])                    # noqa: E731
+    counts = [jnp.int32(0)] * 3
+    with jax.named_scope("gqa" if gqa else "mla"):
+        h = _rms_norm(x, layer["ln_attn"], spec.norm_eps)
+        if gqa:
+            q, k, v = _gqa_project(h, layer, (pos.reshape(B, T))[None], spec)
+            c_q = h
+            with jax.named_scope("kv_write"):
+                new = {**write_kv_paged(kv, "k", k, pages, offs),
+                       **write_kv_paged(kv, "v", v, pages, offs)}
+        else:
+            q_nope, q_rope, row, c_q = _mla_project(h, layer, pos.reshape(B, T), spec)
+            with jax.named_scope("kv_write"):
+                new = {name: write_latent_paged({"latent": kv[name]}, row, pages,
+                                                offs)["latent"]}
+        if spec.index_topk:
+            q_idx, k_idx, w_idx = _index_project(h, c_q, layer, pos.reshape(B, T), spec)
+            new["index_k"] = write_index_paged(kv["index_k"], k_idx, pages, offs)
+            if gqa:
+                o = _gqa_attend_pages(rows(q), rows(q_idx), rows(w_idx), new, tab, pos,
+                                      seen, page_size, spec)
+            else:
+                o = _attend_selected_pages(rows(q_nope), rows(q_rope), rows(q_idx),
+                                           rows(w_idx), new, tab, pos, seen, page_size,
+                                           layer, spec)
+            counts[0] = n_live.sum()
+            counts[1] = jnp.minimum(n_live, spec.index_topk).sum()
+        elif gqa:
+            qr = rows(q)[:, None]
+            o = paged_attention_dispatch(
+                qr, new, tab, pos, seen, page_size=page_size,
+                sm_scale=spec.head_dim ** -0.5, dtype=spec.dtype,
+                dense_attention=lambda ck, cv: _gqa_attend_dense(
+                    qr, ck, cv, (seen & (jnp.arange(max_len)[None, :]
+                                         <= pos[:, None]))[:, None, :]))[:, 0]
+        else:
+            o = _attend_latent_pages(rows(q_nope), rows(q_rope), new[name], tab, pos, seen,
+                                     page_size, layer, spec)
+            if spec.window:
+                counts[2] = jnp.minimum(n_live, spec.window).sum()
+        o = _gate(o.reshape(B, T, *o.shape[1:]), h, layer, spec)
+        x = x + o.reshape(B, T, -1) @ layer["wo"].astype(spec.dtype)
+    return x, new, jnp.stack(counts).astype(jnp.int32)
+
+
 def _forward_slots(params, tokens, cache, tables, positions, cfg, page_size: int):
-    """One decode step: lane b's token written and attended at ``positions[b]`` →
-    (logits [B,V] fp32, cache, counts int32[3 or 6]: the MoE counts summed over the
-    expert layers, then :data:`ATTENTION_COUNTERS` if the config counts them)."""
-    B = tokens.shape[0]
+    """One decode forward of T tokens a lane, tokens [B,T]: lane b's written and attended
+    at ``positions[b] ..`` ``+ T - 1`` (each row sees the rows before it and itself) →
+    (logits [B,T,V] fp32, cache, counts int32[3 or 6]: the MoE counts summed over the
+    expert layers, then :data:`ATTENTION_COUNTERS` if the config counts them; the main
+    stack's last hidden states x [B,T,D], before the final norm — a prediction module
+    reads them). Cache leaves past the decoder's layers (a module's planes, the lanes'
+    drafts) pass through untouched."""
+    B, T = tokens.shape
     max_len = cache["valid"].shape[1]
-    valid = cache["valid"].at[jnp.arange(B), positions].set(True)
-    live = positions < max_len                      # a frozen lane is parked at max_len
-    n_live = jnp.where(live, positions - jnp.argmax(valid, axis=1) + 1, 0)
+    grid = positions[:, None] + jnp.arange(T, dtype=jnp.int32)
+    valid = cache["valid"].at[jnp.arange(B)[:, None], grid].set(True)
     with jax.named_scope("embed"):
-        x = params["embed"][tokens[:, None]].astype(cfg.dtype)              # [B,1,D]
+        x = params["embed"][tokens].astype(cfg.dtype)                        # [B,T,D]
     counts = jnp.zeros((3,), jnp.int32)
-    attn_counts = [jnp.int32(0)] * 3
-    routes = {}                 # window → (tables, valid, write coordinates), once a kind
+    attn_counts = jnp.zeros((3,), jnp.int32)
+    routes = {}                 # window → _read_route(...), once a kind
     new_layers = []
     for l, (layer, kv) in enumerate(zip(params["layers"], cache["layers"])):
         spec = cfg.attn_spec(l)
-        gqa = spec.kind == "gqa"
-        name = "k" if gqa else "ring" if spec.window else "latent"
         if spec.window not in routes:
-            tab, seen = tables, valid
-            if spec.window:
-                tab = ring_tables(positions, tables.shape[1], page_size, spec.window)
-                seen = valid & (jnp.arange(max_len)[None, :]
-                                > positions[:, None] - spec.window)
-            routes[spec.window] = (tab, seen, paged_write_coords(
-                tab, positions[:, None], page_size, max_len, kv[name].shape[0]))
-        tab, seen, (pages, offs) = routes[spec.window]
-        with jax.named_scope("gqa" if gqa else "mla"):
-            h = _rms_norm(x, layer["ln_attn"], cfg.norm_eps)
-            if gqa:
-                q, k, v = _gqa_project(h, layer, positions[None, :, None], spec)
-                c_q = h
-                with jax.named_scope("kv_write"):
-                    new = {**write_kv_paged(kv, "k", k, pages, offs),
-                           **write_kv_paged(kv, "v", v, pages, offs)}
-            else:
-                q_nope, q_rope, row, c_q = _mla_project(h, layer, positions[:, None], spec)
-                with jax.named_scope("kv_write"):
-                    new = {name: write_latent_paged({"latent": kv[name]}, row, pages,
-                                                    offs)["latent"]}
-            if spec.index_topk:
-                q_idx, k_idx, w_idx = _index_project(h, c_q, layer, positions[:, None], spec)
-                new["index_k"] = write_index_paged(kv["index_k"], k_idx, pages, offs)
-                if gqa:
-                    o = _gqa_attend_pages(q[:, 0], q_idx[:, 0], w_idx[:, 0], new, tab,
-                                          positions, seen, page_size, spec)
-                else:
-                    o = _attend_selected_pages(q_nope[:, 0], q_rope[:, 0], q_idx[:, 0],
-                                               w_idx[:, 0], new, tab, positions, seen,
-                                               page_size, layer, spec)
-                attn_counts[0] += n_live.sum()
-                attn_counts[1] += jnp.minimum(n_live, spec.index_topk).sum()
-            elif gqa:
-                o = paged_attention_dispatch(
-                    q, new, tab, positions, seen, page_size=page_size,
-                    sm_scale=spec.head_dim ** -0.5, dtype=spec.dtype,
-                    dense_attention=lambda ck, cv, q=q: _gqa_attend_dense(
-                        q, ck, cv, (seen & (jnp.arange(max_len)[None, :]
-                                            <= positions[:, None]))[:, None, :]))[:, 0]
-            else:
-                o = _attend_latent_pages(q_nope[:, 0], q_rope[:, 0], new[name], tab,
-                                         positions, seen, page_size, layer, spec)
-                if spec.window:
-                    attn_counts[2] += jnp.minimum(n_live, spec.window).sum()
-            o = _gate(o, h[:, 0], layer, spec)
-            x = x + (o.reshape(B, -1) @ layer["wo"].astype(cfg.dtype))[:, None]
+            name = "k" if spec.kind == "gqa" else "ring" if spec.window else "latent"
+            routes[spec.window] = _read_route(tables, positions, valid, spec, T, page_size,
+                                              kv[name].shape[0])
+        x, new, c = _decode_layer(x, layer, kv, spec, routes[spec.window], page_size,
+                                  max_len)
+        attn_counts = attn_counts + c
         y, c = _mlp(x, layer, cfg)
         x = x + y
         counts = counts + c
         new_layers.append(new)
     if cfg.counts_attention:
-        counts = jnp.concatenate([counts, jnp.stack(attn_counts).astype(jnp.int32)])
-    return _head(x, params, cfg)[:, 0], {"layers": new_layers, "valid": valid}, counts
+        counts = jnp.concatenate([counts, attn_counts])
+    new = {**cache, "layers": new_layers + cache["layers"][len(new_layers):], "valid": valid}
+    return _head(x, params, cfg), new, counts, x
+
+
+def _module_slots(params, hidden, next_tokens, cache, tables, positions, cfg,
+                  page_size: int):
+    """The prediction module over the rows a verify just wrote, T a lane: row ``j`` of
+    lane b at ``positions[b] + j`` from the main stack's hidden state there ``hidden``
+    [B,T,D] and the token that follows it ``next_tokens`` [B,T], through the module's own
+    latent and index-key planes (``cache["layers"][n_layers]``, the valid rows the verify
+    left) → (the module's hidden states [B,T,D] before its norm, the cache with its planes
+    written, counts as :func:`_forward_slots`'s)."""
+    B, T, _ = hidden.shape
+    L, max_len = cfg.n_layers, cache["valid"].shape[1]
+    spec, kv = cfg.attn_spec(L), cache["layers"][L]
+    route = _read_route(tables, positions, cache["valid"], spec, T, page_size,
+                        kv["latent"].shape[0])
+    with jax.named_scope("mtp"):
+        u = _module_input(params, hidden, next_tokens, cfg)
+        u, new, attn_counts = _decode_layer(u, params["mtp"], kv, spec, route, page_size,
+                                            max_len)
+        y, counts = _mlp(u, params["mtp"], cfg)
+    if cfg.counts_attention:
+        counts = jnp.concatenate([counts, attn_counts])
+    layers = list(cache["layers"])
+    layers[L] = new
+    return u + y, {**cache, "layers": layers}, counts
 
 
 def forward_slots_paged(params: dict, tokens: jax.Array, cache: dict, tables: jax.Array,
                         positions: jax.Array, cfg, page_size: int):
-    """Per-lane decode step over the paged latent cache: ``tokens`` [B,1] written at each
-    lane's own ``positions[b]`` through its block-table row (sentinel entries and
-    positions at ``max_len`` DROP) → (logits [B,1,V] fp32, new cache)."""
-    if tokens.shape[1] != 1:
-        raise NotImplementedError(
-            "deepseek.forward_slots_paged decodes one token a lane (the latent kernel "
-            "takes one query); a multi-token verify is not implemented")
-    logits, cache, _ = _forward_slots(params, tokens[:, 0], cache, tables, positions, cfg,
-                                      page_size)
-    return logits[:, None], cache
+    """Per-lane decode over the paged latent cache: ``tokens`` [B,T] written at each lane's
+    own ``positions[b] ..`` through its block-table row (sentinel entries and positions at
+    or past ``max_len`` DROP), each row attended at its own position — T > 1 is the
+    speculative verify, its rows run as ``B · T`` queries of the kernels, each with its
+    own length over its lane's table row → (logits [B,T,V] fp32, new cache)."""
+    logits, cache, _, _ = _forward_slots(params, tokens, cache, tables, positions, cfg,
+                                         page_size)
+    return logits, cache
 
 
 def forward_slots_multi(params: dict, cache: dict, tokens: jax.Array,
@@ -1037,17 +1210,17 @@ def forward_slots_multi(params: dict, cache: dict, tokens: jax.Array,
     freeze/emission contract is the shared one). The latent pool rides in the scan's
     CARRY and is written in place. Returns ``(cache, tok_buf [n_steps, B], counts [B],
     model counts int32[3 or 6])`` — the last is the module's ``DECODE_COUNTERS``,
-    summed over the steps."""
+    summed over the steps. A prediction module's planes and drafts ride along
+    untouched: they are the speculative path's."""
     if tables is None:
         raise NotImplementedError("deepseek decodes over the paged latent cache only")
     max_len = cache["valid"].shape[1]
     n_counts = 3 + 3 * cfg.counts_attention
 
     def forward_one(c, tok, write_pos):
-        logits, new, counts = _forward_slots(
-            params, tok, {"layers": c["layers"], "valid": c["valid"]}, tables, write_pos,
-            cfg, page_size)
-        return logits, {**new, "moe_counts": c["moe_counts"] + counts}
+        logits, new, counts, _ = _forward_slots(params, tok[:, None], c, tables, write_pos,
+                                                cfg, page_size)
+        return logits[:, 0], {**new, "moe_counts": c["moe_counts"] + counts}
 
     carry = {**cache, "moe_counts": jnp.zeros((n_counts,), jnp.int32)}
     carry, tok_buf, counts = multi_step_decode(
@@ -1055,3 +1228,69 @@ def forward_slots_multi(params: dict, cache: dict, tokens: jax.Array,
         xs, n_steps, max_len)
     moe_counts = carry.pop("moe_counts")
     return carry, tok_buf, counts, moe_counts
+
+
+def _module_draft(params, cfg, tables, page_size: int) -> SpecDraft:
+    """The prediction module as the fused scan's drafter: the round's draft is the lane's
+    carried ``cache["draft"]``; after the accept, the module runs over the rows the verify
+    wrote (``p .. p + k``) with the hidden states there and the ACCEPTED tokens that follow
+    them — a row past the accepted prefix reads a rejected token and is written again the
+    next round, whose rows start at it — and the next draft is the module's first choice
+    at row ``p + n - 1``, the row before the new pending token. A lane that emitted nothing
+    keeps its draft; its writes dropped at ``max_len``."""
+    def propose(_state, c):
+        return c["draft"][:, None]
+
+    def advance(_state, c, hidden, ref, n_emit, write_pos):
+        u, c, counts = _module_slots(params, hidden, ref, c, tables, write_pos, cfg,
+                                     page_size)
+        at = jnp.maximum(n_emit - 1, 0)[:, None, None]
+        last = jnp.take_along_axis(u, at, axis=1)                          # [B,1,D]
+        with jax.named_scope("mtp"):
+            logits = _head(last, params, cfg, params["mtp"]["ln_out"])[:, 0]
+        draft = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return None, {**c, "draft": jnp.where(n_emit > 0, draft, c["draft"]),
+                      "moe_counts": c["moe_counts"] + counts}
+
+    return SpecDraft(None, propose, advance)
+
+
+def forward_slots_spec_multi(params: dict, cache: dict, tokens: jax.Array,
+                             positions: jax.Array, active: jax.Array, budgets: jax.Array,
+                             eos_ids: jax.Array, propose, select_ref, key_tab: jax.Array,
+                             history, hist_lens, n_steps: int, spec_k: int, cfg,
+                             tables: Optional[jax.Array] = None, page_size: int = 0):
+    """``n_steps`` speculative rounds as one scan over the paged latent pool
+    (``common.spec_multi_step_decode``: the accept / key-cursor / freeze contract is the
+    shared one). Each round's verify is :func:`forward_slots_paged` over ``[B, spec_k +
+    1]``. ``propose`` None drafts with the model's own prediction module
+    (:func:`_module_draft`, ``spec_k`` = ``cfg.mtp_draft``; ``history`` unused), else
+    ``propose(history, hist_lens)`` over the carried token history. Returns ``(cache,
+    tok_buf [n_steps, B, spec_k+1], emits [n_steps, B], counts [B], proposed [B],
+    accepted [B], model counts int32[3 or 6])`` — the last summed over the rounds, the
+    verify's and the module's rows alike."""
+    from .common import history_draft, spec_multi_step_decode
+
+    if tables is None:
+        raise NotImplementedError("deepseek decodes over the paged latent cache only")
+    max_len = cache["valid"].shape[1]
+    n_counts = 3 + 3 * cfg.counts_attention
+
+    def forward_verify(c, seq, write_pos):
+        logits, new, counts, hidden = _forward_slots(params, seq, c, tables, write_pos, cfg,
+                                                     page_size)
+        return logits, {**new, "moe_counts": c["moe_counts"] + counts}, hidden
+
+    if propose is None:
+        if spec_k != cfg.mtp_draft:
+            raise ValueError(f"the prediction module drafts {cfg.mtp_draft} token(s) a "
+                             f"round; spec_k={spec_k}")
+        draft = _module_draft(params, cfg, tables, page_size)
+    else:
+        draft = history_draft(propose, history, hist_lens)
+    carry = {**cache, "moe_counts": jnp.zeros((n_counts,), jnp.int32)}
+    carry, tok_buf, emits, counts, proposed, accepted = spec_multi_step_decode(
+        forward_verify, draft, select_ref, carry, tokens, positions, active, budgets,
+        eos_ids, key_tab, n_steps, spec_k, max_len)
+    moe_counts = carry.pop("moe_counts")
+    return carry, tok_buf, emits, counts, proposed, accepted, moe_counts

@@ -1739,18 +1739,18 @@ def forward_slots_spec_multi(
     :func:`~.common.spec_multi_step_decode` for the accept/key-cursor/freeze
     contract. Returns ``(cache, tok_buf [n_steps, B, spec_k+1], emits
     [n_steps, B], counts [B], proposed [B], accepted [B])``."""
-    from .common import spec_multi_step_decode
+    from .common import history_draft, spec_multi_step_decode
 
     max_len = cache["valid"].shape[1]
 
     def forward_verify(c, seq, write_pos):
-        return forward_slots(
+        return (*forward_slots(
             params, seq, c, write_pos, cfg, tables=tables, page_size=page_size
-        )
+        ), None)
 
     return spec_multi_step_decode(
-        forward_verify, propose, select_ref, cache, tokens, positions, active,
-        budgets, eos_ids, key_tab, history, hist_lens, n_steps, spec_k, max_len,
+        forward_verify, history_draft(propose, history, hist_lens), select_ref, cache,
+        tokens, positions, active, budgets, eos_ids, key_tab, n_steps, spec_k, max_len,
     )
 
 

@@ -120,8 +120,10 @@ _PATH_CALLS = {
     # the one-token body of the scan over dense rows
     "dense rows (page_size=0)": ("forward_slots",),
     "paged": ("init_paged_cache", "paged_walk_shape"),
-    # the host loop's verify, dense and paged, and the fused rounds
-    "spec_k": ("forward_slots", "forward_slots_paged", "forward_slots_spec_multi"),
+    # the fused rounds, and the host loop's verify in the engine's own layout
+    "spec_k": ("forward_slots_spec_multi",),
+    "spec_k over dense rows": ("forward_slots",),
+    "spec_k over pages": ("forward_slots_paged",),
 }
 
 
@@ -464,14 +466,14 @@ def _spec_multi_step(params, cache, tokens, positions, active, budgets, eos_ids,
     propose = lambda hist, lens: ngram_propose_resident(  # noqa: E731
         hist, lens, spec_k, max_ngram)
     select_ref = _spec_multi_select(sample, temps, top_ps, top_ks)
-    cache, tok_buf, emits, counts, proposed, accepted = (
+    cache, tok_buf, emits, counts, proposed, accepted, *model_counts = (
         _model(cfg).forward_slots_spec_multi(
             params, cache, tokens, positions, active, budgets, eos_ids,
             propose, select_ref, key_tab, history, hist_lens, n_steps, spec_k,
             cfg,
         )
     )
-    return tok_buf, emits, counts, proposed, accepted, cache
+    return (tok_buf, emits, counts, proposed, accepted, cache, *model_counts)
 
 
 @partial(jax.jit,
@@ -492,14 +494,52 @@ def _spec_multi_step_paged(params, cache, tables, tokens, positions, active,
     propose = lambda hist, lens: ngram_propose_resident(  # noqa: E731
         hist, lens, spec_k, max_ngram)
     select_ref = _spec_multi_select(sample, temps, top_ps, top_ks)
-    cache, tok_buf, emits, counts, proposed, accepted = (
+    cache, tok_buf, emits, counts, proposed, accepted, *model_counts = (
         _model(cfg).forward_slots_spec_multi(
             params, cache, tokens, positions, active, budgets, eos_ids,
             propose, select_ref, key_tab, history, hist_lens, n_steps, spec_k,
             cfg, tables=tables, page_size=page_size,
         )
     )
-    return tok_buf, emits, counts, proposed, accepted, cache
+    return (tok_buf, emits, counts, proposed, accepted, cache, *model_counts)
+
+
+@partial(jax.jit, static_argnames=("cfg", "n_steps", "spec_k", "sample"),
+         donate_argnums=(1,))
+def _spec_module_step(params, cache, tokens, positions, active, budgets, eos_ids,
+                      key_tab, temps, top_ps, top_ks, cfg, n_steps: int, spec_k: int,
+                      sample: bool):
+    """``n_steps`` speculative rounds as ONE dispatched program whose drafter is the
+    model's own prediction module (``spec_decode.ModuleDrafter``): each lane's draft and
+    the module's rows live in the cache, so no history rides along. → (tok_buf [N, B,
+    spec_k+1], emits [N, B], counts [B], proposed [B], accepted [B], new cache, the
+    model's counts)."""
+    select_ref = _spec_multi_select(sample, temps, top_ps, top_ks)
+    cache, tok_buf, emits, counts, proposed, accepted, *model_counts = (
+        _model(cfg).forward_slots_spec_multi(
+            params, cache, tokens, positions, active, budgets, eos_ids,
+            None, select_ref, key_tab, None, None, n_steps, spec_k, cfg,
+        )
+    )
+    return (tok_buf, emits, counts, proposed, accepted, cache, *model_counts)
+
+
+@partial(jax.jit, static_argnames=("cfg", "n_steps", "spec_k", "sample", "page_size"),
+         donate_argnums=(1,))
+def _spec_module_step_paged(params, cache, tables, tokens, positions, active, budgets,
+                            eos_ids, key_tab, temps, top_ps, top_ks, cfg, n_steps: int,
+                            spec_k: int, sample: bool, page_size: int):
+    """:func:`_spec_module_step` over the PAGED cache (the module's planes are pool
+    pages under the same block tables)."""
+    select_ref = _spec_multi_select(sample, temps, top_ps, top_ks)
+    cache, tok_buf, emits, counts, proposed, accepted, *model_counts = (
+        _model(cfg).forward_slots_spec_multi(
+            params, cache, tokens, positions, active, budgets, eos_ids,
+            None, select_ref, key_tab, None, None, n_steps, spec_k, cfg,
+            tables=tables, page_size=page_size,
+        )
+    )
+    return (tok_buf, emits, counts, proposed, accepted, cache, *model_counts)
 
 
 @partial(jax.jit, static_argnames=("page_size", "scan_layers"), donate_argnums=(0,))
@@ -548,7 +588,11 @@ def _insert_row_paged(cache, row_cache, write_ids, slot, page_size: int,
     valid = jax.lax.dynamic_update_slice(
         cache["valid"], row_cache["valid"], (slot, 0)
     )
-    return {"layers": layers, "valid": valid}
+    # any other leaf of the pool's cache is state a LANE keeps (a prediction module's next
+    # draft, [lanes, ...]): it takes the row's
+    lane = {k: v.at[slot].set(row_cache[k][0]) for k, v in cache.items()
+            if k not in ("layers", "valid")}
+    return {"layers": layers, "valid": valid, **lane}
 
 
 @partial(jax.jit, static_argnames=("page_size", "scan_layers"))
@@ -589,10 +633,7 @@ def _copy_page(cache, src, dst, scan_layers: bool):
         page = jax.lax.dynamic_index_in_dim(pool, src, axis=axis)
         return jax.lax.dynamic_update_slice_in_dim(pool, page, dst, axis=axis)
 
-    return {
-        "layers": jax.tree_util.tree_map(cp, cache["layers"]),
-        "valid": cache["valid"],
-    }
+    return {**cache, "layers": jax.tree_util.tree_map(cp, cache["layers"])}
 
 
 @partial(jax.jit, static_argnames=("scan_layers",))
@@ -624,10 +665,7 @@ def _import_pages(cache, block, write_ids, scan_layers: bool):
             return pool.at[:, write_ids].set(b.astype(pool.dtype))
         return pool.at[write_ids].set(b.astype(pool.dtype))
 
-    return {
-        "layers": jax.tree_util.tree_map(put, cache["layers"], block),
-        "valid": cache["valid"],
-    }
+    return {**cache, "layers": jax.tree_util.tree_map(put, cache["layers"], block)}
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -637,7 +675,7 @@ def _set_lane_valid(cache, slot, valid_row):
     from the handoff's layout and writes it directly). ``slot`` is traced —
     one program serves every lane."""
     valid = jax.lax.dynamic_update_slice(cache["valid"], valid_row[None], (slot, 0))
-    return {"layers": cache["layers"], "valid": valid}
+    return {**cache, "valid": valid}
 
 
 @partial(jax.jit, static_argnames=("cfg", "max_len"))
@@ -691,7 +729,7 @@ class ContinuousBatcher:
 
     def __init__(self, params, cfg, max_slots: int = 8, max_len: int = 512,
                  prompt_bucket: int = 64, prefix_cache: int = 0, telemetry=None,
-                 compile_cache=None, prompt_buckets=None, spec_k: int = 0,
+                 compile_cache=None, prompt_buckets=None, spec_k: Optional[int] = None,
                  drafter=None, spec_accept: str = "replay", page_size: int = 0,
                  kv_pages: Optional[int] = None, tracer=None, faults=None,
                  step_timeout_s: Optional[float] = None,
@@ -709,8 +747,14 @@ class ContinuousBatcher:
         if layout is not None:
             params, self.decode_layout_weights = layout(params, cfg)
         self.params = params
+        # A model that drafts with its own module says so in its config (``mtp_draft``):
+        # that is the engine's speculation unless ``spec_k`` is given.
+        if spec_k is None:
+            spec_k = getattr(cfg, "mtp_draft", 0) if role != "prefill" else 0
         paths = ["prefill", "paged" if page_size else "dense rows (page_size=0)"]
-        paths += ["decode"] * (role != "prefill") + ["spec_k"] * bool(spec_k)
+        paths += ["decode"] * (role != "prefill")
+        if spec_k:
+            paths += ["spec_k", "spec_k over pages" if page_size else "spec_k over dense rows"]
         paths += ["prefix_cache"] * bool(prefix_cache)
         for path in paths:
             for fn in _PATH_CALLS[path]:
@@ -812,9 +856,12 @@ class ContinuousBatcher:
             )
         # Batched speculative decoding: ``spec_k`` draft proposals per active slot per
         # step, verified by ONE fused [B, spec_k+1] target forward; each slot accepts a
-        # variable-length prefix. 0 (default) = no speculation: the scan at this
-        # engine's ``decode_steps``. ``drafter`` is a
-        # ``spec_decode.DraftSource`` (default: the model-free NgramDrafter).
+        # variable-length prefix. 0 = no speculation: the scan at this engine's
+        # ``decode_steps``; the default is the config's ``mtp_draft`` (0 for a model
+        # that serves without its prediction module). ``drafter`` is a
+        # ``spec_decode.DraftSource`` (default: the model's own module where the config
+        # serves with it — ``spec_decode.ModuleDrafter`` — else the model-free
+        # NgramDrafter).
         # ``spec_accept`` picks the sampled-slot acceptance test: "replay" (bitwise
         # parity with spec_k=0 under a fixed key schedule) or "residual" (vectorized
         # Leviathan accept/reject — lossless in distribution, higher acceptance).
@@ -834,9 +881,9 @@ class ContinuousBatcher:
                 "pass spec_k>=1 to enable speculative decoding"
             )
         if self.spec_k and drafter is None:
-            from .spec_decode import NgramDrafter
+            from .spec_decode import ModuleDrafter, NgramDrafter
 
-            drafter = NgramDrafter()
+            drafter = ModuleDrafter() if getattr(cfg, "mtp_draft", 0) else NgramDrafter()
         self.drafter = drafter
         # Persistent AOT executable cache (``accelerate_tpu.compile_cache``): accepts
         # a shared AotCache (e.g. ``accelerator.compile_cache``) or a
@@ -871,6 +918,8 @@ class ContinuousBatcher:
             ("spec_verify", _spec_verify_step, _spec_verify_step_paged, ("cfg",)),
             ("spec_multi", _spec_multi_step, _spec_multi_step_paged,
              ("cfg", "n_steps", "spec_k", "max_ngram", "sample")),
+            ("spec_module", _spec_module_step, _spec_module_step_paged,
+             ("cfg", "n_steps", "spec_k", "sample")),
         ):
             label, fn = (f"serving.{name}_paged", paged) if self.paged else (
                 f"serving.{name}", dense)
@@ -1294,9 +1343,19 @@ class ContinuousBatcher:
         residual accept consumes keys data-dependently on device draws the scan
         cannot replay), and a drafter with a device-resident propose
         (``DraftSource.resident`` — the shipped NgramDrafter). Everything else
-        keeps the PR-6 host loop, bitwise-identically."""
+        keeps the PR-6 host loop, bitwise-identically. A model's own prediction module
+        (``spec_decode.ModuleDrafter``) drafts from the verify's hidden states, which
+        only the scan holds: it takes the scan at every ``decode_steps``."""
+        if self._module_drafts():
+            return True
         return (self.multi_step > 1 and self.spec_accept == "replay"
                 and getattr(self.drafter, "resident", False))
+
+    def _module_drafts(self) -> bool:
+        """Whether the drafter is the model's own prediction module."""
+        from .spec_decode import ModuleDrafter
+
+        return isinstance(self.drafter, ModuleDrafter)
 
     def evict_slot(self, uid: int) -> bool:
         """Free the decode lane holding request ``uid`` (deadline enforcement /
@@ -2008,39 +2067,51 @@ class ContinuousBatcher:
         ``_multi_step``."""
         N = self.multi_step
         k = self.spec_k
+        module = self._module_drafts()
         with phase("engine.decode.prepare"):
             # Per-lane key TABLE: the next N*(k+1) emission keys (the worst case —
             # N full acceptances); the scan's per-lane cursor (its emission count)
             # indexes into it.
             sampled, lane_args = self._lane_args(active, N * (k + 1))
-            # Drafting history: prompt + generated so far, packed from column 0 —
-            # compact token order, so it works unchanged with prefix-cached and
-            # paged layouts (it is NOT the cache layout, just the token sequence).
-            history = np.zeros((self.max_slots, self.max_len), np.int32)
-            hist_lens = np.zeros((self.max_slots,), np.int32)
-            for i in active:
-                req = self.slot_req[i]
-                ctx = np.concatenate(
-                    [np.asarray(req.prompt, np.int32),
-                     np.asarray(req.tokens, np.int32)]
-                )[-self.max_len:]
-                history[i, :len(ctx)] = ctx
-                hist_lens[i] = len(ctx)
-            lane_args += (jnp.asarray(history), jnp.asarray(hist_lens))
+            if not module:
+                # Drafting history: prompt + generated so far, packed from column 0 —
+                # compact token order, so it works unchanged with prefix-cached and
+                # paged layouts (it is NOT the cache layout, just the token sequence).
+                # A model's own module drafts from the cache instead.
+                history = np.zeros((self.max_slots, self.max_len), np.int32)
+                hist_lens = np.zeros((self.max_slots,), np.int32)
+                for i in active:
+                    req = self.slot_req[i]
+                    ctx = np.concatenate(
+                        [np.asarray(req.prompt, np.int32),
+                         np.asarray(req.tokens, np.int32)]
+                    )[-self.max_len:]
+                    history[i, :len(ctx)] = ctx
+                    hist_lens[i] = len(ctx)
+                lane_args += (jnp.asarray(history), jnp.asarray(hist_lens))
             tables = self._tables()
-        t_guard, (tok_buf, emits, _, proposed, accepted, self.cache) = (
-            self._dispatch("spec_multi", active, tables, lane_args,
-                           n_steps=N, spec_k=k,
-                           max_ngram=int(self.drafter.max_ngram), sample=sampled))
+        statics = {"n_steps": N, "spec_k": k, "sample": sampled}
+        if not module:
+            statics["max_ngram"] = int(self.drafter.max_ngram)
+        t_guard, (tok_buf, emits, _, proposed, accepted, self.cache, *model_counts) = (
+            self._dispatch("spec_module" if module else "spec_multi", active, tables,
+                           lane_args, **statics))
         with phase("engine.decode.fetch"):
             ref_host = np.asarray(tok_buf).tolist()    # [N, B, k+1]
             emits_host = np.asarray(emits).tolist()    # [N, B]
             prop_host = np.asarray(proposed)           # [B]
             acc_host = np.asarray(accepted)            # [B]
+            # what the model counted beside its tokens (its DECODE_COUNTERS), if anything
+            model_counts = [np.asarray(c) for c in model_counts]
         self._post_dispatch(t_guard)  # watchdog check BEFORE any token lands
         finished, lanes = self._land(
             active, ((i, ref_host[r][i][:emits_host[r][i]])
-                     for r in range(N) for i in active))
+                     for r in range(N) for i in active),
+            spec_proposed=int(prop_host.sum()), spec_accepted=int(acc_host.sum()),
+            # a lane runs a round while it is live, and a live lane emits in it
+            spec_lane_rounds=sum(e > 0 for row in emits_host for e in row),
+            **{name: int(v) for c in model_counts
+               for name, v in zip(self.model.DECODE_COUNTERS, c)})
         if ph.tracer is not None:
             # As in ``_multi_step``, with the lane's ``proposed``/``accepted`` totals
             # over the N rounds.
@@ -2105,7 +2176,9 @@ class ContinuousBatcher:
             accepted[i] = sum(
                 1 for j, t in enumerate(emitted) if j < k and t == int(proposals[i, j])
             )
-        finished, lanes = self._land(active, emissions)
+        finished, lanes = self._land(
+            active, emissions, spec_proposed=k * len(active),
+            spec_accepted=sum(accepted.values()), spec_lane_rounds=len(active))
         if ph.tracer is not None:
             self._decode_spans(
                 ph, ((req, {"tokens": n, "proposed": k,
@@ -2218,7 +2291,13 @@ class ContinuousBatcher:
             _, lane_args = self._lane_args([], self.multi_step)
             for sample in (False, True):
                 warm("decode_multi", lane_args, n_steps=self.multi_step, sample=sample)
-            if self.spec_k:
+            if self.spec_k and self._module_drafts():
+                # the model's own module drafts: its rounds are the one spec program
+                _, lane_args = self._lane_args([], self.multi_step * (self.spec_k + 1))
+                for sample in (False, True):
+                    warm("spec_module", lane_args, n_steps=self.multi_step,
+                         spec_k=self.spec_k, sample=sample)
+            elif self.spec_k:
                 warm("spec_verify", (
                     jnp.zeros((self.max_slots, self.spec_k + 1), jnp.int32),
                     jnp.asarray(self.positions)))

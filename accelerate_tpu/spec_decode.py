@@ -2,7 +2,7 @@
 
 Speculative decoding splits a decode step into PROPOSE (cheap, per-slot, k tokens)
 and VERIFY (one fused target forward over ``[B, k+1]``, ``models.llama.forward_slots``).
-This module owns the propose side: one small interface, two shipped implementations —
+This module owns the propose side: one small interface, three shipped implementations —
 
 - :class:`NgramDrafter` — model-free prompt-lookup drafting (the "self-drafting" /
   prompt-lookup-decoding trick): propose the continuation of the longest recent n-gram
@@ -11,6 +11,10 @@ This module owns the propose side: one small interface, two shipped implementati
   tier-1-testable without a second model. Acceptance is workload-dependent (great on
   extraction/repetition-heavy traffic, ~0 on incompressible text) but NEVER changes
   outputs: the verify step emits exactly what plain decode would.
+- :class:`ModuleDrafter` — the target's OWN prediction module (DeepSeek's multi-token
+  prediction, ``models/deepseek_v32.py``): no weights or cache of its own — the module's
+  are the engine's — and a propose that exists only inside the fused decode scan, where
+  the verify's hidden states are.
 - :class:`ModelDrafter` — a real draft model (llama- or gpt-family config via
   ``models.common.cached_decode_family``; cross-family draft/target pairs work whenever
   the vocabularies match) with its own per-slot KV cache mirroring the engine's lane
@@ -37,7 +41,8 @@ import jax.numpy as jnp
 
 from .models.common import cached_decode_family
 
-__all__ = ["DraftSource", "NgramDrafter", "ModelDrafter", "ngram_propose_resident"]
+__all__ = ["DraftSource", "NgramDrafter", "ModelDrafter", "ModuleDrafter",
+           "ngram_propose_resident"]
 
 
 class DraftSource:
@@ -79,6 +84,36 @@ class DraftSource:
 
     def warm_programs(self, engine, max_new_tokens: int = 32) -> list:
         return []
+
+
+class ModuleDrafter(DraftSource):
+    """The model's own prediction module as the drafter: ``cfg.mtp_draft`` tokens a
+    round from the module that the config carries (``models.deepseek._module_draft``).
+    Each round the module runs over the rows the verify wrote, fed with the verify's
+    hidden states and the ACCEPTED tokens, and leaves each lane's next draft in the
+    engine's cache; prefill writes the module's prompt rows and the first draft. So
+    everything happens on the device, inside the engine's fused scan
+    (``serving.spec_module``), at every ``decode_steps``; there is no host propose.
+    Needs the paged cache and replay acceptance (the residual test runs in the host
+    loop, which has no hidden states)."""
+
+    resident = True
+
+    def bind(self, engine) -> None:
+        k = getattr(engine.cfg, "mtp_draft", 0)
+        if not k:
+            raise ValueError(f"{type(engine.cfg).__name__} has no prediction module "
+                             "(mtp_draft = 0): nothing to draft with")
+        if engine.spec_k != k:
+            raise ValueError(f"the prediction module drafts {k} token(s) a round; "
+                             f"spec_k={engine.spec_k}")
+        if not engine.paged or engine.spec_accept != "replay":
+            raise ValueError("the prediction module drafts inside the fused scan over the "
+                             "paged cache: page_size >= 1 and spec_accept='replay'")
+
+    def propose(self, lanes, pending, positions, k):
+        raise NotImplementedError("the prediction module drafts inside the fused decode "
+                                  "scan only (it reads the verify's hidden states)")
 
 
 class NgramDrafter(DraftSource):

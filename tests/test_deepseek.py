@@ -366,7 +366,9 @@ def test_engine_reports_the_expert_and_page_counters(served):
 
 @pytest.mark.parametrize("kw,fn", [
     ({"page_size": 0, "kv_pages": None}, "forward_slots"),
-    ({"spec_k": 2}, "forward_slots"),
+    # speculation over pages is served (the fused rounds and the paged verify); over
+    # dense rows it needs the one-token body the module lacks
+    ({"spec_k": 2, "page_size": 0, "kv_pages": None}, "forward_slots"),
     ({"prefix_cache": 2}, "forward_cached_logits"),
 ], ids=["dense_rows", "spec_k", "prefix_cache"])
 def test_engine_refuses_what_the_model_module_lacks(kw, fn):

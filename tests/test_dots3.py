@@ -333,7 +333,7 @@ def test_engine_reports_the_selection_and_window_counters(served):
 
 @pytest.mark.parametrize("kw,what", [
     ({"page_size": 0, "kv_pages": None}, r"has no forward_slots: the engine's dense rows"),
-    ({"spec_k": 2}, r"has no forward_slots: the engine's spec_k path"),
+    ({"spec_k": 2}, r"has no forward_slots_spec_multi: the engine's spec_k path"),
     ({"prefix_cache": 2}, r"has no forward_cached_logits: the engine's prefix_cache path"),
     ({"role": "prefill", "decode_steps": 1}, r"prefill/decode hand-off path \(role='prefill'\)"),
     ({"role": "decode"}, r"prefill/decode hand-off path \(role='decode'\)"),

@@ -505,7 +505,7 @@ def test_engine_reports_the_selection_and_expert_counters(served):
 
 @pytest.mark.parametrize("kw,what", [
     ({"page_size": 0, "kv_pages": None}, r"has no forward_slots: the engine's dense rows"),
-    ({"spec_k": 2}, r"has no forward_slots: the engine's spec_k path"),
+    ({"spec_k": 2}, r"has no forward_slots_spec_multi: the engine's spec_k path"),
     ({"prefix_cache": 2}, r"has no forward_cached_logits: the engine's prefix_cache path"),
 ], ids=["dense_rows", "spec_k", "prefix_cache"])
 def test_engine_refuses_by_name_the_paths_the_model_has_no_forward_for(kw, what):
